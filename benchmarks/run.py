@@ -53,6 +53,8 @@ def main(argv=None) -> None:
             os.environ["XLA_FLAGS"] = (
                 _flags + " --xla_force_host_platform_device_count=8").strip()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (fig3_breakdown, fig14_end2end, fig15_energy,
                    fig16_pure_inference, fig17_opbreakdown, fig18_bulk,
                    fig19_batchprep, fig20_mutable, fig21_fastpath,

@@ -7,11 +7,13 @@ Hetero accelerator -> run GCN inference through a DFG over RPC.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.service import HolisticGNNService, make_service_dfg
 from repro.core import gnn
 from repro.kernels.ops import program_config
 from repro.rpc import RPCServer, RPCClient
 
+use_compile_cache()
 rng = np.random.default_rng(0)
 
 # 1. a power-law graph + node embeddings (the "raw data on storage")

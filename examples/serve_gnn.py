@@ -72,6 +72,7 @@ import threading
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.service import HolisticGNNService, make_service_dfg
 from repro.core import gnn
 from repro.kernels.ops import program_config
@@ -137,6 +138,7 @@ def main():
                     help="elastic drill: drain the K highest-id shards out "
                          "of the array live (same verification)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.kill_shard is not None and args.replication < 2:
         ap.error("--kill-shard needs --replication >= 2")
     if args.chaos and args.replication < 2:

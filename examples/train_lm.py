@@ -7,9 +7,11 @@ resumes from the last committed step and replays the same data stream).
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import main as train_main
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--smoke", action="store_true")

@@ -219,11 +219,7 @@ class Engine:
         missing = [i for i in dfg._ins if i not in env]
         if missing:
             raise KeyError(f"missing DFG inputs: {missing}")
-        order = dfg.topo_nodes()
-        if fuse is None:
-            fuse = _FUSED_OP in self.registry.ops
-        if fuse:
-            order = fuse_aggregate_combine(order, set(dfg._outs.values()))
+        order, fuse = self._order(dfg, fuse)
         self.trace = []
         self.timings = []
         if jit:
@@ -231,6 +227,14 @@ class Engine:
         for node in order:
             self._exec_node(node, env)
         return {name: env[src] for name, src in dfg._outs.items()}
+
+    def _order(self, dfg: DFG, fuse: bool | None) -> tuple[list[_Node], bool]:
+        order = dfg.topo_nodes()
+        if fuse is None:
+            fuse = _FUSED_OP in self.registry.ops
+        if fuse:
+            order = fuse_aggregate_combine(order, set(dfg._outs.values()))
+        return order, fuse
 
     # ------------------------------------------------------------ eager path
     def _exec_node(self, node: _Node, env: dict[str, Any]) -> None:
@@ -262,7 +266,30 @@ class Engine:
         suffix = order[cut:]
         if not suffix:
             return {name: env[src] for name, src in dfg._outs.items()}
+        fn, arr_refs, suffix_outs, trace = self._program(dfg, suffix, env,
+                                                         fuse)
+        self.trace.extend(trace)
+        t0 = _time.perf_counter()
+        results = _block(fn(*(env[r] for r in arr_refs)))
+        self.timings.append(("__dfg_jit__", "jit", _time.perf_counter() - t0))
+        env.update(zip(suffix_outs, results))
+        return {name: env[src] for name, src in dfg._outs.items()}
 
+    def jit_program(self, dfg: DFG, feeds: dict[str, Any]):
+        """The cached jitted program of a DFG with no stateful node, and the
+        feed names of its array arguments in call order.  ``feeds`` may hold
+        ``jax.ShapeDtypeStruct``s: ``fn.lower(*(feeds[r] for r in refs))``
+        then compiles the program without running it."""
+        order, fuse = self._order(dfg, None)
+        if any(n.op in self.registry.unjittable for n in order):
+            raise ValueError("jit_program needs a DFG without stateful ops")
+        fn, arr_refs, _, _ = self._program(dfg, order, dict(feeds), fuse)
+        return fn, arr_refs
+
+    def _program(self, dfg: DFG, suffix: list[_Node], env: dict[str, Any],
+                 fuse: bool):
+        """(jitted fn, array arg refs, output refs, device trace) of a
+        jit-safe suffix, from the LRU cache or freshly traced."""
         produced: set[str] = set()
         for n in suffix:
             produced.update(n.outputs)
@@ -321,18 +348,13 @@ class Engine:
                 self._jit_cache.popitem(last=False)
                 self._cache_evictions += 1
         fn, trace = hit
-        self.trace.extend(trace)
-        t0 = _time.perf_counter()
-        results = _block(fn(*(env[r] for r in arr_refs)))
-        self.timings.append(("__dfg_jit__", "jit", _time.perf_counter() - t0))
-        env.update(zip(suffix_outs, results))
-        return {name: env[src] for name, src in dfg._outs.items()}
+        return fn, arr_refs, suffix_outs, trace
 
 
 def _block(x):
-    """Block on async results so per-node timings are honest."""
-    try:
-        import jax
-        return jax.block_until_ready(x)
-    except Exception:  # noqa: BLE001 — non-array outputs
-        return x
+    """Block on the array leaves of ``x`` so per-node timings are honest;
+    a device error raises here."""
+    import jax
+    jax.block_until_ready([leaf for leaf in jax.tree.leaves(x)
+                           if isinstance(leaf, jax.Array)])
+    return x

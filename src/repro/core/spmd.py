@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 
 ROWS_FULL, ROWS_DATA = "full", "data"
 FEAT_REP, FEAT_MODEL = "rep", "model"
@@ -462,8 +461,8 @@ def build_sharded_program(suffix, resolved, arr_refs, static_env,
             step(e)
         return tuple(e[r] for r in suffix_outs)
 
-    mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=tuple(out_specs))
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=tuple(out_specs), check_vma=False)
 
     def program(*vals):
         padded = [jnp.pad(v, pads[r]) if r in pads else v

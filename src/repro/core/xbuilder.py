@@ -5,7 +5,7 @@ ICAP engine) and **User** (swappable accelerator, programmed as a partial
 bitstream through ``Program()``).  On TPU there are no gates to rewire; the
 faithful analog is *runtime re-binding of compiled kernels*:
 
-  * **Shell** = the always-present pure-`jnp` C-kernels (device ``"cpu"``,
+  * **Shell** = the always-present pure-`jnp` C-kernels (device ``"shell"``,
     priority 50) — the framework can always run, like the paper's Shell cores.
   * **User bitstreams** = named kernel sets (e.g. Pallas MXU GEMM = the
     systolic array, Pallas VPU SpMM = the vector processor).  ``program()``
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from .registry import KernelRegistry
 
-SHELL_DEVICE = "cpu"
+SHELL_DEVICE = "shell"
 SHELL_PRIORITY = 50
 
 

@@ -1,8 +1,7 @@
 from . import ref
 from .ops import (gemm, spmm, sddmm, rmsnorm, agg_combine, flash_attention,
-                  decode_attention, set_interpret, get_interpret,
-                  BITSTREAMS, program_config)
+                  decode_attention, BITSTREAMS, program_config)
 
 __all__ = ["ref", "gemm", "spmm", "sddmm", "rmsnorm", "agg_combine",
-           "flash_attention", "decode_attention", "set_interpret",
-           "get_interpret", "BITSTREAMS", "program_config"]
+           "flash_attention", "decode_attention", "BITSTREAMS",
+           "program_config"]

@@ -1,8 +1,8 @@
 """Fused aggregate-combine Pallas kernel — one GCN layer in one kernel.
 
 Computes ``relu(spmm(h, nbr, mask, mode) @ w + b)`` without materialising the
-aggregated features in HBM: the VPU gather/reduce (SpMM) lands in a VMEM
-scratch slab that feeds the MXU matmul directly — the GNNHLS-style
+aggregated features in HBM: the row gather (``gather.py``) and VPU reduce land
+in a VMEM scratch slab that feeds the MXU matmul directly — the GNNHLS-style
 aggregate/combine fusion on top of GraphStore's page-shaped ELL blocks.
 
 Grid is (dst blocks, output-feature tiles) with the output dimension
@@ -19,32 +19,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .config import CompilerParams, resolve_interpret
+from .config import resolve_interpret
+from .gather import (TABLE_SPEC, aggregate, block_rows, gather_rows,
+                     index_spec, pad_rows, round_up, row_spec, slab_scratch,
+                     table)
 
 
-def _agg_combine_kernel(h_ref, nbr_ref, mask_ref, w_ref, b_ref, o_ref,
-                        agg_ref, *, mode: str, epilogue: bool):
-    j = pl.program_id(1)
+def _agg_combine_kernel(nbr_ref, h_hbm, mask_ref, w_ref, *refs, mode: str,
+                        epilogue: bool):
+    if epilogue:
+        b_ref, o_ref, agg_ref, slab, sem = refs
+    else:
+        o_ref, agg_ref, slab, sem = refs
 
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _aggregate():
-        nbr = nbr_ref[...]                  # (bd, K) int32
-        mask = mask_ref[...]                # (bd, K) f32
-        bd, kk = nbr.shape
-        h = h_ref[...]                      # (N, Fp) VMEM slab
-        g = jnp.take(h, nbr.reshape(-1), axis=0).reshape(bd, kk, -1)
-        g = g * mask[..., None]
-        s = g.sum(axis=1)
-        if mode == "mean":
-            deg = jnp.maximum(mask.sum(axis=1), 1.0)
-            s = s / deg[:, None]
-        agg_ref[...] = s.astype(jnp.float32)
+        gather_rows(h_hbm, nbr_ref, slab, sem)
+        agg_ref[...] = aggregate(slab, mask_ref[...], mode)
 
     z = jnp.dot(agg_ref[...], w_ref[...].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
     if epilogue:
-        z = z + b_ref[...].astype(jnp.float32)
-        z = jnp.maximum(z, 0.0)
+        z = jnp.maximum(z + b_ref[...].astype(jnp.float32), 0.0)
     o_ref[...] = z.astype(o_ref.dtype)
 
 
@@ -54,7 +50,7 @@ def agg_combine(h: jax.Array, nbr: jax.Array, mask: jax.Array,
                 interpret: bool | None = None) -> jax.Array:
     """h (N,F); nbr,mask (D,K); w (F,O); b (O,) -> relu(agg@w+b) (D,O)."""
     return _agg_combine(h, nbr, mask, w, b, mode=mode, bd=bd, bo=bo,
-                        epilogue=True, interpret=resolve_interpret(interpret))
+                        interpret=resolve_interpret(interpret))
 
 
 def agg_combine_partial(h: jax.Array, nbr: jax.Array, mask: jax.Array,
@@ -69,44 +65,39 @@ def agg_combine_partial(h: jax.Array, nbr: jax.Array, mask: jax.Array,
     full sum (a nonlinearity cannot be applied to a partial sum).  Same
     fused Pallas kernel, epilogue compiled out.
     """
-    b = jnp.zeros((w.shape[1],), jnp.float32)      # unused when epilogue=False
-    return _agg_combine(h, nbr, mask, w, b, mode=mode, bd=bd, bo=bo,
-                        epilogue=False, interpret=resolve_interpret(interpret))
+    return _agg_combine(h, nbr, mask, w, None, mode=mode, bd=bd, bo=bo,
+                        interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("mode", "bd", "bo", "epilogue",
-                                    "interpret"))
-def _agg_combine(h, nbr, mask, w, b, *, mode, bd, bo, epilogue, interpret):
-    n, f = h.shape
+                   static_argnames=("mode", "bd", "bo", "interpret"))
+def _agg_combine(h, nbr, mask, w, b, *, mode, bd, bo, interpret):
     d, k = nbr.shape
     o = w.shape[1]
-    bd = min(bd, max(8, d))
+    tab = table(h)
+    fp = tab.shape[-1]
+    bd = block_rows(d, k, fp, h.dtype.itemsize, bd)
     bo = min(bo, max(128, o))
-    dp = -(-d // bd) * bd
-    fp = -(-f // 128) * 128
-    op = -(-o // bo) * bo
-    npad = -(-max(n, 8) // 8) * 8
-    hp = jnp.pad(h, ((0, npad - n), (0, fp - f)))
-    nbrp = jnp.pad(nbr, ((0, dp - d), (0, 0)))
-    maskp = jnp.pad(mask, ((0, dp - d), (0, 0)))
-    wp = jnp.pad(w, ((0, fp - f), (0, op - o)))
-    bp = jnp.pad(b.reshape(1, -1), ((0, 0), (0, op - o)))
+    dp = round_up(d, bd)
+    op = round_up(o, bo)
+    in_specs = [index_spec(bd, k), TABLE_SPEC, row_spec(bd, k),
+                pl.BlockSpec((fp, bo), lambda i, j: (0, j))]
+    args = [pad_rows(nbr.astype(jnp.int32), dp), tab, pad_rows(mask, dp),
+            jnp.pad(w, ((0, fp - w.shape[0]), (0, op - o)))]
+    if b is not None:
+        in_specs.append(pl.BlockSpec((1, bo), lambda i, j: (0, j)))
+        args.append(jnp.pad(b.reshape(1, -1), ((0, 0), (0, op - o))))
     out = pl.pallas_call(
-        functools.partial(_agg_combine_kernel, mode=mode, epilogue=epilogue),
+        functools.partial(_agg_combine_kernel, mode=mode,
+                          epilogue=b is not None),
         grid=(dp // bd, op // bo),
-        in_specs=[
-            pl.BlockSpec((npad, fp), lambda i, j: (0, 0)),   # VMEM h slab
-            pl.BlockSpec((bd, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bd, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((fp, bo), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bo), lambda i, j: (0, j)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bd, bo), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((dp, op), h.dtype),
-        scratch_shapes=[pltpu.VMEM((bd, fp), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bd, fp), jnp.float32)]
+        + slab_scratch(k, bd, fp, h.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(hp, nbrp, maskp, wp, bp)
+    )(*args)
     return out[:d, :o]

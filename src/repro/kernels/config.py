@@ -1,33 +1,20 @@
-"""Global Pallas execution-mode switch.
+"""Pallas execution mode.
 
-Every kernel wrapper defaults ``interpret=None`` and resolves it here, so a
-single ``set_interpret(False)`` flips the whole kernel library to native TPU
-compilation — direct callers no longer bypass the toggle by picking up a
-hardcoded per-kernel default.  Resolution happens *outside* the jitted
-wrappers: ``interpret`` is a static argument, so the resolved boolean (not
-``None``) must be what reaches the jit cache key.
+Every kernel wrapper takes ``interpret=None`` and resolves it here, outside
+its jit: ``interpret`` is a static argument, so the resolved boolean (not
+``None``) is what keys the jit cache.  ``None`` follows the backend: Mosaic
+compiles the kernel on a TPU, and the Pallas interpreter runs it on the CPU
+(``JAX_PLATFORMS=cpu``, the tests).  An explicit ``interpret=`` wins.
 """
 from __future__ import annotations
 
-import jax.experimental.pallas.tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; resolve
-# whichever this installation provides so kernels work on both.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-_INTERPRET = True
+import jax
 
 
-def set_interpret(flag: bool) -> None:
-    """Global toggle: False on real TPU."""
-    global _INTERPRET
-    _INTERPRET = bool(flag)
-
-
-def get_interpret() -> bool:
-    return _INTERPRET
+def default_interpret() -> bool:
+    """True unless the default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    return _INTERPRET if interpret is None else bool(interpret)
+    return default_interpret() if interpret is None else bool(interpret)

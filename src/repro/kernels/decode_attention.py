@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .config import CompilerParams, resolve_interpret
+from .config import resolve_interpret
 
 _LANES = 128
 NEG_INF = -1e30
@@ -109,7 +109,7 @@ def _decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, qg, k_pages, v_pages)

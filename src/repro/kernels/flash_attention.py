@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .config import CompilerParams, resolve_interpret
+from .config import resolve_interpret
 
 _LANES = 128
 NEG_INF = -1e30
@@ -111,7 +111,7 @@ def _flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

@@ -12,9 +12,9 @@ Three accelerator configurations mirror the paper's prototypes (Fig. 12):
   * **Hetero-HGNN** — vector + systolic: SpMM/SDDMM on the VPU kernels,
     GEMM on the MXU kernel (highest priority), the winning configuration.
 
-On this CPU container Pallas kernels run in interpret mode; on TPU the same
-``pallas_call``s compile natively (flip ``interpret=False`` via
-set_interpret()).
+Every kernel follows the backend (``kernels/config.py``): Mosaic compiles
+it on a TPU, and the Pallas interpreter runs the same ``pallas_call`` on the
+CPU.
 """
 from __future__ import annotations
 
@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.xbuilder import Bitstream
-from .config import set_interpret, get_interpret
 from .gemm import gemm
 from .spmm import spmm
 from .sddmm import sddmm
@@ -32,10 +31,6 @@ from .rmsnorm import rmsnorm
 from .agg_combine import agg_combine, agg_combine_partial
 from .flash_attention import flash_attention
 from .decode_attention import decode_attention
-
-
-def _i():
-    return get_interpret()
 
 
 # ----------------------------------------------------------- dense fallbacks
@@ -49,14 +44,14 @@ def _spmm_via_gemm(h, nbr, mask, *, mode: str = "mean"):
     if mode == "mean":
         deg = jnp.maximum(mask.sum(axis=1), 1.0)
         a = a / deg[:, None]
-    return gemm(a, h, interpret=_i())
+    return gemm(a, h)
 
 
 def _sddmm_via_gemm(h, nbr, mask):
     n = h.shape[0]
     d, k = nbr.shape
     onehot = jax.nn.one_hot(nbr.reshape(-1), n, dtype=h.dtype)        # (D*K,N)
-    g = gemm(onehot, h, interpret=_i()).reshape(d, k, -1)
+    g = gemm(onehot, h).reshape(d, k, -1)
     return g * h[:d][:, None, :] * mask[..., None]
 
 
@@ -121,7 +116,7 @@ def program_config(xbuilder, name: str) -> float:
 
 __all__ = ["gemm", "spmm", "sddmm", "rmsnorm", "agg_combine",
            "agg_combine_partial",
-           "flash_attention", "decode_attention", "set_interpret",
-           "get_interpret", "BITSTREAMS", "program_config",
+           "flash_attention", "decode_attention", "BITSTREAMS",
+           "program_config",
            "octa_bitstream", "lsap_bitstream", "hetero_bitstream",
            "hetero_gemm_bitstream"]

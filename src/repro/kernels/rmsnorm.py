@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .config import CompilerParams, resolve_interpret
+from .config import resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -42,7 +42,7 @@ def _rmsnorm(x: jax.Array, w: jax.Array, *, eps: float, br: int,
         ],
         out_specs=pl.BlockSpec((br, f), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, f), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xp, w)

@@ -2,11 +2,9 @@
 
 Consumes GraphStore's page-shaped blocks directly: a (D,K) padded
 neighbor-index matrix + mask against the sampled embedding table h (N,F).
-TPU adaptation (vs. the paper's Hwacha vector loops): sampled subgraphs are
-small (paper Table 5: <= ~6K nodes), so the *full node dimension* of h fits
-VMEM when the feature dimension is tiled — the kernel keeps an (N, bf) slab
-resident in VMEM and performs VPU row-gathers per destination block, never
-touching HBM per edge.  Grid is (dst blocks, feature tiles).
+The table stays in HBM; each grid step DMAs the rows its ``bd`` destination
+rows name into VMEM (``gather.py``) and reduces them on the VPU, so VMEM use
+does not grow with the super-batch.  Grid is (dst blocks,).
 """
 from __future__ import annotations
 
@@ -17,54 +15,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .config import CompilerParams, resolve_interpret
+from .config import resolve_interpret
+from .gather import (TABLE_SPEC, aggregate, block_rows, gather_rows,
+                     index_spec, pad_rows, round_up, row_spec, slab_scratch,
+                     table)
 
 
-def _spmm_kernel(h_ref, nbr_ref, mask_ref, o_ref, *, mode: str):
-    nbr = nbr_ref[...]                    # (bd, K) int32
-    mask = mask_ref[...]                  # (bd, K) f32
-    bd, kk = nbr.shape
-    h = h_ref[...]                        # (N, bf) VMEM slab
-    g = jnp.take(h, nbr.reshape(-1), axis=0).reshape(bd, kk, -1)
-    g = g * mask[..., None]
-    s = g.sum(axis=1)
-    if mode == "mean":
-        deg = jnp.maximum(mask.sum(axis=1), 1.0)
-        s = s / deg[:, None]
-    o_ref[...] = s.astype(o_ref.dtype)
+def _spmm_kernel(nbr_ref, h_hbm, mask_ref, o_ref, slab, sem, *, mode: str):
+    gather_rows(h_hbm, nbr_ref, slab, sem)
+    o_ref[...] = aggregate(slab, mask_ref[...], mode).astype(o_ref.dtype)
 
 
 def spmm(h: jax.Array, nbr: jax.Array, mask: jax.Array, *, mode: str = "mean",
-         bd: int = 128, bf: int = 128,
-         interpret: bool | None = None) -> jax.Array:
-    return _spmm(h, nbr, mask, mode=mode, bd=bd, bf=bf,
+         bd: int = 128, interpret: bool | None = None) -> jax.Array:
+    return _spmm(h, nbr, mask, mode=mode, bd=bd,
                  interpret=resolve_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("mode", "bd", "bf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("mode", "bd", "interpret"))
 def _spmm(h: jax.Array, nbr: jax.Array, mask: jax.Array, *, mode: str,
-          bd: int, bf: int, interpret: bool) -> jax.Array:
-    n, f = h.shape
+          bd: int, interpret: bool) -> jax.Array:
+    f = h.shape[1]
     d, k = nbr.shape
-    bd = min(bd, max(8, d))
-    bf = min(bf, max(128, f))
-    dp = -(-d // bd) * bd
-    fp = -(-f // bf) * bf
-    hp = jnp.pad(h, ((0, 0), (0, fp - f)))
-    nbrp = jnp.pad(nbr, ((0, dp - d), (0, 0)))
-    maskp = jnp.pad(mask, ((0, dp - d), (0, 0)))
+    tab = table(h)
+    fp = tab.shape[-1]
+    bd = block_rows(d, k, fp, h.dtype.itemsize, bd)
+    dp = round_up(d, bd)
     out = pl.pallas_call(
         functools.partial(_spmm_kernel, mode=mode),
-        grid=(dp // bd, fp // bf),
-        in_specs=[
-            pl.BlockSpec((n, bf), lambda i, j: (0, j)),     # VMEM-resident slab
-            pl.BlockSpec((bd, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bd, k), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bd, bf), lambda i, j: (i, j)),
+        grid=(dp // bd,),
+        in_specs=[index_spec(bd, k), TABLE_SPEC, row_spec(bd, k)],
+        out_specs=pl.BlockSpec((bd, fp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((dp, fp), h.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        scratch_shapes=slab_scratch(k, bd, fp, h.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(hp, nbrp, maskp)
+    )(pad_rows(nbr.astype(jnp.int32), dp), tab, pad_rows(mask, dp))
     return out[:d, :f]

@@ -30,7 +30,7 @@ from ..configs.base import ShapeConfig
 from ..models import build, layers as L
 from ..train import optimizer as O
 from ..train.trainer import make_train_step
-from .mesh import make_production_mesh, dp_axes_of
+from .mesh import make_mesh, make_production_mesh, dp_axes_of
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -158,9 +158,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, dev: bool,
     cfg = (SMOKES if smoke else ARCHS)[arch]
     shape = SHAPES[shape_name]
     if dev:
-        mesh = jax.make_mesh((2, 2, 4) if multi_pod else (2, 4),
-                             ("pod", "data", "model") if multi_pod
-                             else ("data", "model"))
+        mesh = make_mesh((2, 2, 4) if multi_pod else (2, 4),
+                         ("pod", "data", "model") if multi_pod
+                         else ("data", "model"))
         shape = dataclasses.replace(
             shape, global_batch=max(mesh.shape.get("pod", 1)
                                     * mesh.shape["data"],

@@ -15,10 +15,17 @@ import numpy as np
 import jax
 
 
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axes: ``jax.make_mesh`` defaults to
+    Explicit axes, which ``with_sharding_constraint`` refuses."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes_of(mesh) -> tuple:

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
-from .. import compat
 
 # ---------------------------------------------------------------- mesh state
 # DP is a sentinel resolved to the data-parallel axes of the active mesh;
@@ -609,12 +608,12 @@ def moe_apply(p, x, cfg):
         # data (measured 2x34 GB/layer on phi3.5-moe).
         local = functools.partial(_moe_local, cfg=cfg, axes=dp_axes)
         dspec = P(dp_axes, None, None)
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             local, mesh=mesh,
             in_specs=(dspec, P(None, None), P(None, None, None),
                       P(None, None, None), P(None, None, None)),
             out_specs=(dspec, P()),
-            axis_names=set(dp_axes), check=False)(
+            axis_names=set(dp_axes), check_vma=False)(
             xn_in, p["router"], p["w_gate"], p["w_in"], p["w_out"])
     else:
         y, aux = _moe_local(xn_in, p["router"], p["w_gate"], p["w_in"],

@@ -24,11 +24,10 @@ import zlib
 import numpy as np
 import jax
 
-from .. import compat
 
 
 def _flatten(tree):
-    flat, treedef = compat.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     items = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -14,7 +14,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map
 
 
 def compressed_psum_mean(mesh, axis: str = "data"):
@@ -44,6 +43,6 @@ def compressed_psum_mean(mesh, axis: str = "data"):
         return mean, err
 
     spec = P(axis)
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(spec, spec), out_specs=(spec, spec),
-                     check=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(spec, spec), out_specs=(spec, spec),
+                         check_vma=False)

@@ -42,7 +42,7 @@ def test_cycle_detection():
 
 def test_priority_dispatch_and_reconfig():
     reg = KernelRegistry()
-    xb = XBuilder(reg)                          # installs Shell (cpu, 50)
+    xb = XBuilder(reg)                          # installs Shell (shell, 50)
     calls = []
 
     def mk(dev):
@@ -64,9 +64,9 @@ def test_priority_dispatch_and_reconfig():
     assert dev == "vector"
     xb.unprogram("vector")
     dev, _ = reg.resolve("GEMM")
-    assert dev == "cpu"                         # Shell always present
+    assert dev == "shell"                       # Shell always present
     with pytest.raises(ValueError):
-        xb.unprogram("cpu")
+        xb.unprogram("shell")
 
 
 def test_named_configs_match_shell():

@@ -194,5 +194,5 @@ def test_gcn_fusion_on_hetero_bitstream():
     svc.xbuilder.unprogram("vector")
     svc.xbuilder.unprogram("systolic")
     fallback = svc.run(dfg.save(), [1, 2], weights=weights)["Result"]
-    assert all(d == "cpu" for _, d in svc.engine.trace)
+    assert all(d == "shell" for _, d in svc.engine.trace)
     np.testing.assert_allclose(before, fallback, rtol=1e-5, atol=1e-5)

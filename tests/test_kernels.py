@@ -8,6 +8,7 @@ from _hyp import given, settings, st
 
 from repro.kernels import (ref, gemm, spmm, sddmm, rmsnorm, flash_attention,
                            decode_attention)
+from repro.kernels.agg_combine import agg_combine, agg_combine_partial
 
 RNG = np.random.default_rng(0)
 
@@ -47,6 +48,41 @@ def test_sddmm_sweep(n, f, d, kk):
     np.testing.assert_allclose(sddmm(h, nbr, mask),
                                ref.sddmm_ref(h, nbr, mask),
                                rtol=1e-5, atol=1e-5)
+
+
+def _gather_case(kernel, h, nbr, mask, w, b):
+    """(kernel output, ref.py oracle) for one row-gather kernel."""
+    mean = ref.spmm_ref(h, nbr, mask, mode="mean")
+    return {
+        "spmm_mean": (lambda: spmm(h, nbr, mask, mode="mean"), lambda: mean),
+        "spmm_sum": (lambda: spmm(h, nbr, mask, mode="sum"),
+                     lambda: ref.spmm_ref(h, nbr, mask, mode="sum")),
+        "sddmm": (lambda: sddmm(h, nbr, mask),
+                  lambda: ref.sddmm_ref(h, nbr, mask)),
+        "agg_combine": (lambda: agg_combine(h, nbr, mask, w, b),
+                        lambda: jnp.maximum(ref.gemm_ref(mean, w) + b, 0.0)),
+        "agg_combine_partial": (lambda: agg_combine_partial(h, nbr, mask, w),
+                                lambda: ref.gemm_ref(mean, w)),
+    }[kernel]
+
+
+# (20000, 420): a padded table of 41 MB, beyond the 16 MiB scoped VMEM, so
+# the rows must come from HBM; (600, 4000): a width that caps the rows per
+# grid step below the default block
+@pytest.mark.parametrize("n,f,d,kk", [(20000, 420, 300, 10),
+                                      (600, 4000, 100, 10)])
+@pytest.mark.parametrize("kernel", ["spmm_mean", "spmm_sum", "sddmm",
+                                    "agg_combine", "agg_combine_partial"])
+def test_row_gather_kernels(kernel, n, f, d, kk):
+    """The shared HBM row gather at a non-multiple-of-128 F and large N."""
+    h = _r(n, f)
+    nbr = RNG.integers(0, n, (d, kk))
+    nbr[0, 0], nbr[-1, -1] = n - 1, 0            # both ends of the table
+    nbr = jnp.asarray(nbr, jnp.int32)
+    mask = jnp.asarray(RNG.integers(0, 2, (d, kk)), jnp.float32)
+    w, b = _r(f, 64) * 0.05, _r(64)
+    got, want = _gather_case(kernel, h, nbr, mask, w, b)
+    np.testing.assert_allclose(got(), want(), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("b,f", [(3, 64), (17, 256), (64, 512)])
@@ -93,6 +129,6 @@ def test_spmm_property(db, kb, n, d, kk):
     h = _r(n, 8)
     nbr = jnp.asarray(RNG.integers(0, n, (d, kk)), jnp.int32)
     mask = jnp.asarray(RNG.integers(0, 2, (d, kk)), jnp.float32)
-    got = spmm(h, nbr, mask, mode="sum", bd=8 * db, bf=128)
+    got = spmm(h, nbr, mask, mode="sum", bd=8 * db)
     dense = (jax.nn.one_hot(nbr, n) * mask[..., None]).sum(1) @ h
     np.testing.assert_allclose(got, dense, rtol=1e-4, atol=1e-4)
