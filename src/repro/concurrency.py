@@ -144,6 +144,10 @@ LOCK_ORDER: tuple[LockSpec, ...] = (
              (("supervisor", "_lock"),), leaf=True,
              doc="Supervisor state arrays.  Strict LEAF: drains, "
                  "rebuilds and transition hooks all run outside it."),
+    LockSpec("spans._lock", 97, "lock",
+             (("spans", "_lock"),), leaf=True,
+             doc="Span recorder's count/seconds table.  LEAF: a span "
+                 "closes inside any other lock, so it ranks last."),
 )
 
 RANK = {s.name: s.rank for s in LOCK_ORDER}

@@ -15,6 +15,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any
 
+from .. import spans
 from .registry import KernelRegistry
 
 
@@ -162,8 +163,9 @@ class Engine:
 
     Two execution paths share the dynamic-binding semantics:
 
-      * **eager** (default): resolve + dispatch node by node, with honest
-        per-node timings (``self.timings``);
+      * **eager** (default): resolve + dispatch node by node; each node
+        runs in a span named ``engine.<op>`` (``repro.spans``), so the
+        recorder keeps its count and seconds per C-operation;
       * **jit** (``run(..., jit=True)``): the maximal jit-safe suffix of the
         DFG is traced *once* through the currently-bound C-kernels and
         compiled as a single XLA program, cached per (markup, registry
@@ -174,6 +176,8 @@ class Engine:
 
     Both paths first apply the aggregate-combine fusion pass whenever a
     fused ``AggCombine`` C-kernel is resolvable (``fuse=None`` -> auto).
+    Every ``run`` is one ``engine.run`` span: dispatch, device execution
+    and the wait for its results.
 
     **SPMD** (``mesh=``): with a (data, model) device mesh the jit path
     lowers the traced suffix through ``shard_map`` instead of plain jit —
@@ -195,7 +199,6 @@ class Engine:
         self.registry = registry
         self.mesh = mesh
         self.trace: list[tuple[str, str]] = []     # (op, device) per executed node
-        self.timings: list[tuple[str, str, float]] = []
         if jit_cache_size < 1:
             raise ValueError(f"jit_cache_size must be >= 1, got "
                              f"{jit_cache_size}")
@@ -221,12 +224,12 @@ class Engine:
             raise KeyError(f"missing DFG inputs: {missing}")
         order, fuse = self._order(dfg, fuse)
         self.trace = []
-        self.timings = []
-        if jit:
-            return self._run_jit(dfg, order, env, fuse)
-        for node in order:
-            self._exec_node(node, env)
-        return {name: env[src] for name, src in dfg._outs.items()}
+        with spans.span("engine.run"):
+            if jit:
+                return self._run_jit(dfg, order, env, fuse)
+            for node in order:
+                self._exec_node(node, env)
+            return {name: env[src] for name, src in dfg._outs.items()}
 
     def _order(self, dfg: DFG, fuse: bool | None) -> tuple[list[_Node], bool]:
         order = dfg.topo_nodes()
@@ -238,14 +241,12 @@ class Engine:
 
     # ------------------------------------------------------------ eager path
     def _exec_node(self, node: _Node, env: dict[str, Any]) -> None:
-        import time as _time
         device, fn = self.registry.resolve(node.op)
         self.trace.append((node.op, device))
         args = [env[i] for i in node.inputs]
-        t0 = _time.perf_counter()
-        out = fn(*args, **node.attrs) if node.attrs else fn(*args)
-        out = _block(out)
-        self.timings.append((node.op, device, _time.perf_counter() - t0))
+        with spans.span(f"engine.{node.op}"):
+            out = fn(*args, **node.attrs) if node.attrs else fn(*args)
+            out = _block(out)
         if len(node.outputs) == 1:
             env[node.outputs[0]] = out
         else:
@@ -255,7 +256,6 @@ class Engine:
     # -------------------------------------------------------------- jit path
     def _run_jit(self, dfg: DFG, order: list[_Node], env: dict[str, Any],
                  fuse: bool) -> dict[str, Any]:
-        import time as _time
         # eager prefix: through the last jit-unsafe (stateful) node
         cut = 0
         for idx, node in enumerate(order):
@@ -269,9 +269,7 @@ class Engine:
         fn, arr_refs, suffix_outs, trace = self._program(dfg, suffix, env,
                                                          fuse)
         self.trace.extend(trace)
-        t0 = _time.perf_counter()
         results = _block(fn(*(env[r] for r in arr_refs)))
-        self.timings.append(("__dfg_jit__", "jit", _time.perf_counter() - t0))
         env.update(zip(suffix_outs, results))
         return {name: env[src] for name, src in dfg._outs.items()}
 
@@ -352,8 +350,8 @@ class Engine:
 
 
 def _block(x):
-    """Block on the array leaves of ``x`` so per-node timings are honest;
-    a device error raises here."""
+    """Block on the array leaves of ``x`` so a node's span covers its
+    device time; a device error raises here."""
     import jax
     jax.block_until_ready([leaf for leaf in jax.tree.leaves(x)
                            if isinstance(leaf, jax.Array)])

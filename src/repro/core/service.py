@@ -14,6 +14,7 @@ import importlib
 import numpy as np
 import jax.numpy as jnp
 
+from .. import spans
 from ..store.blockdev import BlockDevice
 from ..store.graphstore import GraphStore
 from ..store.sampler import sample_batch
@@ -407,15 +408,20 @@ class HolisticGNNService:
         batch, slices = sample_group(
             self.store, [r["targets"] for r in requests],
             [int(r.get("seed", 0)) for r in requests], prog.fanouts)
-        batch = pad_group(batch, self.pad_to)
+        spans.count("serve.rows_real", batch.num_nodes)
+        with spans.span("serve.pad"):
+            batch = pad_group(batch, self.pad_to)
+        spans.count("serve.rows_padded", len(batch.node_vids))
         feeds = self._resolve_weights(weights, weights_ref)
-        feeds[prog.feed_refs[0]] = jnp.asarray(batch.embeddings)
-        for l, blk in enumerate(batch.layers):
-            feeds[prog.feed_refs[1 + 2 * l]] = jnp.asarray(blk.nbr)
-            feeds[prog.feed_refs[2 + 2 * l]] = jnp.asarray(blk.mask)
+        with spans.span("serve.h2d"):
+            feeds[prog.feed_refs[0]] = jnp.asarray(batch.embeddings)
+            for l, blk in enumerate(batch.layers):
+                feeds[prog.feed_refs[1 + 2 * l]] = jnp.asarray(blk.nbr)
+                feeds[prog.feed_refs[2 + 2 * l]] = jnp.asarray(blk.mask)
         out = self.engine.run(prog.model, feeds, jit=jit)
-        return [{k: np.asarray(v)[off: off + n] for k, v in out.items()}
-                for off, n in slices]
+        with spans.span("serve.slice"):
+            return [{k: np.asarray(v)[off: off + n] for k, v in out.items()}
+                    for off, n in slices]
 
     @staticmethod
     def _device_counters(dev_stats) -> dict:
@@ -429,7 +435,9 @@ class HolisticGNNService:
 
         The RPC dispatcher injects its own rolling per-method stats under
         ``rpc``; the serving runtime contributes scheduler + transport QoS
-        under ``qos`` via ``qos_provider``.  Against a sharded store every
+        under ``qos`` via ``qos_provider``; ``spans`` holds the span
+        recorder's lifetime counts and seconds per stage of the served
+        path (``repro.spans``).  Against a sharded store every
         per-shard figure comes from ONE endpoint ``stats`` snapshot per
         shard — never from poking shard internals — so the report is
         byte-for-byte the same shape whether the shards are in-process
@@ -512,6 +520,7 @@ class HolisticGNNService:
         out["engine"] = self.engine_stats()
         if self.qos_provider is not None:
             out["qos"] = self.qos_provider()
+        out["spans"] = spans.snapshot()
         return out
 
     def engine_stats(self) -> dict:

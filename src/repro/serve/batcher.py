@@ -35,6 +35,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import spans
 from ..core.dfg import DFG
 from ..rpc.queues import BackpressureError
 from ..store.sampler import (LayerBlock, SampledBatch, _gather_neighbors,
@@ -127,71 +128,74 @@ def sample_group(store, targets_list, seeds, fanouts,
     comp_of = np.arange(int(seg.sum()), dtype=np.int64)
     comp_rev: list[LayerBlock] = []
 
-    for fanout in fanouts:
-        concat = (np.concatenate(fronts) if seg.sum()
-                  else np.empty(0, np.int64))
-        total_k = len(concat)
-        if not total_k:                        # every request is empty
-            comp_rev.append(LayerBlock(
-                nbr=np.zeros((0, fanout), np.int32),
-                mask=np.zeros((0, fanout), np.float32), num_dst=0))
-            continue
-        segs = seg.tolist()
-        if hasattr(store, "sample_neighbors_batch"):
-            try:
-                sel, lens = store.sample_neighbors_batch(
-                    concat, fanout, segments=segs, rngs=rngs)
-            except BackpressureError as e:
-                # a shed fused fetch names the group it refused
-                e.reason.setdefault("stage", "sample")
-                e.reason.setdefault("group_requests", n_req)
-                raise
-        else:                              # host-side store: per-request path
-            sel_parts, len_parts = [], []
-            for r in range(n_req):
-                if not segs[r]:
-                    continue
-                neigh = _gather_neighbors(store, fronts[r])
-                s, l = _subsample_batch(rngs[r], fronts[r], neigh, fanout)
-                sel_parts.append(s)
-                len_parts.append(l)
-            sel = np.concatenate(sel_parts)
-            lens = np.concatenate(len_parts).astype(np.int64)
+    for hop, fanout in enumerate(fanouts):
+        with spans.span("store.hop", hop=hop):
+            concat = (np.concatenate(fronts) if seg.sum()
+                      else np.empty(0, np.int64))
+            total_k = len(concat)
+            if not total_k:                        # every request is empty
+                comp_rev.append(LayerBlock(
+                    nbr=np.zeros((0, fanout), np.int32),
+                    mask=np.zeros((0, fanout), np.float32), num_dst=0))
+                continue
+            segs = seg.tolist()
+            if hasattr(store, "sample_neighbors_batch"):
+                try:
+                    sel, lens = store.sample_neighbors_batch(
+                        concat, fanout, segments=segs, rngs=rngs)
+                except BackpressureError as e:
+                    # a shed fused fetch names the group it refused
+                    e.reason.setdefault("stage", "sample")
+                    e.reason.setdefault("group_requests", n_req)
+                    raise
+            else:                      # host-side store: per-request path
+                sel_parts, len_parts = [], []
+                for r in range(n_req):
+                    if not segs[r]:
+                        continue
+                    neigh = _gather_neighbors(store, fronts[r])
+                    s, l = _subsample_batch(rngs[r], fronts[r], neigh, fanout)
+                    sel_parts.append(s)
+                    len_parts.append(l)
+                sel = np.concatenate(sel_parts)
+                lens = np.concatenate(len_parts).astype(np.int64)
 
-        # ---- group-wide reindex over request-tagged vids
-        req_of_row = np.repeat(np.arange(n_req), seg)
-        row_of_flat = np.repeat(np.arange(total_k), lens)
-        tag_front = concat + req_of_row * _REQ_TAG
-        tag_sel = sel.astype(np.int64) + req_of_row[row_of_flat] * _REQ_TAG
-        local, next_tagged = _reindex(tag_front, tag_sel)
-        new_tagged = next_tagged[total_k:]
-        new_counts = np.bincount(new_tagged // _REQ_TAG, minlength=n_req)
-        new_off = np.concatenate([[0], np.cumsum(new_counts)]).astype(np.int64)
+            # ---- group-wide reindex over request-tagged vids
+            req_of_row = np.repeat(np.arange(n_req), seg)
+            row_of_flat = np.repeat(np.arange(total_k), lens)
+            tag_front = concat + req_of_row * _REQ_TAG
+            tag_sel = sel.astype(np.int64) + req_of_row[row_of_flat] * _REQ_TAG
+            local, next_tagged = _reindex(tag_front, tag_sel)
+            new_tagged = next_tagged[total_k:]
+            new_counts = np.bincount(new_tagged // _REQ_TAG, minlength=n_req)
+            new_off = np.concatenate([[0], np.cumsum(new_counts)]
+                                     ).astype(np.int64)
 
-        # composed nbr values: frontier locals map through comp_of, new
-        # nodes append after every level-k node in request-rank order
-        remap = np.concatenate([comp_of,
-                                total_k + np.arange(len(new_tagged))])
-        nbr = np.zeros((total_k, fanout), np.int32)
-        mask = np.zeros((total_k, fanout), np.float32)
-        rows = comp_of[row_of_flat]
-        offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
-        cols = np.arange(len(sel)) - np.repeat(offs, lens)
-        nbr[rows, cols] = remap[local]
-        mask[rows, cols] = 1.0
-        comp_rev.append(LayerBlock(nbr=nbr, mask=mask, num_dst=total_k))
+            # composed nbr values: frontier locals map through comp_of, new
+            # nodes append after every level-k node in request-rank order
+            remap = np.concatenate([comp_of,
+                                    total_k + np.arange(len(new_tagged))])
+            nbr = np.zeros((total_k, fanout), np.int32)
+            mask = np.zeros((total_k, fanout), np.float32)
+            rows = comp_of[row_of_flat]
+            offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
+            cols = np.arange(len(sel)) - np.repeat(offs, lens)
+            nbr[rows, cols] = remap[local]
+            mask[rows, cols] = 1.0
+            comp_rev.append(LayerBlock(nbr=nbr, mask=mask, num_dst=total_k))
 
-        # ---- next level: per-request lists grow by their new nodes
-        new_vids = new_tagged % _REQ_TAG
-        fronts = [np.concatenate([fronts[r],
-                                  new_vids[new_off[r]: new_off[r + 1]]])
-                  for r in range(n_req)]
-        old_off = np.concatenate([[0], np.cumsum(seg)])
-        comp_of = np.concatenate(
-            [np.concatenate([comp_of[old_off[r]: old_off[r + 1]],
-                             total_k + np.arange(new_off[r], new_off[r + 1])])
-             for r in range(n_req)])
-        seg = seg + new_counts
+            # ---- next level: per-request lists grow by their new nodes
+            new_vids = new_tagged % _REQ_TAG
+            fronts = [np.concatenate([fronts[r],
+                                      new_vids[new_off[r]: new_off[r + 1]]])
+                      for r in range(n_req)]
+            old_off = np.concatenate([[0], np.cumsum(seg)])
+            comp_of = np.concatenate(
+                [np.concatenate([comp_of[old_off[r]: old_off[r + 1]],
+                                 total_k + np.arange(new_off[r],
+                                                     new_off[r + 1])])
+                 for r in range(n_req)])
+            seg = seg + new_counts
 
     total_nodes = int(seg.sum())
     vids = np.empty(total_nodes, np.int64)
@@ -200,7 +204,8 @@ def sample_group(store, targets_list, seeds, fanouts,
     emb = None
     if fetch_embeddings and getattr(store, "feature_dim", 0):
         try:
-            emb = store.get_embeds(vids)       # ONE coalesced (cached) gather
+            with spans.span("store.embed"):
+                emb = store.get_embeds(vids)   # ONE coalesced (cached) gather
         except BackpressureError as e:
             e.reason.setdefault("stage", "fetch_embeds")
             e.reason.setdefault("group_requests", n_req)

@@ -47,6 +47,7 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+from .. import spans
 from ..concurrency import witness_lock
 from ..rpc import RPCServer, MultiQueueRoP, AsyncRPCClient
 from ..rpc.transport import serialize, deserialize
@@ -109,7 +110,8 @@ class ServingRuntime:
             weights_key = kwargs.pop("weights_key", None)
 
             def on_done(resp: dict) -> None:
-                self.rop.post_completion(qid, cmd_id, serialize(resp))
+                with spans.span("serve.reply"):
+                    self.rop.post_completion(qid, cmd_id, serialize(resp))
 
             try:
                 self.scheduler.submit(
@@ -188,8 +190,9 @@ class ServingRuntime:
                     self._dispatch(*got, inline=False)
                 if self.scheduler.step():
                     continue
-                if self.scheduler.wait_for_work(timeout=0.05):
-                    time.sleep(0.0005)        # batching window still open
+                with spans.span("serve.wait"):
+                    if self.scheduler.wait_for_work(timeout=0.05):
+                        time.sleep(0.0005)    # batching window still open
 
         for fn, name in ((dispatcher, "rop-dispatch"), (worker, "batcher")):
             th = threading.Thread(target=fn, name=name, daemon=True)

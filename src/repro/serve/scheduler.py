@@ -20,7 +20,9 @@ QoS levers:
   * **deadlines** — requests whose deadline passed while queued complete
     with a ``DeadlineExceeded`` error instead of occupying the engine;
   * **telemetry** — rolling p50/p95/p99 latency, throughput, queue depth
-    and group-size accounting, surfaced via the ``stats`` RPC.
+    and group-size accounting, surfaced via the ``stats`` RPC; each
+    grouping round is a ``serve.form`` span, each group a ``serve.group``
+    span (``repro.spans``) that records its requests' queue wait.
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ from typing import Callable
 
 import numpy as np
 
+from .. import spans
 from ..concurrency import witness_condition, witness_lock
 from ..rpc.queues import BackpressureError
 from .batcher import fingerprint_weights
@@ -169,6 +172,7 @@ class BatchScheduler:
         self._cond = witness_condition(
             "scheduler._cond", threading.Condition())
         self._seq = itertools.count()
+        self._group_ids = itertools.count()
 
     # -------------------------------------------------------------- admission
     def accepts(self, dfg) -> bool:
@@ -248,7 +252,7 @@ class BatchScheduler:
     # ------------------------------------------------------------- scheduling
     def _form_group(self, force: bool) -> list[ServeRequest]:
         """Pop one fused group (priority head + compatible followers)."""
-        with self._cond:
+        with spans.span("serve.form"), self._cond:
             now = time.perf_counter()
             alive: list[ServeRequest] = []
             expired: list[ServeRequest] = []
@@ -305,39 +309,46 @@ class BatchScheduler:
 
     # -------------------------------------------------------------- execution
     def _execute(self, group: list[ServeRequest]) -> None:
-        head = group[0]
-        try:
-            if self.accepts(head.dfg):
-                results = self.service.run_batch(
-                    head.dfg,
-                    [{"targets": r.targets, "seed": r.seed} for r in group],
-                    weights=head.weights, jit=head.jit,
-                    weights_ref=head.weights_ref)
-            else:                      # non-service DFG: solo fallback
-                results = [self.service.run(head.dfg, head.targets,
-                                            weights=head.weights,
-                                            seed=head.seed, jit=head.jit,
-                                            weights_ref=head.weights_ref)]
-        except BackpressureError as e:
-            # typed shed: the array's flow control (in-flight windows /
-            # queue-full retry budget) refused the fused fetch — report
-            # the reason, don't crash the group as a generic error
-            self.qos.note_backpressured(dict(e.reason))
-            resp = {"ok": False, "error": f"BackpressureError: {e}",
-                    "backpressure": True, "reason": dict(e.reason)}
-            self.qos.note_errors(len(group))
-            for r in group:
-                r.on_done(dict(resp))
-            return
-        except Exception as e:  # noqa: BLE001 — fault fans out to the group
-            resp = {"ok": False, "error": f"{type(e).__name__}: {e}",
-                    "traceback": traceback.format_exc()}
-            self.qos.note_errors(len(group))
-            for r in group:
-                r.on_done(dict(resp))
-            return
-        now = time.perf_counter()
-        self.qos.note_group(len(group))
-        for r, out in zip(group, results):
-            self.qos.record(now - r.t_enqueue)
-            r.on_done({"ok": True, "result": out})
+        with spans.span("serve.group", group=next(self._group_ids),
+                        size=len(group),
+                        first_seq=min(r.seq for r in group)):
+            now = time.perf_counter()
+            spans.count("serve.queue_wait", len(group),
+                        sum(now - r.t_enqueue for r in group))
+            head = group[0]
+            try:
+                if self.accepts(head.dfg):
+                    results = self.service.run_batch(
+                        head.dfg,
+                        [{"targets": r.targets, "seed": r.seed}
+                         for r in group],
+                        weights=head.weights, jit=head.jit,
+                        weights_ref=head.weights_ref)
+                else:                      # non-service DFG: solo fallback
+                    results = [self.service.run(
+                        head.dfg, head.targets, weights=head.weights,
+                        seed=head.seed, jit=head.jit,
+                        weights_ref=head.weights_ref)]
+            except BackpressureError as e:
+                # typed shed: the array's flow control (in-flight windows /
+                # queue-full retry budget) refused the fused fetch — report
+                # the reason, don't crash the group as a generic error
+                self.qos.note_backpressured(dict(e.reason))
+                resp = {"ok": False, "error": f"BackpressureError: {e}",
+                        "backpressure": True, "reason": dict(e.reason)}
+                self.qos.note_errors(len(group))
+                for r in group:
+                    r.on_done(dict(resp))
+                return
+            except Exception as e:  # noqa: BLE001 — fans out to the group
+                resp = {"ok": False, "error": f"{type(e).__name__}: {e}",
+                        "traceback": traceback.format_exc()}
+                self.qos.note_errors(len(group))
+                for r in group:
+                    r.on_done(dict(resp))
+                return
+            now = time.perf_counter()
+            self.qos.note_group(len(group))
+            for r, out in zip(group, results):
+                self.qos.record(now - r.t_enqueue)
+                r.on_done({"ok": True, "result": out})

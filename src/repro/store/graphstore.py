@@ -31,6 +31,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import spans
 from ..concurrency import witness_lock
 from .blockdev import BlockDevice, SLOTS_PER_PAGE, SLOT_DTYPE
 from .sampler import _ramp
@@ -244,9 +245,11 @@ class GraphStore:
         self.attach_cache(EmbeddingPageCache(capacity_pages), **kw)
 
     def _read_pages_cached(self, lpns, tag: str) -> np.ndarray:
-        if self.cache is not None and (tag == "embed" or self._cache_graph):
-            return self.cache.read_pages(self.dev, lpns, tag=tag)
-        return self.dev.read_pages(lpns, tag=tag)
+        with spans.span("store.read", tag=tag):
+            if self.cache is not None and (tag == "embed"
+                                           or self._cache_graph):
+                return self.cache.read_pages(self.dev, lpns, tag=tag)
+            return self.dev.read_pages(lpns, tag=tag)
 
     # ================================================================= helpers
     def _classify(self, degree: int) -> str:
@@ -496,8 +499,9 @@ class GraphStore:
         of the batched queries (one queued scatter-read).  The sharded
         coordinator calls this once per shard, concurrently; the returned
         block is a snapshot copy, so selection can run outside the lock.
+        One ``store.plan`` span, its page read a ``store.read`` inside it.
         """
-        with self._lock:
+        with spans.span("store.plan"), self._lock:
             return self._fetch_plan(
                 np.asarray(vids_arr, dtype=np.int64).reshape(-1))
 
@@ -690,8 +694,9 @@ class GraphStore:
         """
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
         block, desc = self.fetch_plan(vids_arr)
-        return select_from_plan(vids_arr, block, desc, fanout, rng,
-                                segments=segments, rngs=rngs)
+        with spans.span("store.select"):
+            return select_from_plan(vids_arr, block, desc, fanout, rng,
+                                    segments=segments, rngs=rngs)
 
     def _l_locate_batch(self, block, row_of, l_pos, lq, k, miss, desc) -> None:
         """Vectorized L-page meta scan over every fetched page at once.

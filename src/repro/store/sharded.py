@@ -68,6 +68,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import spans
 from ..concurrency import witness_condition, witness_lock
 from ..rpc.queues import BackpressureError, QueueFullError
 from .blockdev import (BlockDevice, DeviceFailedError, SLOTS_PER_PAGE,
@@ -1101,8 +1102,9 @@ class ShardedGraphStore:
         plan (rng consumed in global frontier order: bit-identical)."""
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
         block, desc = self._fan_fetch(vids_arr)
-        return select_from_plan(vids_arr, block, desc, fanout, rng,
-                                segments=segments, rngs=rngs)
+        with spans.span("store.select"):
+            return select_from_plan(vids_arr, block, desc, fanout, rng,
+                                    segments=segments, rngs=rngs)
 
     # ----------------------------------------------------------- embeddings
     def get_embed(self, vid: int) -> np.ndarray:
