@@ -338,6 +338,11 @@ class BlockDevice:
         ``read_page`` round-trip per page.
         """
         lpns = np.asarray(lpns, dtype=np.int64)
+        self._read_command(lpns, tag)
+        return self._pages[lpns]
+
+    def _read_command(self, lpns: np.ndarray, tag: str) -> None:
+        """Charge one queued read command for the page set ``lpns``."""
         self._check_alive()
         self._maybe_sleep(self.command_latency_us
                           + self.page_read_us * len(lpns) / self.channels)
@@ -346,7 +351,35 @@ class BlockDevice:
         self.stats.events.append(IOEvent(
             time.perf_counter() - self._t0, "read",
             int(lpns[0]) if len(lpns) else 0, len(lpns) * PAGE_BYTES, tag))
-        return self._pages[lpns]
+
+    def read_rows(self, lpns, offsets, d: int, *,
+                  tag: str = "embed") -> np.ndarray:
+        """Batched row read -> (len(offsets), d): one queued command for
+        the page set ``lpns``, charged exactly as ``read_pages(lpns)``,
+        that returns only the ``d``-slot rows starting at the flat slots
+        ``offsets`` of the page array.
+
+        Each row is gathered once through a strided window over the page
+        array, so no page is copied: a row that straddles pages is
+        contiguous there.  An empty page set issues no command; its rows
+        lie in pages the caller already holds (the page cache's hits).
+        """
+        lpns = np.asarray(lpns, dtype=np.int64)
+        if len(lpns):
+            self._read_command(lpns, tag)
+        offsets = np.asarray(offsets, dtype=np.int64)
+        flat = self._pages.reshape(-1)
+        if not len(offsets):
+            return np.empty((0, d), SLOT_DTYPE)
+        if offsets.min() < 0 or offsets.max() > flat.size - d:
+            raise IndexError("row outside the device")
+        return np.lib.stride_tricks.sliding_window_view(flat, d)[offsets]
+
+    def pages_of(self, lpns) -> np.ndarray:
+        """Copies of pages ``lpns`` with no command: for a caller whose
+        command has already read them (the page cache installing the
+        pages a ``read_rows`` fetched)."""
+        return self._pages[np.asarray(lpns, dtype=np.int64)]
 
     def read_span(self, lpn0: int, n_pages: int, *, tag: str = "embed") -> np.ndarray:
         self._check_alive()

@@ -22,6 +22,13 @@ Mechanics:
   * ``read_pages`` is a drop-in for ``BlockDevice.read_pages``: hits are
     gathered from the slab, the misses of one request are fetched with ONE
     queued dev.read_pages (the PR-1 fast path is preserved) and inserted;
+  * ``read_rows`` is the row path of the embedding gather: the same
+    lookup, counters, LRU ticks and installs as ``read_pages`` over the
+    merged page set, but no page block is built.  A row wholly inside one
+    hit page is copied from the slab; every other row (in a missed page,
+    or straddling pages, which are contiguous on the device) is gathered
+    by ONE ``dev.read_rows`` charged for exactly the missed pages.  The
+    install of the kept misses is the only whole-page copy;
   * invalidation is hooked at the device write layer (``BlockDevice.on_write``
     fires for every ``write_page``/``write_span``/``free_page`` and for the
     page-relocating ``_grow``), so every mutable-graph path — unit updates,
@@ -118,8 +125,58 @@ class EmbeddingPageCache:
                 self._insert(miss_lpns, fetched)
         return block
 
+    def read_rows(self, dev, lpns, offsets,
+                  d: int) -> tuple[np.ndarray, int]:
+        """Cache-fronted row read -> ``((len(offsets), d) rows, pages
+        installed)``.
+
+        ``lpns`` is the merged page set covering the ``d``-slot rows at
+        the flat slots ``offsets`` of ``dev``'s page array.  Lookup,
+        counters, LRU ticks and installs are those of
+        ``read_pages(dev, lpns)``; only the rows are copied out.  A row
+        wholly inside one hit page comes from the slab; every other row
+        (in a missed page, or straddling pages) comes from ONE
+        ``dev.read_rows`` charged for exactly the missed pages.
+        """
+        lpns = np.asarray(lpns, dtype=np.int64).reshape(-1)
+        offsets = np.asarray(offsets, dtype=np.int64).reshape(-1)
+        if not len(lpns):
+            return np.empty((0, d), SLOT_DTYPE), 0
+        with self._lock:
+            self._tick += 1
+            table = self._table_for(int(lpns.max()))
+            slots = table[lpns]
+            hit = slots >= 0
+            n_hit = int(hit.sum())
+            if n_hit:
+                self._last_use[slots[hit]] = self._tick
+            self.stats.hits += n_hit
+            self.stats.bytes_from_cache += n_hit * PAGE_BYTES
+            first = offsets // SLOTS_PER_PAGE
+            within = offsets - first * SLOTS_PER_PAGE
+            row_slot = table[first]
+            from_slab = (row_slot >= 0) & (within + d <= SLOTS_PER_PAGE)
+            rows = np.empty((len(offsets), d), SLOT_DTYPE)
+            if from_slab.any():                # before installs evict
+                win = np.lib.stride_tricks.sliding_window_view(
+                    self._slab.reshape(-1), d)
+                rows[from_slab] = win[row_slot[from_slab] * SLOTS_PER_PAGE
+                                      + within[from_slab]]
+            miss_lpns = lpns[~hit]
+            if not from_slab.all():
+                rest = ~from_slab
+                rows[rest] = dev.read_rows(miss_lpns, offsets[rest], d)
+            n_miss = len(miss_lpns)
+            if not n_miss:
+                return rows, 0
+            self.stats.misses += n_miss
+            self.stats.bytes_from_dev += n_miss * PAGE_BYTES
+            kept = miss_lpns[-self.capacity:]  # as _insert keeps them
+            self._slab[self._claim(kept)] = dev.pages_of(kept)
+            return rows, len(kept)
+
     def _insert(self, lpns: np.ndarray, pages: np.ndarray) -> None:
-        """Install fetched pages; batched LRU eviction frees slots needed.
+        """Install fetched pages.
 
         ``lpns`` may exceed capacity (a scan bigger than the cache): only
         the trailing ``capacity`` pages are kept — the rest would be evicted
@@ -127,6 +184,11 @@ class EmbeddingPageCache:
         """
         if len(lpns) > self.capacity:
             lpns, pages = lpns[-self.capacity:], pages[-self.capacity:]
+        self._slab[self._claim(lpns)] = pages
+
+    def _claim(self, lpns: np.ndarray) -> np.ndarray:
+        """Map ``lpns`` (at most ``capacity``) to slots, stamped with this
+        tick; batched LRU eviction frees the slots needed."""
         need = len(lpns) - len(self._free)
         if need > 0:                          # reclaim the LRU slots in bulk
             used = np.nonzero(self._slot_lpn >= 0)[0]
@@ -136,12 +198,14 @@ class EmbeddingPageCache:
             self._slot_lpn[victims] = -1
             self._free.extend(victims.tolist())
             self.stats.evictions += need
-        slots = np.array([self._free.pop() for _ in range(len(lpns))],
+        k = len(lpns)                         # pop k slots off the end
+        slots = np.array(self._free[len(self._free) - k:][::-1],
                          dtype=np.int64)
-        self._slab[slots] = pages
+        del self._free[len(self._free) - k:]
         self._slot_lpn[slots] = lpns
         self._last_use[slots] = self._tick
         self._lpn_slot[lpns] = slots
+        return slots
 
     def clone_empty(self) -> "EmbeddingPageCache":
         """A fresh, cold cache with this cache's capacity — the rebuild

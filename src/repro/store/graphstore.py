@@ -33,7 +33,7 @@ import numpy as np
 
 from .. import spans
 from ..concurrency import witness_lock
-from .blockdev import BlockDevice, SLOTS_PER_PAGE, SLOT_DTYPE
+from .blockdev import BlockDevice, PAGE_BYTES, SLOTS_PER_PAGE, SLOT_DTYPE
 from .sampler import _ramp
 
 # H-type page layout
@@ -244,12 +244,11 @@ class GraphStore:
         from .embcache import EmbeddingPageCache
         self.attach_cache(EmbeddingPageCache(capacity_pages), **kw)
 
-    def _read_pages_cached(self, lpns, tag: str) -> np.ndarray:
-        with spans.span("store.read", tag=tag):
-            if self.cache is not None and (tag == "embed"
-                                           or self._cache_graph):
-                return self.cache.read_pages(self.dev, lpns, tag=tag)
-            return self.dev.read_pages(lpns, tag=tag)
+    def _read_graph_pages(self, lpns) -> np.ndarray:
+        with spans.span("store.read", tag="graph"):
+            if self.cache is not None and self._cache_graph:
+                return self.cache.read_pages(self.dev, lpns, tag="graph")
+            return self.dev.read_pages(lpns, tag="graph")
 
     # ================================================================= helpers
     def _classify(self, degree: int) -> str:
@@ -467,7 +466,7 @@ class GraphStore:
         lpns = l_lpns + h_lpns                  # ONE queued scatter-read
         if not lpns:
             return None, desc
-        block = self._read_pages_cached(lpns, "graph")
+        block = self._read_graph_pages(lpns)
         row_of = {lpn: i for i, lpn in enumerate(lpns)}
 
         if len(lq):
@@ -516,7 +515,7 @@ class GraphStore:
                 (self.h_chain[int(v)][int(p)]
                  for v, p in zip(vids.tolist(), pgs.tolist())),
                 dtype=np.int64, count=len(vids))
-            return self._read_pages_cached(lpns, "graph")
+            return self._read_graph_pages(lpns)
 
     def plan_info(self, vids: np.ndarray) -> dict:
         """Planning metadata for a batch of vids, no page I/O: per-vid
@@ -630,10 +629,9 @@ class GraphStore:
             if n_rows <= 0:
                 return self._emb_rows
             old_rows = self._emb_rows
-            old = np.empty((old_rows, d), dtype=np.float32)
-            if old_rows:
-                self._get_embeds_locked(
-                    np.arange(old_rows, dtype=np.int64), old)
+            old = (self._get_embeds_locked(np.arange(old_rows,
+                                                     dtype=np.int64))
+                   if old_rows else np.empty((0, d), dtype=np.float32))
             grown = np.concatenate(
                 [old, np.zeros((n_rows, d), dtype=np.float32)], axis=0)
             self._write_embedding_table(grown)
@@ -808,22 +806,21 @@ class GraphStore:
         """Coalesced batched embedding gather.
 
         All rows' covering pages are merged (duplicates and overlaps
-        collapsed) into one queued scatter-read; rows are then sliced out of
-        the fetched block with a vectorized gather.  The sequential layout
+        collapsed) into one queued read command; each row is then gathered
+        once, straight from the page that holds it, into the output — the
+        merged pages themselves are never copied.  The sequential layout
         of the embedding space (paper Fig. 7) means adjacent VIDs share
         pages, so the merged page set is far smaller than one span per row.
         """
         if self._emb_base is None:
             raise KeyError("no embedding table loaded")
         vids = np.asarray(vids, dtype=np.int64).reshape(-1)
-        d = self.feature_dim
-        out = np.empty((len(vids), d), dtype=np.float32)
         if not len(vids):
-            return out
+            return np.empty((0, self.feature_dim), dtype=np.float32)
         with self._lock:
-            return self._get_embeds_locked(vids, out)
+            return self._get_embeds_locked(vids)
 
-    def _get_embeds_locked(self, vids: np.ndarray, out: np.ndarray) -> np.ndarray:
+    def _get_embeds_locked(self, vids: np.ndarray) -> np.ndarray:
         d = self.feature_dim
         lo = vids * d
         p0 = lo // SLOTS_PER_PAGE
@@ -831,25 +828,20 @@ class GraphStore:
         span = int((p1 - p0).max())                     # pages per row (>=1)
         cand = p0[:, None] + np.arange(span)[None, :]   # (rows, span)
         pages = np.unique(cand[cand < p1[:, None]])     # merged page set
-        block = self._read_pages_cached(self._emb_base + pages, "embed")
-        # a row's pages are consecutive integers, hence adjacent rows of the
-        # fetched block — so each embedding row is CONTIGUOUS in the block's
-        # flat view and one broadcast gather slices every row at once
-        fstart = np.searchsorted(pages, p0) * SLOTS_PER_PAGE \
-            + (lo - p0 * SLOTS_PER_PAGE)
-        flatb = block.reshape(-1)
-        # gather through a sliding-window VIEW: one fancy index over
-        # virtual rows, instead of materialising a (rows, d) int64 index
-        # matrix (which costs more to build than the gather itself —
-        # ~10x on feature-heavy tables)
-        win = np.lib.stride_tricks.sliding_window_view(flatb, d) \
-            if len(flatb) >= d else None
-        if win is not None:
-            out[...] = win[fstart].view(np.float32)
-        else:                                           # tiny device edge
-            out[...] = flatb[fstart[:, None] + np.arange(d)[None, :]] \
-                .view(np.float32)
-        return out
+        lpns = self._emb_base + pages
+        # a row's pages are consecutive LPNs, so each row is contiguous in
+        # the device's flat page array: one offset locates it
+        offsets = self._emb_base * SLOTS_PER_PAGE + lo
+        installed = 0
+        with spans.span("store.read", tag="embed"):
+            if self.cache is not None:
+                rows, installed = self.cache.read_rows(self.dev, lpns,
+                                                       offsets, d)
+            else:
+                rows = self.dev.read_rows(lpns, offsets, d)
+        spans.count("store.embed_bytes",
+                    rows.nbytes + installed * PAGE_BYTES)
+        return rows.view(np.float32)
 
     # ============================================================== unit ops
     def _l_collect(self, page: np.ndarray) -> list[tuple[int, np.ndarray]]:

@@ -2,9 +2,13 @@
 part that matters for mutable-graph serving — write-hook invalidation
 across every mutation path (unit updates, embedding RMWs, page splits,
 device growth/relocation)."""
-import numpy as np
+import tracemalloc
 
-from repro.store.blockdev import BlockDevice
+import numpy as np
+import pytest
+
+from repro import spans
+from repro.store.blockdev import PAGE_BYTES, SLOTS_PER_PAGE, BlockDevice
 from repro.store.embcache import EmbeddingPageCache
 from repro.store.graphstore import GraphStore
 
@@ -121,3 +125,170 @@ def test_device_grow_relocation_invalidates_everything():
         assert k < 20000, "device never grew"
     np.testing.assert_array_equal(cached.get_embeds(vids),
                                   plain.get_embeds(vids))
+
+
+# ---------------------------------------------------------------- row path
+def _merged_pages(gs, vids):
+    """The merged covering page set of ``vids`` (embedding-space LPNs)."""
+    d = gs.feature_dim
+    lo = vids * d
+    p0, p1 = lo // SLOTS_PER_PAGE, -(-(lo + d) // SLOTS_PER_PAGE)
+    pages = np.unique(np.concatenate(
+        [np.arange(a, b) for a, b in zip(p0, p1)]))
+    return pages, lo, p0
+
+
+def _page_block_gather(gs, vids):
+    """``get_embeds`` by the page-block path: read the merged page set
+    into one block (through the cache when one is attached) and slice
+    each row out of it."""
+    d = gs.feature_dim
+    vids = np.asarray(vids, dtype=np.int64)
+    pages, lo, p0 = _merged_pages(gs, vids)
+    lpns = gs._emb_base + pages
+    block = (gs.cache.read_pages(gs.dev, lpns, tag="embed")
+             if gs.cache is not None
+             else gs.dev.read_pages(lpns, tag="embed"))
+    flat = block.reshape(-1)
+    start = np.searchsorted(pages, p0) * SLOTS_PER_PAGE \
+        + lo - p0 * SLOTS_PER_PAGE
+    return np.stack([flat[s:s + d] for s in start]).view(np.float32)
+
+
+def _cache_state(gs):
+    c = gs.cache
+    st = c.stats
+    return {"read_pages": gs.dev.stats.read_pages,
+            "read_bytes": gs.dev.stats.read_bytes,
+            "hits": st.hits, "misses": st.misses,
+            "evictions": st.evictions,
+            "bytes_from_cache": st.bytes_from_cache,
+            "bytes_from_dev": st.bytes_from_dev,
+            "lpn_slot": c._lpn_slot.tolist(),
+            "slot_lpn": c._slot_lpn.tolist(),
+            "last_use": c._last_use.tolist(),
+            "free": list(c._free)}
+
+
+def _embed_bytes():
+    return spans.snapshot().get("store.embed_bytes", {"n": 0})["n"]
+
+
+@pytest.mark.parametrize("d", [24, 100, 1000, 1500])
+def test_row_path_matches_page_block_path(d):
+    """Rows gathered straight from the slab and the page array are the
+    rows the page-block path slices, and the device, the cache's counters,
+    its LPN->slot map and its LRU order end exactly as that path leaves
+    them — cold, warm, partly warm, a scan larger than the cache, after
+    RMW invalidation and after device growth (at d=1500 a row spans two
+    or three pages)."""
+    cap = 8
+    n = 100 * SLOTS_PER_PAGE // d + 3              # ~100 pages, last partial
+    rng = np.random.default_rng(d)
+    table = rng.standard_normal((n, d)).astype(np.float32)
+    edges = np.array([[0, 1], [1, 2]], dtype=np.int64)
+    new, old = (GraphStore(BlockDevice(64), feature_dim=d) for _ in "ab")
+    for gs in (new, old):
+        gs.update_graph(edges, table)
+        gs.attach_cache(EmbeddingPageCache(cap))
+    rows_per_page = max(1, SLOTS_PER_PAGE // d)
+
+    def check(vids):
+        vids = np.asarray(vids, dtype=np.int64)
+        miss0 = old.cache.stats.misses
+        b0 = _embed_bytes()
+        got = new.get_embeds(vids)
+        want = _page_block_gather(old, vids)
+        np.testing.assert_array_equal(got.view(np.int32),
+                                      want.view(np.int32))
+        np.testing.assert_array_equal(got.view(np.int32),
+                                      table[vids].view(np.int32))
+        assert _cache_state(new) == _cache_state(old)
+        installed = min(old.cache.stats.misses - miss0, cap)
+        assert _embed_bytes() - b0 == \
+            len(vids) * d * 4 + installed * PAGE_BYTES
+
+    few = np.arange(0, 3 * rows_per_page, 2)       # a few pages
+    check(few)                                     # cold
+    assert new.cache.stats.misses and not new.cache.stats.hits
+    check(few[::-1])                               # warm: all hits
+    assert new.cache.stats.hits
+    check(np.concatenate([few, few + 4 * rows_per_page]))  # partly warm
+    check(rng.permutation(n))                      # scan >> capacity
+    assert new.cache.stats.evictions
+    check(rng.integers(0, n, 40))
+    row = rng.standard_normal(d).astype(np.float32)
+    for gs in (new, old):                          # RMW invalidation
+        gs.update_embed(n - 2, row)
+    table[n - 2] = row
+    check([n - 2, n - 1, 0])
+    block = rng.standard_normal((5, d)).astype(np.float32)
+    for gs in (new, old):
+        gs.write_embed_rows(1, block)
+    table[1:6] = block
+    check(np.arange(8))
+    pages0 = new.dev.num_pages
+    while new.dev.num_pages == pages0:             # device growth
+        for gs in (new, old):
+            gs.dev.alloc_front()
+    check(np.arange(0, n, 3))
+
+
+@pytest.mark.parametrize("case", ["cold", "partly_warm", "warm", "uncached"])
+def test_row_path_charges_latency_like_page_reads(case):
+    """Deferred simulated latency of a ``get_embeds`` equals that of
+    ``read_pages`` over the same missed pages (none when all hit)."""
+    d, n = 100, 2000
+    table = np.random.default_rng(5).standard_normal((n, d)) \
+        .astype(np.float32)
+    edges = np.array([[0, 1]], dtype=np.int64)
+    new, old = (GraphStore(BlockDevice(64), feature_dim=d) for _ in "ab")
+    for gs in (new, old):
+        gs.update_graph(edges, table)
+        if case != "uncached":
+            gs.attach_cache(EmbeddingPageCache(64))
+        gs.dev.simulate_latency = True
+        gs.dev.command_latency_us = 80.0
+        gs.dev.page_read_us = 30.0
+    vids = np.arange(0, 400, 3)
+    if case in ("partly_warm", "warm"):
+        warm = vids if case == "warm" else vids[: len(vids) // 2]
+        for gs in (new, old):
+            with gs.dev.defer_latency():
+                _page_block_gather(gs, warm)
+    with new.dev.defer_latency() as got:
+        new.get_embeds(vids)
+    misses = old.cache.stats.misses if old.cache is not None else 0
+    with old.dev.defer_latency() as want:
+        _page_block_gather(old, vids)
+    missed = (old.cache.stats.misses - misses if old.cache is not None
+              else len(_merged_pages(old, vids)[0]))
+    assert got.us == want.us
+    assert got.us == (0.0 if not missed else
+                      80.0 + 30.0 * missed / new.dev.channels)
+    assert (got.us == 0.0) == (case == "warm")
+
+
+def test_row_path_builds_no_page_block():
+    """The gather's peak host allocation scales with the rows it returns,
+    not with the P >> capacity pages they touch: no (P, 1024) block."""
+    n, d, cap, k = 200_000, 100, 64, 5000
+    table = np.random.default_rng(6).standard_normal((n, d)) \
+        .astype(np.float32)
+    gs = GraphStore(BlockDevice(64), feature_dim=d)
+    gs.update_graph(np.array([[0, 1]], dtype=np.int64), table)
+    gs.attach_cache(EmbeddingPageCache(cap))
+    rng = np.random.default_rng(7)
+    gs.get_embeds(rng.integers(0, n, k))           # warm every lazy path
+    vids = rng.integers(0, n, k)
+    n_pages = len(_merged_pages(gs, vids)[0])
+    tracemalloc.start()
+    try:
+        got = gs.get_embeds(vids)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    np.testing.assert_array_equal(got, table[vids])
+    bound = 4 * k * d * 4 + cap * PAGE_BYTES
+    assert n_pages * PAGE_BYTES > 2 * bound
+    assert peak < bound, (peak, bound)
