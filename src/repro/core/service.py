@@ -418,6 +418,8 @@ class HolisticGNNService:
             for l, blk in enumerate(batch.layers):
                 feeds[prog.feed_refs[1 + 2 * l]] = jnp.asarray(blk.nbr)
                 feeds[prog.feed_refs[2 + 2 * l]] = jnp.asarray(blk.mask)
+        spans.count("serve.h2d_bytes", batch.embeddings.nbytes + sum(
+            blk.nbr.nbytes + blk.mask.nbytes for blk in batch.layers))
         out = self.engine.run(prog.model, feeds, jit=jit)
         with spans.span("serve.slice"):
             return [{k: np.asarray(v)[off: off + n] for k, v in out.items()}

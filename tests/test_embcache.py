@@ -174,14 +174,15 @@ def _embed_bytes():
     return spans.snapshot().get("store.embed_bytes", {"n": 0})["n"]
 
 
-@pytest.mark.parametrize("d", [24, 100, 1000, 1500])
+@pytest.mark.parametrize("d", [24, 100, 1000, 1500, 8415])
 def test_row_path_matches_page_block_path(d):
     """Rows gathered straight from the slab and the page array are the
     rows the page-block path slices, and the device, the cache's counters,
     its LPN->slot map and its LRU order end exactly as that path leaves
     them — cold, warm, partly warm, a scan larger than the cache, after
     RMW invalidation and after device growth (at d=1500 a row spans two
-    or three pages)."""
+    or three pages; at d=8415, Coauthor-Physics' width, nine or ten, more
+    than the whole cache holds)."""
     cap = 8
     n = 100 * SLOTS_PER_PAGE // d + 3              # ~100 pages, last partial
     rng = np.random.default_rng(d)
@@ -192,6 +193,10 @@ def test_row_path_matches_page_block_path(d):
         gs.update_graph(edges, table)
         gs.attach_cache(EmbeddingPageCache(cap))
     rows_per_page = max(1, SLOTS_PER_PAGE // d)
+    if d == 8415:                     # every row spans more than the cache
+        lo = np.arange(n) * d
+        pages = (lo + d - 1) // SLOTS_PER_PAGE - lo // SLOTS_PER_PAGE + 1
+        assert set(pages.tolist()) == {9, 10} and cap < 9
 
     def check(vids):
         vids = np.asarray(vids, dtype=np.int64)

@@ -203,3 +203,35 @@ def test_served_path_spans_match_the_scheduler_counters():
     assert sum(d[name]["s"] for name in STAGES) <= d["serve.group"]["s"]
     for name in ("store.plan", "store.select"):
         assert d[name]["s"] <= d["store.hop"]["s"]
+
+
+def test_h2d_bytes_count_the_padded_table_and_blocks(monkeypatch):
+    """``serve.h2d_bytes`` grows by the bytes of the padded feature table
+    and of every layer's neighbour and mask block of the group."""
+    from repro.serve import batcher
+    svc, n = _service()
+    fanouts = [5, 5]
+    params = gnn.init_params("gcn", [32, 16, 8], seed=1)
+    dfg = make_service_dfg("gcn", 2, fanouts).save()
+    weights = {k: v for k, v in
+               gnn.dfg_feeds("gcn", params, None, []).items() if k != "H"}
+    padded = []
+    pad_group = batcher.pad_group
+
+    def keep(batch, base):
+        padded.append(pad_group(batch, base))
+        return padded[-1]
+    monkeypatch.setattr(batcher, "pad_group", keep)
+    rng = np.random.default_rng(5)
+    requests = [{"targets": rng.integers(0, n, 7).tolist(), "seed": i}
+                for i in range(3)]
+    before = spans.snapshot()
+    svc.run_batch(dfg, requests, weights=weights)
+    after = spans.snapshot()
+    (batch,) = padded
+    rows = len(batch.node_vids)
+    assert rows > _delta(before, after, "serve.rows_real")["n"]  # padded
+    want = rows * 32 * 4 + sum(blk.nbr.shape[0] * blk.nbr.shape[1] * 8
+                               for blk in batch.layers)
+    assert _delta(before, after, "serve.h2d_bytes") == {"n": want, "s": 0.0}
+    svc.close()

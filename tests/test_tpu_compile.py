@@ -1,6 +1,7 @@
 """The served path's Pallas kernels and the engine's jitted GCN step compile
 for a TPU v5e (described, not attached) at the largest super-batch bucket the
-continuous batcher emits, with F=420, the widest feature table served.
+continuous batcher emits: the kernels and the step with F=420, and the step
+at Coauthor-Physics' widths [8415, 64, 5], the widest feature table served.
 
 The topology and every sharding built from it live in module-scoped
 fixtures: describing the topology loads the TPU compiler library, which one
@@ -89,7 +90,9 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_cache):
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
-def test_engine_gcn_step_compiles_for_v5e(one_chip, no_cache, monkeypatch):
+def _compiled_gcn_step(monkeypatch, one_chip, widths, n, d0, d1):
+    """The engine's jitted two-layer GCN step at ``widths`` over an
+    ``n``-row table and ``d0``/``d1`` destination rows, compiled."""
     # JAX_PLATFORMS=cpu resolves kernels to interpret mode; the step is
     # compiled as the TPU backend would resolve it
     monkeypatch.setattr(config, "default_interpret", lambda: False)
@@ -97,15 +100,35 @@ def test_engine_gcn_step_compiles_for_v5e(one_chip, no_cache, monkeypatch):
     program_config(svc.xbuilder, "hetero")
     prog = split_service_dfg(make_service_dfg("gcn", 2, list(FANOUTS)))
     h, nbr0, mask0, nbr1, mask1 = prog.feed_refs
-    shapes = {h: ((N, F), jnp.float32),
-              nbr0: ((D0, K), jnp.int32), mask0: ((D0, K), jnp.float32),
-              nbr1: ((D1, K), jnp.int32), mask1: ((D1, K), jnp.float32),
-              "W0": ((F, HIDDEN), jnp.float32), "b0": ((HIDDEN,), jnp.float32),
-              "W1": ((HIDDEN, HIDDEN), jnp.float32),
-              "b1": ((HIDDEN,), jnp.float32)}
+    f, hid, out = widths
+    shapes = {h: ((n, f), jnp.float32),
+              nbr0: ((d0, K), jnp.int32), mask0: ((d0, K), jnp.float32),
+              nbr1: ((d1, K), jnp.int32), mask1: ((d1, K), jnp.float32),
+              "W0": ((f, hid), jnp.float32), "b0": ((hid,), jnp.float32),
+              "W1": ((hid, out), jnp.float32),
+              "b1": ((out,), jnp.float32)}
     feeds = {r: jax.ShapeDtypeStruct(*s, sharding=one_chip)
              for r, s in shapes.items()}
     fn, refs = svc.engine.jit_program(prog.model, feeds)
-    text = fn.lower(*(feeds[r] for r in refs)).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2      # both fused GCN layers
+    compiled = fn.lower(*(feeds[r] for r in refs)).compile()
     svc.close()
+    return compiled
+
+
+def test_engine_gcn_step_compiles_for_v5e(one_chip, no_cache, monkeypatch):
+    text = _compiled_gcn_step(monkeypatch, one_chip, (F, HIDDEN, HIDDEN),
+                              N, D0, D1).as_text()
+    assert text.count("tpu_custom_call") >= 2      # both fused GCN layers
+
+
+def test_engine_gcn_step_compiles_for_v5e_at_physics_widths(
+        one_chip, no_cache, monkeypatch):
+    """Coauthor-Physics' [8415, 64, 5] at the full group's bucket: layer
+    0's AggCombine gathers 8,448-lane rows, 8 destination rows a step, and
+    the step fits one chip's 16 GB with room to spare."""
+    compiled = _compiled_gcn_step(monkeypatch, one_chip, (8415, 64, 5),
+                                  N, D0, D1)
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= N * 8415 * 4
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4 << 30
