@@ -104,63 +104,6 @@ class _IngestSession:
         return {c for c, _r in self.class_pairs}
 
 
-# ------------------------------------------------------------ plan packing
-def pack_plan(desc: list) -> dict:
-    """Array-pack a ``fetch_plan`` descriptor list for the wire.
-
-    A desc entry is ``None`` / ``("L", row, start, end)`` /
-    ``("H", rows, counts)``; shipping them as JSON tuples would put
-    O(vids) structure in the packet header, so they are flattened into a
-    handful of ndarray payload buffers instead (the header stays O(1)).
-    """
-    l_idx, l_row, l_start, l_end = [], [], [], []
-    h_idx, h_len, h_rows, h_counts = [], [], [], []
-    for i, d in enumerate(desc):
-        if d is None:
-            continue
-        if d[0] == "L":
-            l_idx.append(i)
-            l_row.append(int(d[1]))
-            l_start.append(int(d[2]))
-            l_end.append(int(d[3]))
-        else:
-            h_idx.append(i)
-            h_len.append(len(d[1]))
-            h_rows.append(np.asarray(d[1], dtype=np.int64))
-            h_counts.append(np.asarray(d[2], dtype=np.int64))
-    return {
-        "n": len(desc),
-        "l_idx": np.asarray(l_idx, dtype=np.int64),
-        "l_row": np.asarray(l_row, dtype=np.int64),
-        "l_start": np.asarray(l_start, dtype=np.int64),
-        "l_end": np.asarray(l_end, dtype=np.int64),
-        "h_idx": np.asarray(h_idx, dtype=np.int64),
-        "h_len": np.asarray(h_len, dtype=np.int64),
-        "h_rows": (np.concatenate(h_rows) if h_rows
-                   else np.empty(0, dtype=np.int64)),
-        "h_counts": (np.concatenate(h_counts) if h_counts
-                     else np.empty(0, dtype=np.int64)),
-    }
-
-
-def unpack_plan(packed: dict) -> list:
-    """Inverse of ``pack_plan`` — reconstructs the descriptor list."""
-    desc: list = [None] * int(packed["n"])
-    for i, row, start, end in zip(np.asarray(packed["l_idx"]).tolist(),
-                                  np.asarray(packed["l_row"]).tolist(),
-                                  np.asarray(packed["l_start"]).tolist(),
-                                  np.asarray(packed["l_end"]).tolist()):
-        desc[i] = ("L", row, start, end)
-    h_rows = np.asarray(packed["h_rows"], dtype=np.int64)
-    h_counts = np.asarray(packed["h_counts"], dtype=np.int64)
-    off = 0
-    for i, ln in zip(np.asarray(packed["h_idx"]).tolist(),
-                     np.asarray(packed["h_len"]).tolist()):
-        desc[i] = ("H", h_rows[off: off + ln], h_counts[off: off + ln])
-        off += ln
-    return desc
-
-
 def clone_dev_profile(old: BlockDevice) -> BlockDevice:
     """A fresh replacement device with the failed one's perf profile."""
     return BlockDevice(
@@ -194,8 +137,8 @@ class ShardService:
         self._ingest: _IngestSession | None = None
 
     # ------------------------------------------------------ batched fetch
-    def fetch(self, l_vids=None, h_vids=None, h_pgs=None, emb_rows=None,
-              pack: bool = False) -> dict:
+    def fetch(self, l_vids=None, h_vids=None, h_pgs=None,
+              emb_rows=None) -> dict:
         """ONE batched read command covering every page the coordinator
         needs from this shard: an adjacency plan fetch (``l_vids``),
         explicit H-chain page reads (``h_vids``/``h_pgs``, the replicated
@@ -204,14 +147,12 @@ class ShardService:
         deferred-latency account whose total ships back as ``io_us`` so
         the coordinator can pay max over shards.  This is why per-shard
         RPC count is O(1) per batched read, never O(pages)."""
-        out: dict = {"block": None, "desc": None, "hblk": None, "emb": None}
+        out: dict = {"block": None, "plan": None, "hblk": None, "emb": None}
         store = self.store
         with store.dev.defer_latency() as acct:
             if l_vids is not None and len(l_vids):
-                block, desc = store.fetch_plan(
+                out["block"], out["plan"] = store.fetch_plan(
                     np.asarray(l_vids, dtype=np.int64))
-                out["block"] = block
-                out["desc"] = pack_plan(desc) if pack else desc
             if h_vids is not None and len(h_vids):
                 out["hblk"] = store.chain_pages(
                     np.asarray(h_vids, dtype=np.int64),
@@ -965,7 +906,7 @@ class LocalShardEndpoint(ShardEndpoint):
 
     def fetch_submit(self, **kw):
         """Batched read, computed inline (see ``call_submit``)."""
-        return self.call("fetch", pack=False, **kw)
+        return self.call("fetch", **kw)
 
     def fetch_result(self, handle) -> dict:
         """Handles ARE results in-process."""
@@ -1079,19 +1020,16 @@ class RopShardEndpoint(ShardEndpoint):
             self._map_error(e)
 
     def fetch_submit(self, **kw):
-        """Submit one packed batched-read command (awaited via
-        ``fetch_result``; plans travel packed over the wire)."""
-        return self.client.submit("fetch", pack=True, **kw)
+        """Submit one batched-read command (awaited via ``fetch_result``;
+        its plan is arrays, so it crosses the wire as payload buffers)."""
+        return self.client.submit("fetch", **kw)
 
     def fetch_result(self, handle) -> dict:
-        """Await a fetch completion and unpack its plan descriptor."""
+        """Await a fetch completion."""
         try:
-            out = self.client.result(handle)
+            return self.client.result(handle)
         except RuntimeError as e:
             self._map_error(e)
-        if out["desc"] is not None:
-            out["desc"] = unpack_plan(out["desc"])
-        return out
 
     def peer_link(self):
         """A client another shard host can pull rebuild chunks through —

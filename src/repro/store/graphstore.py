@@ -24,7 +24,6 @@ the bulk latency ~= data transfer + embedding write.
 """
 from __future__ import annotations
 
-import bisect
 import threading
 import time
 from dataclasses import dataclass, field
@@ -44,6 +43,9 @@ H_CAP = SLOTS_PER_PAGE - _H_DATA          # neighbors per H page
 _L_NNODES = SLOTS_PER_PAGE - 1
 _L_DATALEN = SLOTS_PER_PAGE - 2
 
+# gmap's selector codes in its array twin (``GraphStore._sel``)
+_KIND = {"L": 1, "H": 2}
+
 
 def _l_meta_vid(i: int) -> int:
     return SLOTS_PER_PAGE - 3 - 2 * i
@@ -53,32 +55,97 @@ def _l_meta_off(i: int) -> int:
     return SLOTS_PER_PAGE - 4 - 2 * i
 
 
-def neighbors_from_plan(vids_arr: np.ndarray, block, desc) -> list[np.ndarray]:
+# A fetch plan locates every position of a frontier in the block of pages
+# one fetch read, as int64 arrays (entries in any order; a position absent
+# from both l_idx and h_idx is an unknown vid):
+#   l_idx, l_row, l_start, l_end   L position: chunk block[l_row, l_start:l_end]
+#   h_idx, h_len                   H position: its chain's page count
+#   h_rows, h_counts               the H entries' chain pages, concatenated in
+#                                  entry order: block row, neighbors on it
+PLAN_FIELDS = ("l_idx", "l_row", "l_start", "l_end",
+               "h_idx", "h_len", "h_rows", "h_counts")
+
+
+def make_plan(**fields) -> dict:
+    """A plan with the given fields and every other field empty."""
+    plan = {k: np.empty(0, dtype=np.int64) for k in PLAN_FIELDS}
+    plan.update(fields)
+    return plan
+
+
+def merge_plans(parts) -> dict:
+    """One plan over a concatenated block out of ``(plan, pos, row_off)``
+    parts: each part's positions map through ``pos`` and its block rows
+    shift by ``row_off``.  The sharded and replicated coordinators build
+    one global plan this way from their per-shard fetches."""
+    cols: dict = {k: [] for k in PLAN_FIELDS}
+    for plan, pos, row_off in parts:
+        for k in PLAN_FIELDS:
+            v = np.asarray(plan[k], dtype=np.int64)
+            if k in ("l_idx", "h_idx"):
+                v = pos[v]
+            elif k in ("l_row", "h_rows"):
+                v = v + row_off
+            cols[k].append(v)
+    return make_plan(**{k: np.concatenate(v) for k, v in cols.items() if v})
+
+
+def _plan_lens(plan, npos: int):
+    """Per-position neighbor counts, each H entry's first page in
+    ``h_rows`` and neighbor total, and the exclusive running count of
+    neighbors over every chain page (``cum0[j]``: neighbors before page
+    ``j``)."""
+    h_len = plan["h_len"]
+    cum0 = np.concatenate([[0], np.cumsum(plan["h_counts"])])
+    h_first = np.cumsum(h_len) - h_len
+    h_tot = cum0[h_first + h_len] - cum0[h_first]
+    lens = np.zeros(npos, dtype=np.int64)
+    lens[plan["l_idx"]] = plan["l_end"] - plan["l_start"]
+    lens[plan["h_idx"]] = h_tot
+    return lens, h_first, h_tot, cum0
+
+
+def _whole_rows(plan, take, out_offs, lens, h_tot):
+    """``(dst, src)`` slot pairs that copy every neighbor of the positions
+    in ``take`` (a mask) to the output at ``out_offs``: ``src`` indexes the
+    flattened block, L chunks and H chain pages alike."""
+    l_idx, h_idx = plan["l_idx"], plan["h_idx"]
+    lt = take[l_idx]
+    ll = lens[l_idx[lt]]
+    l_src = np.repeat(plan["l_row"][lt] * SLOTS_PER_PAGE
+                      + plan["l_start"][lt], ll) + _ramp(ll)
+    l_dst = np.repeat(out_offs[l_idx[lt]], ll) + _ramp(ll)
+    ht = take[h_idx]
+    hl = h_tot[ht]
+    pg = np.repeat(ht, plan["h_len"])           # chain pages of taken hubs
+    cnt = plan["h_counts"][pg]
+    h_src = np.repeat(plan["h_rows"][pg] * SLOTS_PER_PAGE + _H_DATA,
+                      cnt) + _ramp(cnt)
+    h_dst = np.repeat(out_offs[h_idx[ht]], hl) + _ramp(hl)
+    return np.concatenate([l_dst, h_dst]), np.concatenate([l_src, h_src])
+
+
+def neighbors_from_plan(vids_arr: np.ndarray, block, plan) -> list[np.ndarray]:
     """Materialise per-vid neighbor arrays out of a fetched plan.
 
     Shared back half of the batched GetNeighbors: the single-device store
     feeds it one ``_fetch_plan`` result; the sharded coordinator feeds it a
-    recomposition of N per-shard plans (descriptor rows re-based into the
-    concatenated block) — either way the output equals ``get_neighbors``
-    per vid.
+    ``merge_plans`` of N per-shard plans — either way the output equals
+    ``get_neighbors`` per vid.  One flat gather, then one split.
     """
-    out: list = [None] * len(vids_arr)
-    for pos, d in enumerate(desc):
-        if d is None:
-            out[pos] = np.empty(0, dtype=SLOT_DTYPE)
-        elif d[0] == "L":
-            _, row, start, end = d
-            out[pos] = block[row, start:end].copy()
-        else:
-            _, rows, counts = d
-            got = [block[r, _H_DATA: _H_DATA + int(c)]
-                   for r, c in zip(rows, counts)]
-            out[pos] = (np.concatenate(got) if got
-                        else np.empty(0, dtype=SLOT_DTYPE))
-    return out
+    npos = len(vids_arr)
+    if not npos:
+        return []
+    lens, _, h_tot, _ = _plan_lens(plan, npos)
+    offs = np.cumsum(lens) - lens
+    flat = np.empty(int(lens.sum()), dtype=SLOT_DTYPE)
+    dst, src = _whole_rows(plan, np.ones(npos, dtype=bool), offs, lens, h_tot)
+    if len(src):
+        flat[dst] = block.reshape(-1)[src]
+    return np.split(flat, offs[1:])
 
 
-def select_from_plan(vids_arr: np.ndarray, block, desc, fanout: int,
+def select_from_plan(vids_arr: np.ndarray, block, plan, fanout: int,
                      rng: np.random.Generator | None = None, *,
                      segments=None, rngs=None):
     """Fanout selection over a fetched plan — the back half of the fused
@@ -88,49 +155,27 @@ def select_from_plan(vids_arr: np.ndarray, block, desc, fanout: int,
     their chain page counts, uniform draws are consumed one ``fanout``
     block per over-full vertex in frontier order (or per-request segment
     order when ``segments``/``rngs`` are given).  The sharded coordinator
-    recomposes N per-shard plans into one global (block, desc) and runs
-    this same code, which is why an N-shard sample is bit-identical to the
-    single-device sample under the same seed.
+    merges N per-shard plans into one global (block, plan) and runs this
+    same code, which is why an N-shard sample is bit-identical to the
+    single-device sample under the same seed.  Whole-frontier array math
+    throughout: no step runs per vertex or per hub.
     """
     flatb = block.reshape(-1) if block is not None else None
     npos = len(vids_arr)
-
-    # numeric plan arrays (pure-int loop; all math below is vector)
-    lens = np.zeros(npos, dtype=np.int64)
-    is_l = np.zeros(npos, dtype=bool)
-    base = np.zeros(npos, dtype=np.int64)   # L: flat addr of chunk
-    for pos, d in enumerate(desc):
-        if d is None:
-            continue
-        if d[0] == "L":
-            is_l[pos] = True
-            lens[pos] = d[3] - d[2]
-            base[pos] = d[1] * SLOTS_PER_PAGE + d[2]
-        else:
-            lens[pos] = int(d[2].sum())
+    lens, h_first, h_tot, cum0 = _plan_lens(plan, npos)
     over = lens > fanout
-    lens_sel = np.where(lens == 0, 1, np.minimum(lens, fanout))
-    out_offs = np.concatenate([[0], np.cumsum(lens_sel)[:-1]])
+    empty = lens == 0
+    lens_sel = np.where(empty, 1, np.minimum(lens, fanout))
+    out_offs = np.cumsum(lens_sel) - lens_sel
     sel = np.empty(int(lens_sel.sum()), dtype=SLOT_DTYPE)
 
     # degenerate rows: self-loop
-    empty = lens == 0
     sel[out_offs[empty]] = vids_arr[empty]
 
-    # under-full rows copied through (one flat gather; H multi-chunk
-    # under-full rows are rare — degree <= fanout but H-mapped)
-    for cls in np.nonzero(~over & ~empty & ~is_l)[0]:
-        _, rows, counts = desc[cls]
-        o, c0 = int(out_offs[cls]), 0
-        for r, c in zip(rows, counts):
-            sel[o + c0: o + c0 + int(c)] = \
-                block[r, _H_DATA: _H_DATA + int(c)]
-            c0 += int(c)
-    ul = ~over & ~empty & is_l
-    if ul.any():
-        lv = lens[ul]
-        src = np.repeat(base[ul], lv) + _ramp(lv)
-        sel[np.repeat(out_offs[ul], lv) + _ramp(lv)] = flatb[src]
+    # under-full rows, L chunks and H chains alike: one flat gather
+    dst, src = _whole_rows(plan, ~over & ~empty, out_offs, lens, h_tot)
+    if len(src):
+        sel[dst] = flatb[src]
 
     # over-full rows: Floyd by index, vectorized across the frontier
     # (k steps of whole-row vector math, no per-vertex python)
@@ -153,21 +198,25 @@ def select_from_plan(vids_arr: np.ndarray, block, desc, fanout: int,
                 t = np.where(dup, m_arr - fanout + j2, t)
             idx[:, j2] = t
         over_pos = np.nonzero(over)[0]
-        ol = over & is_l
-        if ol.any():
-            ol_in_over = is_l[over_pos]
-            src = base[ol][:, None] + idx[ol_in_over]
-            dst = out_offs[ol][:, None] + np.arange(fanout)[None, :]
-            sel[dst.reshape(-1)] = flatb[src.reshape(-1)]
-        for r_i, cls in enumerate(over_pos):
-            if is_l[cls]:
-                continue
-            _, rows, counts = desc[cls]      # hub: index by page
-            cum = np.cumsum(counts)
-            p = np.searchsorted(cum, idx[r_i], side="right")
-            off = idx[r_i] - np.where(p > 0, cum[p - 1], 0)
-            o = int(out_offs[cls])
-            sel[o: o + fanout] = block[rows[p], _H_DATA + off]
+        # drawn index -> flat block slot.  L: chunk base + index.  Hub: the
+        # index is global over every chain page once the hub's first page's
+        # running count is added; one searchsorted finds (page, slot).
+        base = np.zeros(npos, dtype=np.int64)
+        base[plan["l_idx"]] = (plan["l_row"] * SLOTS_PER_PAGE
+                               + plan["l_start"])
+        ent = np.full(npos, -1, dtype=np.int64)
+        ent[plan["h_idx"]] = np.arange(len(plan["h_idx"]))
+        e = ent[over_pos]
+        hub = e >= 0
+        src = base[over_pos][:, None] + idx
+        if hub.any():
+            g = cum0[h_first[e[hub]]][:, None] + idx[hub]
+            p = np.searchsorted(cum0[1:], g, side="right")
+            src[hub] = (plan["h_rows"][p] * SLOTS_PER_PAGE + _H_DATA
+                        + g - cum0[p])
+        sel[(out_offs[over_pos][:, None]
+             + np.arange(fanout)[None, :]).reshape(-1)] = \
+            flatb[src.reshape(-1)]
     return sel, lens_sel
 
 
@@ -203,8 +252,21 @@ class GraphStore:
         # the tail pointer): lets batched GetNeighbors fetch whole chains in
         # one queued read instead of one pointer-chase round per page.
         self.h_chain: dict[int, list[int]] = {}
-        self._l_keys: list[int] = []                   # sorted max-vid per L page
-        self._l_lpns: list[int] = []                   # parallel LPN list
+        # Array twins of the mapping tables, kept up to date by every site
+        # that changes them (``_map``, ``_map_chain``, the L-table inserts),
+        # so a frontier is planned with whole-array lookups:
+        #   _sel[vid]          gmap as a dense selector: 0 none, 1 L, 2 H
+        #   _chain_at/_n[vid]  vid's chain in _pool (length 0 unless H)
+        #   _pool              chain LPNs; a rewritten chain leaves its old
+        #                      copy behind until a compaction
+        self._sel = np.zeros(0, dtype=np.int8)
+        self._chain_at = np.zeros(0, dtype=np.int64)
+        self._chain_n = np.zeros(0, dtype=np.int64)
+        self._pool = np.empty(0, dtype=np.int64)
+        self._pool_top = 0                             # slots of _pool in use
+        self._pool_live = 0                            # of which live chains
+        self._l_keys = np.empty(0, dtype=np.int64)    # sorted max-vid per L page
+        self._l_lpns = np.empty(0, dtype=np.int64)    # parallel LPN array
         self.feature_dim = int(feature_dim)
         self._emb_base: int | None = None              # first LPN of embedding span
         self._emb_rows = 0
@@ -250,6 +312,87 @@ class GraphStore:
                 return self.cache.read_pages(self.dev, lpns, tag="graph")
             return self.dev.read_pages(lpns, tag="graph")
 
+    # ========================================================= mapping tables
+    def _cover(self, top: int) -> None:
+        """Grow the per-vid tables to cover every vid below ``top``."""
+        n = len(self._sel)
+        if top <= n:
+            return
+        pad = max(top, 2 * n) - n
+        self._sel = np.concatenate([self._sel, np.zeros(pad, np.int8)])
+        self._chain_at = np.concatenate([self._chain_at,
+                                         np.zeros(pad, np.int64)])
+        self._chain_n = np.concatenate([self._chain_n,
+                                        np.zeros(pad, np.int64)])
+
+    def _map(self, vids, kind: str | None) -> None:
+        """Map ``vids`` as ``kind`` ('H' or 'L'), or unmap them (``None``),
+        in ``gmap`` and its selector twin ``_sel``.  An H vid's chain is
+        recorded apart (``_map_chain``)."""
+        vids = np.asarray(vids, dtype=np.int64).reshape(-1)
+        if not len(vids):
+            return
+        if kind is None:
+            for v in vids.tolist():
+                self.gmap.pop(v, None)
+        else:
+            self.gmap.update(dict.fromkeys(vids.tolist(), kind))
+        self._cover(int(vids.max()) + 1)
+        self._sel[vids] = _KIND.get(kind, 0)
+
+    def _map_chain(self, vid: int, chain: list[int]) -> None:
+        """Record ``chain`` as ``vid``'s H chain in ``h_chain`` and its
+        twin: the LPNs go to the end of ``_pool`` and ``vid``'s table row
+        points at them.  Once superseded copies outweigh the live chains,
+        a full pool is compacted instead of grown."""
+        self.h_chain[vid] = chain
+        self._cover(vid + 1)
+        self._pool_live += len(chain) - int(self._chain_n[vid])
+        top = self._pool_top + len(chain)
+        if top > len(self._pool):
+            if self._pool_top > 2 * self._pool_live:
+                self._compact_pool()
+                return
+            grown = np.empty(max(top, 2 * len(self._pool), 1024), np.int64)
+            grown[: self._pool_top] = self._pool[: self._pool_top]
+            self._pool = grown
+        self._pool[self._pool_top: top] = chain
+        self._chain_at[vid] = self._pool_top
+        self._chain_n[vid] = len(chain)
+        self._pool_top = top
+
+    def _unmap_chain(self, vid: int) -> None:
+        """Drop ``vid``'s H chain from ``h_chain`` and its twin."""
+        if self.h_chain.pop(vid, None) is not None:
+            self._pool_live -= int(self._chain_n[vid])
+            self._chain_n[vid] = 0
+
+    def _compact_pool(self) -> None:
+        """Rewrite ``_pool`` with the live chains only (``h_chain``)."""
+        pool = np.empty(max(2 * self._pool_live, 1024), np.int64)
+        at = 0
+        for v, chain in self.h_chain.items():
+            pool[at: at + len(chain)] = chain
+            self._chain_at[v] = at
+            self._chain_n[v] = len(chain)
+            at += len(chain)
+        self._pool, self._pool_top = pool, at
+
+    def _kinds(self, vids: np.ndarray) -> np.ndarray:
+        """Selector code of each vid (0 for a vid this store never held)."""
+        sel = self._sel
+        inside = (vids >= 0) & (vids < len(sel))
+        out = np.zeros(len(vids), dtype=np.int8)
+        out[inside] = sel[vids[inside]]
+        return out
+
+    def _chain_lens(self, vids: np.ndarray) -> np.ndarray:
+        """H-chain page count of each vid (0 unless H-mapped here)."""
+        out = np.zeros(len(vids), dtype=np.int64)
+        h = self._kinds(vids) == _KIND["H"]
+        out[h] = self._chain_n[vids[h]]
+        return out
+
     # ================================================================= helpers
     def _classify(self, degree: int) -> str:
         return "H" if degree > self.h_threshold else "L"
@@ -289,10 +432,10 @@ class GraphStore:
 
     def _l_lookup_page(self, vid: int) -> tuple[int, np.ndarray] | None:
         """Range search the L table (paper Fig. 8): first key >= vid."""
-        k = bisect.bisect_left(self._l_keys, vid)
+        k = int(np.searchsorted(self._l_keys, vid))
         if k == len(self._l_keys):
             return None
-        lpn = self._l_lpns[k]
+        lpn = int(self._l_lpns[k])
         return lpn, self.dev.read_page(lpn).copy()
 
     # ============================================================ bulk ingest
@@ -373,12 +516,14 @@ class GraphStore:
         l_vids = np.nonzero((degrees > 0) & (degrees <= self.h_threshold))[0]
 
         # ---- H-type: per-vertex page chains
+        self._map(h_vids, "H")
         for vid in h_vids:
             nbrs = indices[indptr[vid]: indptr[vid + 1]]
-            self.gmap[int(vid)] = "H"
             self._write_h_chain(int(vid), nbrs)
 
         # ---- L-type: greedy packing in ascending VID order (cumsum splits)
+        keys: list[int] = []
+        lpns: list[int] = []
         if len(l_vids):
             sizes = degrees[l_vids] + 2                      # data + 2 meta slots
             csum = np.concatenate([[0], np.cumsum(sizes)])
@@ -397,13 +542,17 @@ class GraphStore:
                     page[dlen: dlen + len(nbrs)] = nbrs
                     dlen += len(nbrs)
                     cnt += 1
-                    self.gmap[int(vid)] = "L"
                 page[_L_NNODES] = cnt
                 page[_L_DATALEN] = dlen
                 self.dev.write_page(lpn, page)
-                self._l_keys.append(int(l_vids[hi - 1]))
-                self._l_lpns.append(lpn)
+                keys.append(int(l_vids[hi - 1]))
+                lpns.append(lpn)
                 start = hi
+        self._map(l_vids, "L")
+        self._l_keys = np.concatenate(
+            [self._l_keys, np.asarray(keys, dtype=np.int64)])
+        self._l_lpns = np.concatenate(
+            [self._l_lpns, np.asarray(lpns, dtype=np.int64)])
 
     # ================================================================ queries
     def get_neighbors(self, vid: int) -> np.ndarray:
@@ -436,46 +585,40 @@ class GraphStore:
 
         Plans the whole request from the in-DRAM mapping tables (L range
         table + H chain lists), fetches every needed page with a single
-        queued scatter-read, and locates each vid's data:
-
-        Returns ``(block, desc)`` with ``desc[i]`` one of
-          * ``None``                       — unknown vid,
-          * ``("L", row, start, end)``     — chunk slice of ``block[row]``,
-          * ``("H", rows, counts)``        — chain page rows + chunk counts.
+        queued scatter-read, and locates each vid's data.  Returns
+        ``(block, plan)``: the fetched pages (``None`` when none was
+        needed) and the array plan over them (see ``PLAN_FIELDS``).
         """
-        h_items: list[tuple[int, int]] = []     # (position, vid)
-        l_pos: list[int] = []
-        l_vids: list[int] = []
-        desc: list = [None] * len(vids_arr)
-        for pos, v in enumerate(vids_arr.tolist()):
-            kind = self.gmap.get(v)
-            if kind == "H":
-                h_items.append((pos, v))
-            elif kind == "L":
-                l_pos.append(pos)
-                l_vids.append(v)
+        kind = self._kinds(vids_arr)
+        h_pos = np.flatnonzero(kind == _KIND["H"])
+        l_pos = np.flatnonzero(kind == _KIND["L"])
+        hv = vids_arr[h_pos]
+        h_len = self._chain_n[hv]
+        h_lpn = self._pool[np.repeat(self._chain_at[hv], h_len)
+                           + _ramp(h_len)]
 
-        keys = np.asarray(self._l_keys, dtype=np.int64)
-        lq = np.asarray(l_vids, dtype=np.int64)
-        k = np.searchsorted(keys, lq)           # first key >= vid
-        miss = k == len(keys)
-        l_lpns = sorted({self._l_lpns[ki] for ki in k[~miss].tolist()})
-        h_lpns = sorted({lpn for _, vid in h_items
-                         for lpn in self.h_chain[vid]})
+        lq = vids_arr[l_pos]
+        k = np.searchsorted(self._l_keys, lq)   # first key >= vid
+        paged = k < len(self._l_keys)
+        l_pos, lq = l_pos[paged], lq[paged]
+        q_lpn = self._l_lpns[k[paged]]          # each L vid's page
+        l_lpns = np.unique(q_lpn)
+        h_lpns = np.unique(h_lpn)
 
-        lpns = l_lpns + h_lpns                  # ONE queued scatter-read
-        if not lpns:
-            return None, desc
+        lpns = np.concatenate([l_lpns, h_lpns])  # ONE queued scatter-read
+        if not len(lpns):
+            return None, make_plan()
         block = self._read_graph_pages(lpns)
-        row_of = {lpn: i for i, lpn in enumerate(lpns)}
 
+        h_rows = len(l_lpns) + np.searchsorted(h_lpns, h_lpn)
+        plan = make_plan(h_idx=h_pos, h_len=h_len, h_rows=h_rows,
+                         h_counts=block[h_rows, _H_COUNT].astype(np.int64))
         if len(lq):
-            self._l_locate_batch(block, row_of, l_pos, lq, k, miss, desc)
-        for pos, vid in h_items:
-            rows = np.array([row_of[lpn] for lpn in self.h_chain[vid]],
-                            dtype=np.int64)
-            desc[pos] = ("H", rows, block[rows, _H_COUNT].astype(np.int64))
-        return block, desc
+            q_row = np.searchsorted(l_lpns, q_lpn)
+            found, start, end = self._l_locate_batch(block, q_row, lq)
+            plan.update(l_idx=l_pos[found], l_row=q_row[found],
+                        l_start=start[found], l_end=end[found])
+        return block, plan
 
     def get_neighbors_batch(self, vids) -> list[np.ndarray]:
         """Batched GetNeighbors — the near-storage fast path.
@@ -490,8 +633,8 @@ class GraphStore:
         for unknown VIDs), each equal to ``get_neighbors(vid)``.
         """
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
-        block, desc = self.fetch_plan(vids_arr)
-        return neighbors_from_plan(vids_arr, block, desc)
+        block, plan = self.fetch_plan(vids_arr)
+        return neighbors_from_plan(vids_arr, block, plan)
 
     def fetch_plan(self, vids_arr):
         """Locked plan fetch over vids this store holds — the *fetch* phase
@@ -511,11 +654,11 @@ class GraphStore:
         chain pages to shards; this is the device-side command that
         serves a shard's share of them."""
         with self._lock:
-            lpns = np.fromiter(
-                (self.h_chain[int(v)][int(p)]
-                 for v, p in zip(vids.tolist(), pgs.tolist())),
-                dtype=np.int64, count=len(vids))
-            return self._read_graph_pages(lpns)
+            vids = np.asarray(vids, dtype=np.int64)
+            if np.any(pgs >= self._chain_lens(vids)):
+                raise KeyError("chain page not held by this store")
+            return self._read_graph_pages(
+                self._pool[self._chain_at[vids] + pgs])
 
     def plan_info(self, vids: np.ndarray) -> dict:
         """Planning metadata for a batch of vids, no page I/O: per-vid
@@ -525,12 +668,9 @@ class GraphStore:
         replica-spread fetch with ONE call per vertex class instead of
         reaching into ``h_chain``/``_l_keys`` directly."""
         with self._lock:
-            chain_len = np.fromiter(
-                (len(self.h_chain.get(int(v), ())) for v in vids.tolist()),
-                dtype=np.int64, count=len(vids))
-            if self._l_keys:
-                l_page = np.searchsorted(
-                    np.asarray(self._l_keys, dtype=np.int64), vids)
+            chain_len = self._chain_lens(vids)
+            if len(self._l_keys):
+                l_page = np.searchsorted(self._l_keys, vids)
             else:
                 l_page = np.full(len(vids), -1, dtype=np.int64)
             return {"chain_len": chain_len,
@@ -547,7 +687,7 @@ class GraphStore:
         with self._lock:
             if self.gmap.get(vid) == "H":
                 old, _ = self.h_table.pop(vid)
-                self.h_chain.pop(vid, None)
+                self._unmap_chain(vid)
                 while old >= 0:
                     pg = self.dev.read_page(old)
                     nxt = int(pg[_H_NEXT])
@@ -560,8 +700,8 @@ class GraphStore:
                     else -1
                 self.dev.write_page(lpn, page)
             self.h_table[vid] = (new_lpns[0], new_lpns[-1])
-            self.h_chain[vid] = new_lpns
-            self.gmap[vid] = "H"
+            self._map_chain(vid, new_lpns)
+            self._map([vid], "H")
             self.stats.pages_h += len(new_lpns)
 
     def import_l_vertex(self, vid: int, nbrs: np.ndarray) -> None:
@@ -574,24 +714,24 @@ class GraphStore:
             kind = self.gmap.get(vid)
             if kind == "H":
                 lpn, _ = self.h_table.pop(vid)
-                self.h_chain.pop(vid, None)
+                self._unmap_chain(vid)
                 while lpn >= 0:
                     pg = self.dev.read_page(lpn)
                     nxt = int(pg[_H_NEXT])
                     self.dev.free_page(lpn)
                     lpn = nxt
-                self.gmap.pop(vid, None)
+                self._map([vid], None)
             elif kind == "L":
                 hit = self._l_lookup_page(vid)
                 if hit is not None:
                     lpn, page = hit
                     self._l_remove_node(page, lpn, vid)
-                self.gmap.pop(vid, None)
+                self._map([vid], None)
             chunk = np.asarray(nbrs, dtype=SLOT_DTYPE).reshape(-1)
-            if not self._l_keys:
+            if not len(self._l_keys):
                 self._l_insert_new_page([vid], [chunk])
             elif vid > self._l_keys[-1]:
-                lpn = self._l_lpns[-1]
+                lpn = int(self._l_lpns[-1])
                 page = self.dev.read_page(lpn).copy()
                 if self._l_free_slots(page) >= len(chunk) + 2:
                     self._l_append_node(page, vid, chunk)
@@ -600,9 +740,9 @@ class GraphStore:
                 else:
                     self._l_insert_new_page([vid], [chunk])
             else:
-                k = bisect.bisect_left(self._l_keys, vid)
+                k = int(np.searchsorted(self._l_keys, vid))
                 self._l_split_insert(k, vid, chunk)
-            self.gmap[vid] = "L"
+            self._map([vid], "L")
             self.num_vertices = max(self.num_vertices, vid + 1)
 
     def drop_class(self, cls: int, modulus: int) -> int:
@@ -691,104 +831,34 @@ class GraphStore:
         per-vid selection lengths (empty/unknown vids yield a self-loop).
         """
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
-        block, desc = self.fetch_plan(vids_arr)
+        block, plan = self.fetch_plan(vids_arr)
         with spans.span("store.select"):
-            return select_from_plan(vids_arr, block, desc, fanout, rng,
+            return select_from_plan(vids_arr, block, plan, fanout, rng,
                                     segments=segments, rngs=rngs)
 
-    def _l_locate_batch(self, block, row_of, l_pos, lq, k, miss, desc) -> None:
-        """Vectorized L-page meta scan over every fetched page at once.
+    @staticmethod
+    def _l_locate_batch(block, q_row, lq):
+        """``_l_scan`` for a whole batch: every L vid ``lq[i]`` looked up
+        in the meta of its own page, ``block[q_row[i]]``, at once.
 
-        Builds the global (vid -> page row, chunk start, chunk end) tables
-        with a handful of array ops — the range partition makes per-page
-        ascending vids globally sorted, so one ``searchsorted`` resolves all
-        queries — and records ("L", row, start, end) descriptors.
+        A vid's entry is the first meta entry that holds it; its chunk ends
+        at the smallest in-meta offset beyond its start that lies within
+        the data (tombstones' offsets stay valid boundaries), else at the
+        data length.  Returns ``(found, start, end)``, one per query.
         """
-        kis = sorted(set(k[~miss].tolist()))
-        rows = np.array([row_of[self._l_lpns[ki]] for ki in kis],
-                        dtype=np.int64)
-        if not len(rows):
-            return
-        n_m = block[rows, _L_NNODES].astype(np.int64)
-        dlen_m = block[rows, _L_DATALEN].astype(np.int64)
-        nmax = int(n_m.max())
-        j = np.arange(nmax)
-        vid_slot = _L_NNODES - 2 - 2 * j                # meta slot of node j
-        vids_m = block[rows[:, None], vid_slot[None, :]].astype(np.int64)
-        offs_m = block[rows[:, None], vid_slot[None, :] - 1].astype(np.int64)
-        in_meta = j[None, :] < n_m[:, None]
-        live = in_meta & (vids_m >= 0)
-
-        # chunk ends.  Fast path: bulk-packed pages keep offsets strictly
-        # ascending in meta order (no tombstones, no relocations), so node
-        # j's chunk ends where node j+1's begins — no sort needed.  Any
-        # mutated page (unit updates relocate chunks and leave tombstones)
-        # falls back to the general boundary sort below.
-        clean = np.all((~in_meta[:, 1:])
-                       | (offs_m[:, 1:] > offs_m[:, :-1]), axis=1) \
-            if nmax > 1 else np.ones(len(rows), dtype=bool)
-        clean &= np.all(live == in_meta, axis=1)
-        if clean.all():
-            rown, coln = np.nonzero(live)
-            flat_vids = vids_m[rown, coln]
-            if not np.any(flat_vids[1:] < flat_vids[:-1]):
-                ends_m = np.concatenate(
-                    [offs_m[:, 1:], np.zeros((len(rows), 1), np.int64)],
-                    axis=1)
-                last = np.maximum(n_m - 1, 0)
-                ends_m[np.arange(len(rows)), last] = dlen_m
-                flat_offs = offs_m[rown, coln]
-                flat_ends = ends_m[rown, coln]
-                q = np.searchsorted(flat_vids, lq)
-                qc = np.clip(q, 0, max(len(flat_vids) - 1, 0))
-                found = (~miss) & (len(flat_vids) > 0) \
-                    & (flat_vids[qc] == lq)
-                prow = rown[qc]
-                start = flat_offs[qc]
-                end = flat_ends[qc]
-                for i, pos in enumerate(l_pos):
-                    if found[i]:
-                        desc[pos] = ("L", int(rows[prow[i]]), int(start[i]),
-                                     int(end[i]))
-                return
-
-        # general path: valid boundaries flattened with a per-row key so
-        # one global sort + one searchsorted serve every query.
-        big = SLOTS_PER_PAGE + 1
-        bound_ok = in_meta & (offs_m <= dlen_m[:, None])
-        bkey = np.where(bound_ok,
-                        np.arange(len(rows))[:, None] * big + offs_m,
-                        np.iinfo(np.int64).max)
-        bkey = np.sort(bkey.reshape(-1))                # sentinels sort last
-        n_bounds = int(bound_ok.sum())
-        bkey = bkey[:n_bounds]                          # drop sentinels
-
-        # live nodes flattened; the range partition + per-page ascending
-        # packing make vids globally sorted already (checked; argsort only
-        # as a fallback for adversarial layouts)
-        rown, coln = np.nonzero(live)
-        flat_vids = vids_m[rown, coln]
-        flat_offs = offs_m[rown, coln]
-        if np.any(flat_vids[1:] < flat_vids[:-1]):      # pragma: no cover
-            sort2 = np.argsort(flat_vids, kind="stable")
-            flat_vids, flat_offs, rown = (flat_vids[sort2], flat_offs[sort2],
-                                          rown[sort2])
-        svids = flat_vids
-
-        q = np.searchsorted(svids, lq)
-        qc = np.clip(q, 0, max(len(svids) - 1, 0))
-        found = (~miss) & (len(svids) > 0) & (svids[qc] == lq)
-        prow = rown[qc]                                 # row within `sub`
-        start = flat_offs[qc]
-        e = np.searchsorted(bkey, prow * big + start, side="right")
-        ec = np.clip(e, 0, max(n_bounds - 1, 0))
-        in_row = (e < n_bounds) & (bkey[ec] // big == prow)
-        end = np.where(in_row, bkey[ec] % big, dlen_m[prow])
-
-        for i, pos in enumerate(l_pos):
-            if found[i]:
-                desc[pos] = ("L", int(rows[prow[i]]), int(start[i]),
-                             int(end[i]))
+        n = block[q_row, _L_NNODES]
+        dlen = block[q_row, _L_DATALEN][:, None]
+        nmax = max(int(n.max()), 1)
+        # meta of nodes 0..nmax-1 read backwards: vid 0, offset 0, vid 1, ...
+        meta = block[q_row, _l_meta_off(nmax - 1): _L_DATALEN][:, ::-1]
+        vids, offs = meta[:, 0::2], meta[:, 1::2]
+        in_meta = np.arange(nmax)[None, :] < n[:, None]
+        hit = in_meta & (vids == lq[:, None])
+        start = offs[np.arange(len(lq)), hit.argmax(axis=1)]
+        later = in_meta & (offs > start[:, None]) & (offs <= dlen)
+        end = np.where(later, offs, dlen).min(axis=1)
+        return (hit.any(axis=1), start.astype(np.int64),
+                end.astype(np.int64))
 
     def get_embed(self, vid: int) -> np.ndarray:
         """Paper GetEmbed(VID): read only the pages covering row ``vid``."""
@@ -877,7 +947,7 @@ class GraphStore:
         which breaks the range-search partition under out-of-order VIDs; we
         use a range-preserving page split instead (same cost profile: one
         extra page + one table insert)."""
-        lpn = self._l_lpns[k]
+        lpn = int(self._l_lpns[k])
         page = self.dev.read_page(lpn).copy()
         nodes = [nc for nc in self._l_collect(page) if nc[0] != vid]
         nodes.append((vid, chunk))
@@ -898,8 +968,8 @@ class GraphStore:
         self.dev.write_page(new_lpn, self._l_build_page(low))
         self.dev.write_page(lpn, self._l_build_page(high))
         self._l_keys[k] = max(self._l_keys[k], high[-1][0])
-        self._l_keys.insert(k, low[-1][0])
-        self._l_lpns.insert(k, new_lpn)
+        self._l_keys = np.insert(self._l_keys, k, low[-1][0])
+        self._l_lpns = np.insert(self._l_lpns, k, new_lpn)
 
     def add_vertex(self, vid: int, embed: np.ndarray | None = None) -> None:
         """AddVertex: self-loop only, starts as L-type (paper).  Ascending
@@ -911,10 +981,10 @@ class GraphStore:
                 return
             self.stats.unit_updates += 1
             loop = np.array([vid], dtype=SLOT_DTYPE)
-            if not self._l_keys:
+            if not len(self._l_keys):
                 self._l_insert_new_page([vid], [loop])
             elif vid > self._l_keys[-1]:
-                lpn = self._l_lpns[-1]
+                lpn = int(self._l_lpns[-1])
                 page = self.dev.read_page(lpn).copy()
                 if self._l_free_slots(page) >= 3:
                     self._l_append_node(page, vid, loop)
@@ -923,9 +993,9 @@ class GraphStore:
                 else:
                     self._l_insert_new_page([vid], [loop])
             else:
-                k = bisect.bisect_left(self._l_keys, vid)
+                k = int(np.searchsorted(self._l_keys, vid))
                 self._l_split_insert(k, vid, loop)
-            self.gmap[vid] = "L"
+            self._map([vid], "L")
             self.num_vertices = max(self.num_vertices, vid + 1)
             if embed is not None:
                 self.update_embed(vid, embed)
@@ -942,9 +1012,9 @@ class GraphStore:
         page[_L_DATALEN] = dlen
         self.dev.write_page(lpn, page)
         key = int(max(vids))
-        k = bisect.bisect_left(self._l_keys, key)
-        self._l_keys.insert(k, key)
-        self._l_lpns.insert(k, lpn)
+        k = int(np.searchsorted(self._l_keys, key))
+        self._l_keys = np.insert(self._l_keys, k, key)
+        self._l_lpns = np.insert(self._l_lpns, k, lpn)
 
     @staticmethod
     def _l_append_node(page: np.ndarray, vid: int, chunk: np.ndarray) -> None:
@@ -985,12 +1055,12 @@ class GraphStore:
                 page[_H_NEXT] = lpn
                 self.dev.write_page(tail, page)
                 self.h_table[vid] = (head, lpn)
-                self.h_chain[vid].append(lpn)
+                self._map_chain(vid, self.h_chain[vid] + [lpn])
                 self.stats.pages_h += 1
             return
         # ---- L-type
-        k = bisect.bisect_left(self._l_keys, vid)
-        lpn = self._l_lpns[k]
+        k = int(np.searchsorted(self._l_keys, vid))
+        lpn = int(self._l_lpns[k])
         page = self.dev.read_page(lpn).copy()
         meta = self._l_scan(page, vid)
         assert meta is not None, f"vid {vid} missing from L page"
@@ -1028,7 +1098,7 @@ class GraphStore:
 
     def _promote_to_h(self, vid: int, nbrs: np.ndarray) -> None:
         self._write_h_chain(vid, nbrs)
-        self.gmap[vid] = "H"
+        self._map([vid], "H")
 
     def _write_h_chain(self, vid: int, nbrs: np.ndarray) -> None:
         """Write a fresh H chain for ``vid`` and record its mapping."""
@@ -1052,7 +1122,7 @@ class GraphStore:
                 self.dev.write_page(tail, prev)
             tail = lpn
         self.h_table[vid] = (head, tail)
-        self.h_chain[vid] = chain
+        self._map_chain(vid, chain)
 
     def _l_shift_left(self, page: np.ndarray, start: int, ln: int) -> None:
         """Remove chunk [start, start+ln) from the data region, fix offsets."""
@@ -1143,10 +1213,11 @@ class GraphStore:
         """Release ``vid``'s own mapping + pages (not its neighbors' backlinks
         — the sharded coordinator removes those on each neighbor's owning
         shard before calling this on the owner)."""
-        kind = self.gmap.pop(vid, None)
+        kind = self.gmap.get(vid)
+        self._map([vid], None)
         if kind == "H":
             lpn, _ = self.h_table.pop(vid)
-            self.h_chain.pop(vid, None)
+            self._unmap_chain(vid)
             while lpn >= 0:
                 page = self.dev.read_page(lpn)
                 nxt = int(page[_H_NEXT])

@@ -37,8 +37,9 @@ Read-side batched queries run in three explicit phases:
            ``io_us``, and the array pays a single wait equal to the
            slowest shard — the same analytic concurrency model as the
            flash channels inside one device (divide, don't sum);
-  build  — per-shard plans are recomposed into one global (block, desc) —
-           descriptor rows re-based into the concatenated block — and fed
+  build  — per-shard array plans are merged into one global (block, plan)
+           — block rows re-based into the concatenated block, entries
+           scattered to their global positions (``merge_plans``) — and fed
            to the SAME ``select_from_plan``/``neighbors_from_plan`` code
            the single-device store runs.
 
@@ -75,8 +76,8 @@ from .blockdev import (BlockDevice, DeviceFailedError, SLOTS_PER_PAGE,
                        sleep_us)
 from .endpoint import LocalShardEndpoint, make_local_endpoints
 from .graphstore import (BulkTimeline, GraphStoreStats, _H_COUNT,
-                         neighbors_from_plan, preprocess_edges,
-                         select_from_plan)
+                         make_plan, merge_plans, neighbors_from_plan,
+                         preprocess_edges, select_from_plan)
 from .placement import (PlacementMap, common_refine, grow_plan, heat_plan,
                         modular, plan_moves, rows_of_class, shrink_plan)
 from .sampler import _ramp
@@ -1046,7 +1047,7 @@ class ShardedGraphStore:
     def _fan_fetch(self, vids_arr: np.ndarray):
         """plan -> per-shard fetch -> build: the shared front half of the
         batched queries (see module docstring).  Returns a global
-        (block, desc) position-identical to a single device's
+        (block, plan) position-identical to a single device's
         ``_fetch_plan`` over the same vids.
         """
         with self._read_routing() as rt:
@@ -1056,28 +1057,22 @@ class ShardedGraphStore:
             payloads, _ = self._endpoint_fetch(
                 [(s, {"l_vids": vids_arr[pos]}) for s, pos in parts])
 
-        # build: re-base each shard's descriptor rows into the concatenated
-        # block and scatter them back to their global positions
-        desc: list = [None] * len(vids_arr)
-        blocks = []
+        # build: re-base each shard's plan rows into the concatenated block
+        # and scatter its entries back to their global positions (a shard
+        # that read no page has an empty plan)
+        plans, blocks = [], []
         row_off = 0
         for (s, pos), pl in zip(parts, payloads):
-            blk, dsc = pl["block"], pl["desc"]
-            for p, d in zip(pos.tolist(), dsc):
-                if d is None:
-                    continue
-                if d[0] == "L":
-                    desc[p] = ("L", d[1] + row_off, d[2], d[3])
-                else:
-                    desc[p] = ("H", d[1] + row_off, d[2])
+            blk = pl["block"]
             if blk is not None:
+                plans.append((pl["plan"], pos, row_off))
                 blocks.append(blk)
                 row_off += blk.shape[0]
         if not blocks:
-            return None, desc
+            return None, make_plan()
         # single contributing shard: its block is already global
         block = blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
-        return block, desc
+        return block, merge_plans(plans)
 
     def get_neighbors(self, vid: int) -> np.ndarray:
         """Neighbor list of one vid from its owning shard."""
@@ -1091,8 +1086,8 @@ class ShardedGraphStore:
         recomposed in input order (bit-identical to the single-device
         store)."""
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
-        block, desc = self._fan_fetch(vids_arr)
-        return neighbors_from_plan(vids_arr, block, desc)
+        block, plan = self._fan_fetch(vids_arr)
+        return neighbors_from_plan(vids_arr, block, plan)
 
     def sample_neighbors_batch(self, vids, fanout: int,
                                rng: np.random.Generator | None = None, *,
@@ -1101,9 +1096,9 @@ class ShardedGraphStore:
         shard per hop, then the single-device selection over the recomposed
         plan (rng consumed in global frontier order: bit-identical)."""
         vids_arr = np.asarray(vids, dtype=np.int64).reshape(-1)
-        block, desc = self._fan_fetch(vids_arr)
+        block, plan = self._fan_fetch(vids_arr)
         with spans.span("store.select"):
-            return select_from_plan(vids_arr, block, desc, fanout, rng,
+            return select_from_plan(vids_arr, block, plan, fanout, rng,
                                     segments=segments, rngs=rngs)
 
     # ----------------------------------------------------------- embeddings
@@ -1972,7 +1967,7 @@ class ReplicatedGraphStore(ShardedGraphStore):
         per-shard max (the array's deferred latency) could never drop
         below the chain length; per-page spread flattens hub-skewed
         fetches to ~total/N.  L vids stay vid-granular, weighted by their
-        shared page cost.  The recomposed (block, desc) is
+        shared page cost.  The merged (block, plan) is
         position-identical to the single-device plan, so selection stays
         bit-identical.
         """
@@ -1983,15 +1978,14 @@ class ReplicatedGraphStore(ShardedGraphStore):
         # this gap).  The simulated array wait is paid after release, so
         # mutations only ever wait out the (fast) planning math.
         with self._mutate:
-            block, desc, worst = self._plan_and_fetch_spread(vids_arr)
+            block, plan, worst = self._plan_and_fetch_spread(vids_arr)
         with self._bp_lock:
             self.io_wait_us += worst
         sleep_us(worst)
-        return block, desc
+        return block, plan
 
     def _plan_and_fetch_spread(self, vids_arr: np.ndarray):
         rt = self._routing       # stable: flips also hold _mutate
-        desc: list = [None] * len(vids_arr)
         # ---- planning metadata: ONE plan_info call per occupied vertex
         # class against a live replica (replica-invariant tables) — the
         # coordinator never reads shard mapping state directly
@@ -2010,39 +2004,33 @@ class ReplicatedGraphStore(ShardedGraphStore):
             chain_len[idx] = np.asarray(info["chain_len"], dtype=np.int64)
             l_page[idx] = np.asarray(info["l_page"], dtype=np.int64)
 
-        uidx: dict[int, int] = {}
-        u_vids: list[int] = []
-        u_lens: list[int] = []
-        pos_of_u: list[list[int]] = []
-        l_pos: list[int] = []
-        for pos, v in enumerate(vids_arr.tolist()):
-            if chain_len[pos] == 0:
-                l_pos.append(pos)
-            else:
-                u = uidx.get(v)
-                if u is None:
-                    u = uidx[v] = len(u_vids)
-                    u_vids.append(v)
-                    u_lens.append(int(chain_len[pos]))
-                    pos_of_u.append([])
-                pos_of_u[u].append(pos)
+        # hubs: distinct H vids in first-appearance order (u), each
+        # position's hub index, and each hub's chain length
+        hub = chain_len > 0
+        l_pos_arr = np.flatnonzero(~hub)
+        h_pos = np.flatnonzero(hub)
+        uniq, first, inv = np.unique(vids_arr[h_pos], return_index=True,
+                                     return_inverse=True)
+        order = np.argsort(first, kind="stable")
+        rank = np.empty(len(order), dtype=np.int64)
+        rank[order] = np.arange(len(order))
+        u_of_pos = rank[inv]
+        u_vids = uniq[order]
+        u_lens = chain_len[h_pos][first[order]]
 
         # ---- ONE joint selection for the whole fetch: L vids (page-share
         # weighted) and H chain pages (unit weight) compete for the same
         # per-shard budget — planned separately, the hub pages would land
         # on top of an already-balanced L assignment and re-skew the fetch
-        l_pos_arr = np.asarray(l_pos, dtype=np.int64)
         l_vids = vids_arr[l_pos_arr]
-        item_vid = item_pg = item_row = u_lens_a = None
+        item_vid = item_pg = item_row = None
         sel_vids = [l_vids]
         sel_w = [self._l_share_weights(l_vids, l_page[l_pos_arr])
                  if len(l_vids) else np.empty(0)]
         sel_key = [2 * l_vids]                # even keys: L, by vid
-        if u_vids:
-            u_vids_a = np.asarray(u_vids, dtype=np.int64)
-            u_lens_a = np.asarray(u_lens, dtype=np.int64)
-            item_vid = u_vids_a[np.repeat(np.arange(len(u_vids)), u_lens_a)]
-            item_pg = _ramp(u_lens_a)
+        if len(u_vids):
+            item_vid = np.repeat(u_vids, u_lens)
+            item_pg = _ramp(u_lens)
             item_row = np.empty(len(item_vid), dtype=np.int64)
             sel_vids.append(item_vid)
             sel_w.append(np.ones(len(item_vid)))
@@ -2050,11 +2038,11 @@ class ReplicatedGraphStore(ShardedGraphStore):
             # split wherever the quotas land — page-granular spread
             sel_key.append(
                 2 * (np.max(vids_arr) + 1
-                     + np.repeat(np.arange(len(u_vids)), u_lens_a)
-                     * (int(u_lens_a.max()) + 1) + item_pg) + 1)
+                     + np.repeat(np.arange(len(u_vids)), u_lens)
+                     * (int(u_lens.max()) + 1) + item_pg) + 1)
         all_vids = np.concatenate(sel_vids)
         if not len(all_vids):
-            return None, desc, 0.0
+            return None, make_plan(), 0.0
         owner = self._select_replicas(all_vids,
                                       weights=np.concatenate(sel_w),
                                       key=np.concatenate(sel_key))
@@ -2081,38 +2069,34 @@ class ReplicatedGraphStore(ShardedGraphStore):
             reqs.append((s, kw))
         payloads, worst = self._endpoint_fetch(reqs, pay=False)
 
+        plans: list = []
         blocks: list[np.ndarray] = []
         row_off = 0
         for s, pl in zip(shard_order, payloads):
             work = parts[s]
-            dsc, blk, hblk = pl["desc"], pl["block"], pl["hblk"]
-            if dsc is not None:
-                for plx, d in zip(work["l"].tolist(), dsc):
-                    if d is None:
-                        continue
-                    pos = int(l_pos_arr[plx])
-                    if d[0] == "L":
-                        desc[pos] = ("L", d[1] + row_off, d[2], d[3])
-                    else:                     # defensive: kind skew
-                        desc[pos] = ("H", d[1] + row_off, d[2])
-                if blk is not None:
-                    blocks.append(blk)
-                    row_off += blk.shape[0]
+            blk, hblk = pl["block"], pl["hblk"]
+            if blk is not None:
+                # an L plan may hold H entries too (defensive: kind skew)
+                plans.append((pl["plan"], l_pos_arr[work["l"]], row_off))
+                blocks.append(blk)
+                row_off += blk.shape[0]
             if hblk is not None:
                 item_row[work["h"]] = row_off + np.arange(len(hblk))
                 blocks.append(hblk)
                 row_off += hblk.shape[0]
         if not blocks:
-            return None, desc, worst
+            return None, make_plan(), worst
         block = blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
-        if u_vids:
-            starts = np.concatenate([[0], np.cumsum(u_lens_a)[:-1]])
-            for u in range(len(u_vids)):
-                rows = item_row[starts[u]: starts[u] + int(u_lens_a[u])]
-                d = ("H", rows, block[rows, _H_COUNT].astype(np.int64))
-                for pos in pos_of_u[u]:
-                    desc[pos] = d
-        return block, desc, worst
+        if len(u_vids):
+            # every hub position carries its hub's chain pages, in order
+            starts = np.cumsum(u_lens) - u_lens
+            h_len = u_lens[u_of_pos]
+            h_rows = item_row[np.repeat(starts[u_of_pos], h_len)
+                              + _ramp(h_len)]
+            plans.append((make_plan(
+                h_idx=np.arange(len(h_pos)), h_len=h_len, h_rows=h_rows,
+                h_counts=block[h_rows, _H_COUNT].astype(np.int64)), h_pos, 0))
+        return block, merge_plans(plans), worst
 
     # ------------------------------------------------------------ unit reads
     def _unit_call(self, s: int, ep, method: str, **kw):

@@ -11,7 +11,7 @@ from repro.core.service import HolisticGNNService, make_service_dfg
 from repro.store import (BlockDevice, DeviceFailedError, GraphStore,
                          ReplicatedGraphStore, ShardedGraphStore,
                          make_rop_endpoints, sample_batch)
-from repro.store.endpoint import pack_plan, unpack_plan
+from repro.store.graphstore import PLAN_FIELDS, select_from_plan
 
 
 def _graph(n=400, e=3000, feat=24, seed=0):
@@ -62,19 +62,35 @@ def _assert_reads_match(single, store, n, seed=3):
     np.testing.assert_array_equal(a.embeddings, b.embeddings)
 
 
-# ------------------------------------------------------------ plan packing
-def test_pack_unpack_plan_roundtrip():
-    desc = [None,
-            ("L", 3, 0, 17),
-            ("H", np.array([5, 9, 2]), np.array([100, 100, 7])),
-            None,
-            ("L", 0, 4, 4)]
-    got = unpack_plan(pack_plan(desc))
-    assert got[0] is None and got[3] is None
-    assert got[1] == ("L", 3, 0, 17) and got[4] == ("L", 0, 4, 4)
-    assert got[2][0] == "H"
-    np.testing.assert_array_equal(got[2][1], desc[2][1])
-    np.testing.assert_array_equal(got[2][2], desc[2][2])
+# ------------------------------------------------------------ array plans
+def test_array_plan_crosses_wire_unchanged():
+    """A shard's fetch plan is int64 arrays: it crosses the RoP wire as
+    payload buffers, field for field, and selects the same sample as the
+    in-process plan and the single device."""
+    single, edges, emb = _single(h_threshold=4)
+    store = _remote(1, edges=edges, emb=emb, h_threshold=4)
+    try:
+        vids = np.random.default_rng(5).integers(0, 420, 90)  # + unknown
+        ep = store.endpoints[0]
+        wire = ep.fetch_result(ep.fetch_submit(l_vids=vids))
+        local = ep.host.service.fetch(l_vids=vids)
+        np.testing.assert_array_equal(wire["block"], local["block"])
+        assert set(wire["plan"]) == set(PLAN_FIELDS)
+        assert len(local["plan"]["h_idx"]) and len(local["plan"]["l_idx"])
+        for k in PLAN_FIELDS:
+            assert wire["plan"][k].dtype == np.int64, k
+            np.testing.assert_array_equal(wire["plan"][k],
+                                          local["plan"][k], err_msg=k)
+        want = single.sample_neighbors_batch(vids, 3,
+                                             np.random.default_rng(2))
+        for got in (select_from_plan(vids, wire["block"], wire["plan"], 3,
+                                     np.random.default_rng(2)),
+                    select_from_plan(vids, local["block"], local["plan"],
+                                     3, np.random.default_rng(2))):
+            np.testing.assert_array_equal(got[0], want[0])
+            np.testing.assert_array_equal(got[1], want[1])
+    finally:
+        store.close()
 
 
 # ------------------------------------------------------- remote bit-identity

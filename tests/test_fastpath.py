@@ -6,17 +6,21 @@ import pytest
 import jax.numpy as jnp
 
 from repro.store.blockdev import BlockDevice
-from repro.store.graphstore import GraphStore
-from repro.store.sampler import sample_batch, sample_batch_ref
+from repro.store.graphstore import H_CAP, GraphStore
+from repro.store.sampler import _subsample, sample_batch, sample_batch_ref
+
+
+def _store_edges(rng, n, e):
+    src = rng.zipf(1.4, e) % (n - 10)          # last 10 vids never get edges
+    dst = rng.integers(0, n - 10, e)
+    return np.stack([dst, src], axis=1).astype(np.int64)
 
 
 def _store(seed=0, n=400, e=3000, h_threshold=8, feat=24):
     """Power-law graph with H/L mix; some vertices stay edge-less (isolated
     vertices have embeddings but no adjacency -> empty-neighbor path)."""
     rng = np.random.default_rng(seed)
-    src = rng.zipf(1.4, e) % (n - 10)          # last 10 vids never get edges
-    dst = rng.integers(0, n - 10, e)
-    edges = np.stack([dst, src], axis=1).astype(np.int64)
+    edges = _store_edges(rng, n, e)
     emb = rng.standard_normal((n, feat)).astype(np.float32)
     gs = GraphStore(BlockDevice(), h_threshold=h_threshold)
     gs.update_graph(edges, emb)
@@ -62,6 +66,129 @@ def test_get_neighbors_batch_multipage_h_chain():
     got = gs.get_neighbors_batch([0, 1, 2])
     for v, g in zip([0, 1, 2], got):
         np.testing.assert_array_equal(g, gs.get_neighbors(v))
+
+
+def _hub_store(mutate=False):
+    """Power-law graph plus two hubs whose chains span several pages;
+    ``mutate`` then applies unit ops: L chunks relocated and L nodes
+    tombstoned, an L vertex promoted to H, a chain grown by a page, an H
+    and an L vertex deleted."""
+    star = np.arange(400, 400 + 2 * H_CAP + 300)
+    hubs = np.concatenate([np.stack([np.full(len(star), 3), star], axis=1),
+                           np.stack([np.full(H_CAP + 9, 9),
+                                     star[: H_CAP + 9]], axis=1)])
+    gs = GraphStore(BlockDevice(), h_threshold=8)
+    gs.update_graph(np.concatenate(
+        [_store_edges(np.random.default_rng(5), 400, 3000), hubs]))
+    n = int(star[-1]) + 1
+    assert len(gs.h_chain[3]) >= 3 and len(gs.h_chain[9]) >= 2
+    if mutate:
+        low = [v for v in range(40, 400) if gs.gmap.get(v) == "L"]
+        for v in low[:12]:
+            gs.add_edge(v, low[-1])          # relocates or appends chunks
+        p = low[20]
+        for u in range(100, 100 + 12):
+            gs.add_edge(p, u)                # promotes p to H
+        assert gs.gmap[p] == "H"
+        for u in range(n, n + H_CAP):        # grows hub 3 by a page
+            gs.add_edge(3, u)
+        gs.delete_vertex(low[30])            # tombstone in an L page
+        gs.delete_vertex(9)                  # an H vertex gone
+        gs.delete_edge(low[40], low[41])
+        n += H_CAP
+    return gs, n
+
+
+def _assert_tables_mirror(gs):
+    """The plan's array tables hold exactly what gmap/h_chain/_l_keys do."""
+    for v, kind in gs.gmap.items():
+        assert gs._kinds(np.array([v]))[0] == {"L": 1, "H": 2}[kind], v
+    assert int((gs._sel > 0).sum()) == len(gs.gmap)
+    for v, chain in gs.h_chain.items():
+        at, k = int(gs._chain_at[v]), int(gs._chain_n[v])
+        assert gs._pool[at: at + k].tolist() == chain, v
+    assert int(gs._chain_n.sum()) == sum(map(len, gs.h_chain.values()))
+    assert np.all(np.diff(gs._l_keys) > 0)
+
+
+def _select_ref(gs, vids, fanout, rng):
+    """Per-vertex reference of one fanout selection (the oracle's own)."""
+    parts = [_subsample(rng, int(v), gs.get_neighbors(int(v)), fanout)
+             for v in vids]
+    return (np.concatenate(parts + [np.empty(0, np.int32)]).astype(np.int32),
+            np.array([len(x) for x in parts], dtype=np.int64))
+
+
+_PLAN_CASES = ["bulk_hub_chains", "after_unit_ops", "unknown_and_dups",
+               "segments"]
+
+
+@pytest.mark.parametrize("case", _PLAN_CASES)
+def test_array_plan_matches_reference(case):
+    """The array plan's batched reads and fanout selection equal the
+    per-vid references bit for bit: bulk hub chains, unit-op mutations,
+    unknown and duplicate vids, and a multi-request segments/rngs
+    frontier."""
+    gs, n = _hub_store(mutate=case != "bulk_hub_chains")
+    _assert_tables_mirror(gs)
+    rng = np.random.default_rng(4)
+    vids = np.concatenate([[3, 9], rng.integers(0, 400, 60)])
+    if case == "unknown_and_dups":
+        vids = np.concatenate([vids, [n + 5, 10**7, 3, 3, vids[4], 9]])
+    for v, got in zip(vids, gs.get_neighbors_batch(vids)):
+        np.testing.assert_array_equal(got, gs.get_neighbors(int(v)),
+                                      err_msg=str(v))
+    for fanout in (3, 10):
+        if case == "segments":
+            segs = [7, 0, 30, len(vids) - 37]
+            seeds = [11, 12, 13, 14]
+            got = gs.sample_neighbors_batch(
+                vids, fanout, segments=segs,
+                rngs=[np.random.default_rng(x) for x in seeds])
+            bounds = np.cumsum([0] + segs)
+            parts = [_select_ref(gs, vids[bounds[i]: bounds[i + 1]], fanout,
+                                 np.random.default_rng(seeds[i]))
+                     for i in range(len(segs))]
+            want = (np.concatenate([p[0] for p in parts]),
+                    np.concatenate([p[1] for p in parts]))
+        else:
+            got = gs.sample_neighbors_batch(vids, fanout,
+                                            np.random.default_rng(fanout))
+            want = _select_ref(gs, vids, fanout,
+                               np.random.default_rng(fanout))
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+    targets = vids[:8] if case != "unknown_and_dups" else vids[-6:]
+    _assert_batches_equal(
+        sample_batch(gs, targets, [10, 4], rng=np.random.default_rng(1)),
+        sample_batch_ref(gs, targets, [10, 4],
+                         rng=np.random.default_rng(1)))
+
+
+@pytest.mark.parametrize("mutate", [False, True])
+def test_sample_reads_distinct_l_pages_then_every_chain_page(mutate):
+    """One fused sample reads exactly the distinct L pages of its L vids
+    (sorted) and then every page of each H vid's chain (sorted, deduped),
+    in one command — the page set ``pages_per_request`` counts."""
+    gs, n = _hub_store(mutate)
+    vids = np.concatenate([[3, 3, 9, n + 1],
+                           np.random.default_rng(2).integers(0, 400, 80)])
+    l_pages, h_pages = set(), set()
+    for v in vids.tolist():
+        if gs.gmap.get(v) == "L":
+            k = int(np.searchsorted(gs._l_keys, v))
+            l_pages.add(int(gs._l_lpns[k]))
+        elif gs.gmap.get(v) == "H":
+            h_pages.update(gs.h_chain[v])
+    want = sorted(l_pages) + sorted(h_pages)
+    seen = []
+    read = gs.dev.read_pages
+    gs.dev.read_pages = lambda lpns, **kw: (seen.append(list(lpns)),
+                                            read(lpns, **kw))[1]
+    r0 = gs.dev.stats.read_pages
+    gs.sample_neighbors_batch(vids, 5, np.random.default_rng(0))
+    assert seen == [want]
+    assert gs.dev.stats.read_pages - r0 == len(want)
 
 
 def test_get_embeds_coalesced_matches_rowwise():

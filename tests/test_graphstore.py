@@ -130,6 +130,32 @@ def test_property_random_mutations(ops):
     store_adj = gs.to_adjacency()
     for v, want in adj.items():
         assert store_adj.get(v, set()) == want, (v, store_adj.get(v), want)
+    vids = list(range(next_vid + 2))
+    for v, got in zip(vids, gs.get_neighbors_batch(vids)):
+        np.testing.assert_array_equal(got, gs.get_neighbors(v))
+
+
+def test_chain_pool_compacts_and_keeps_every_chain():
+    """Rewritten H chains leave superseded copies in the planner's LPN
+    pool; once they outweigh the live chains the pool is compacted, and
+    every chain (and batched read) still matches the mapping tables."""
+    gs = GraphStore(BlockDevice(), h_threshold=8)
+    gs.update_graph(_mk_graph(seed=3))
+    hubs = [v for v, k in gs.gmap.items() if k == "H"]
+    pages = {v: np.stack([gs.dev.read_page(lpn).copy()
+                          for lpn in gs.h_chain[v]]) for v in hubs}
+    writes = 0
+    while writes < 2 * len(gs._pool):
+        for v in hubs[:3]:
+            gs.import_h_chain(v, pages[v])
+            writes += len(pages[v])
+    assert gs._pool_top < writes
+    for v, chain in gs.h_chain.items():
+        at, n = int(gs._chain_at[v]), int(gs._chain_n[v])
+        assert gs._pool[at: at + n].tolist() == chain
+    vids = list(range(210))
+    for v, got in zip(vids, gs.get_neighbors_batch(vids)):
+        np.testing.assert_array_equal(got, gs.get_neighbors(v))
 
 
 def test_write_amplification_unit_ops():
