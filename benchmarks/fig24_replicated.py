@@ -2,7 +2,7 @@
 
 PR 3's array leaves two holes the ROADMAP calls out: a lost device loses
 its partition, and hash placement concentrates hot data (fig23's balance
-0.8-0.95).  This sweep drives a ``ReplicatedGraphStore`` over 4 simulated
+0.8-0.95).  This sweep drives a ``ShardedGraphStore`` over 4 simulated
 devices with R ∈ {1, 2, 3} on a *skewed* read mix: a hot co-engagement
 community whose vertex ids cluster in two residue classes (the
 clustered-id cohort a partition-unaware ingest produces — the
@@ -32,7 +32,7 @@ import time
 import numpy as np
 
 from . import common as C
-from repro.store import ReplicatedGraphStore, sample_batch
+from repro.store import ShardedGraphStore, sample_batch
 from repro.store.blockdev import BlockDevice
 
 # Same array-scale QLC-class profile as fig23: per-page flash time
@@ -147,8 +147,8 @@ def run(smoke: bool = False, reps=(1, 2, 3)):
     base_io = None
     healthy_ref = None
     for rep in reps:
-        store = ReplicatedGraphStore(devs=shard_devices(N_SHARDS),
-                                     replication=rep, h_threshold=32)
+        store = ShardedGraphStore(devs=shard_devices(N_SHARDS),
+                                  replication=rep, h_threshold=32)
         store.update_graph(edges, emb)
         _measure(store, batches[:1], fanouts)            # warm
         io_s, wall, reads, results = _measure(store, batches, fanouts)

@@ -29,9 +29,8 @@ from __future__ import annotations
 import numpy as np
 
 from . import common as C
-from repro.store import (PAGE_BYTES, ReplicatedGraphStore,
-                         ShardedGraphStore, make_rop_endpoints,
-                         sample_batch)
+from repro.store import (PAGE_BYTES, ShardedGraphStore,
+                         make_rop_endpoints, sample_batch)
 from repro.store.blockdev import BlockDevice
 
 # Array-scale device profile, one notch below fig23's: archival/dense-QLC
@@ -79,8 +78,8 @@ def _rpc_amortisation(lines, *, replicated: bool, batches=(16, 64, 256)):
     edges, emb = _workload(4000, 24000, 64)
     eps = make_rop_endpoints(n_shards, h_threshold=64)
     if replicated:
-        store = ReplicatedGraphStore(endpoints=eps, replication=2,
-                                     h_threshold=64)
+        store = ShardedGraphStore(endpoints=eps, replication=2,
+                                  h_threshold=64)
     else:
         store = ShardedGraphStore(endpoints=eps, h_threshold=64)
     store.update_graph(edges, emb)
@@ -157,8 +156,8 @@ def _rebuild_streaming(lines):
     peer — the endpoint-to-endpoint claim, measured."""
     edges, emb = _workload(6000, 40000, 128)
     eps = make_rop_endpoints(3, h_threshold=64)
-    store = ReplicatedGraphStore(endpoints=eps, replication=2,
-                                 h_threshold=64)
+    store = ShardedGraphStore(endpoints=eps, replication=2,
+                              h_threshold=64)
     store.update_graph(edges, emb)
     victim = 1
     store.fail_shard(victim)

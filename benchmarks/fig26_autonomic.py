@@ -33,8 +33,8 @@ import numpy as np
 from . import common as C
 from repro.rpc.queues import BackpressureError
 from repro.serve import HealthPolicy, ShardSupervisor
-from repro.store import (ReplicatedGraphStore, ShardedGraphStore,
-                         make_rop_endpoints, sample_batch)
+from repro.store import (ShardedGraphStore, make_rop_endpoints,
+                         sample_batch)
 from repro.store.blockdev import BlockDevice
 from repro.store.sharded import FlowControl
 
@@ -93,8 +93,8 @@ def phase_chaos(smoke: bool) -> list[str]:
         else (96, 12, [10, 10])
     edges, emb = _workload(n, e, feat)
     batches = _batches(n, batch, n_batches)
-    store = ReplicatedGraphStore(devs=shard_devices(N_SHARDS),
-                                 replication=2, h_threshold=32)
+    store = ShardedGraphStore(devs=shard_devices(N_SHARDS),
+                              replication=2, h_threshold=32)
     store.update_graph(edges, emb)
     ref = [_serve(store, t, b, fanouts) for b, t in enumerate(batches)]
     reads = [d.stats.read_pages for d in store.devs]
@@ -157,8 +157,8 @@ def phase_paced_rebuild(smoke: bool) -> list[str]:
     pace_s = 0.02 if smoke else 0.04
     min_off, min_on = (6, 3) if smoke else (12, 5)
     edges, emb = _workload(n, e, feat)
-    store = ReplicatedGraphStore(devs=shard_devices(N_SHARDS),
-                                 replication=2, h_threshold=32)
+    store = ShardedGraphStore(devs=shard_devices(N_SHARDS),
+                              replication=2, h_threshold=32)
     store.update_graph(edges, emb)
     batches = _batches(n, batch, 64)
     _serve(store, batches[0], 0, fanouts)                      # warm

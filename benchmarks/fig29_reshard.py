@@ -40,8 +40,7 @@ import numpy as np
 from . import common as C
 from .fig24_replicated import (HUB_CLASSES, N_SHARDS, _balance,
                                skewed_workload, target_stream)
-from repro.store import (GraphStore, ReplicatedGraphStore,
-                         ShardedGraphStore, sample_batch)
+from repro.store import GraphStore, ShardedGraphStore, sample_batch
 from repro.store.blockdev import BlockDevice
 from repro.store.endpoint import LocalShardEndpoint
 
@@ -216,8 +215,8 @@ def run(smoke: bool = False):
 
     # ------------------------------------- heat rebalance at R in {1, 2}
     for r in reps:
-        store = ReplicatedGraphStore(devs=_devs(N_SHARDS), replication=r,
-                                     h_threshold=H_THRESHOLD)
+        store = ShardedGraphStore(devs=_devs(N_SHARDS), replication=r,
+                                  h_threshold=H_THRESHOLD)
         store.update_graph(edges, emb)
         _measure_reads(store, batches[:1], fanouts)              # warm
         reads_before, res_before = _measure_reads(store, batches, fanouts)

@@ -105,7 +105,7 @@ LOCK_ORDER: tuple[LockSpec, ...] = (
                  "table and so LO005 knows it is accounted for."),
     LockSpec("graphstore._lock", 60, "rlock",
              (("graphstore", "_lock"), ("endpoint", "_lock")),
-             doc="Per-shard store critical section (gmap/h_chain/pages). "
+             doc="Per-shard store critical section (mapping tables/pages). "
                  "Re-entrant; cross-instance nesting is sanctioned for "
                  "the single-puller migration/rebuild stream."),
     LockSpec("blockdev._lock", 70, "lock",

@@ -26,7 +26,7 @@ from . import gnn
 
 class HolisticGNNService:
     """The device-resident service object: Table-1 RPC surface over one
-    store (single device, sharded array, or replicated array) plus the
+    store (one device, or an array of shards with R >= 1 replicas) plus the
     DFG engine, kernel registry, and XBuilder.  Construct once per
     array; dispatch via ``RPCServer`` or call directly in-process."""
 
@@ -52,9 +52,8 @@ class HolisticGNNService:
         endpoint-transparent: the same RPCs, the same bit-identical
         sampling, whichever transport the shards use.
 
-        ``replication=R >= 2`` upgrades the array to a
-        ``ReplicatedGraphStore``: R-way replica placement with
-        replica-spread reads (fed by a gossiped counter view refreshed at
+        ``replication=R >= 2`` keeps R copies of every vertex class on
+        the array: R-way replica placement with replica-spread reads (fed by a gossiped counter view refreshed at
         most every ``stats_staleness_s`` seconds), write fan-out, and the
         ``fail_shard`` / ``rebuild_shard`` RPCs for serving through
         device failures.
@@ -78,17 +77,11 @@ class HolisticGNNService:
                                  "instead")
             arr_n = None if (devs is not None or endpoints is not None) \
                 else n_shards
-            if replication > 1:
-                from ..store.sharded import ReplicatedGraphStore
-                self.store = ReplicatedGraphStore(
-                    n_shards=arr_n, devs=devs, endpoints=endpoints,
-                    replication=replication, h_threshold=h_threshold,
-                    stats_staleness_s=stats_staleness_s, flow=flow)
-            else:
-                from ..store.sharded import ShardedGraphStore
-                self.store = ShardedGraphStore(
-                    n_shards=arr_n, devs=devs, endpoints=endpoints,
-                    h_threshold=h_threshold, flow=flow)
+            from ..store.sharded import ShardedGraphStore
+            self.store = ShardedGraphStore(
+                n_shards=arr_n, devs=devs, endpoints=endpoints,
+                replication=replication, h_threshold=h_threshold,
+                stats_staleness_s=stats_staleness_s, flow=flow)
         else:
             self.store = GraphStore(dev or BlockDevice(),
                                     h_threshold=h_threshold)
@@ -206,7 +199,7 @@ class HolisticGNNService:
 
     # ---------------------------------------------------------- fault admin
     def _replicated(self):
-        if not hasattr(self.store, "fail_shard"):
+        if getattr(self.store, "replication", 1) < 2:
             raise RuntimeError("shard fault RPCs need a replicated array "
                                "(construct with replication >= 2)")
         return self.store

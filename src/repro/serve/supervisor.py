@@ -80,7 +80,7 @@ class HealthPolicy:
 
 class ShardSupervisor:
     """Health monitor + auto-drain/auto-rebuild loop over one array store
-    (``ReplicatedGraphStore`` or ``ShardedGraphStore``).
+    (a ``ShardedGraphStore``, any replication).
 
     ``start()`` launches the monitor thread and attaches the supervisor
     as ``store.health`` (the store reports shard errors and reads the

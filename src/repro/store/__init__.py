@@ -6,14 +6,13 @@ from .endpoint import (LocalShardEndpoint, RopShardEndpoint, ShardEndpoint,
                        ShardHost, ShardService, make_local_endpoints,
                        make_rop_endpoints)
 from .ingest import MutationFirehose, distributed_update_graph
-from .sharded import (FlowControl, ReplicatedGraphStore, ShardedGraphStore,
-                      partition_csr)
+from .sharded import FlowControl, ShardedGraphStore, partition_csr
 from .sampler import (sample_batch, sample_batch_ref, pad_batch,
                       SampledBatch, LayerBlock)
 
 __all__ = ["BlockDevice", "DeviceFailedError", "PAGE_BYTES",
            "SLOTS_PER_PAGE", "GraphStore", "ShardedGraphStore",
-           "ReplicatedGraphStore", "FlowControl", "partition_csr",
+           "FlowControl", "partition_csr",
            "ShardEndpoint", "ShardService", "LocalShardEndpoint",
            "RopShardEndpoint", "ShardHost", "make_local_endpoints",
            "make_rop_endpoints",

@@ -22,9 +22,9 @@ makes the partition boundary a real message boundary:
     and *awaited* together, so the array still pays max(shard costs).
 
 The coordinator (``store/sharded.py``) speaks ONLY this protocol — no
-``.gmap`` / ``.h_chain`` / ``.dev`` attribute access — which is what lets
-``ShardedGraphStore``/``ReplicatedGraphStore`` drive an array whose
-shards live behind real links.  Because both endpoint flavours run the
+mapping-table or ``.dev`` attribute access — which is what lets
+``ShardedGraphStore`` drive an array whose shards live behind real
+links.  Because both endpoint flavours run the
 same ``ShardService`` code over the same page layouts, an array of
 ``RopShardEndpoint`` shards is **bit-identical** to the same array of
 ``LocalShardEndpoint`` shards under the same seed (healthy, degraded,
@@ -548,31 +548,24 @@ class ShardService:
         used = 0
         next_vid = -1
         done = True
-        with st._lock:
-            # vid list AND kinds in one snapshot: consulting the live map
-            # per-vid outside the lock could see a concurrent L->H
-            # promotion and ship a half-of-each view of that vertex
-            kinds = {v: k for v, k in st.gmap.items()
-                     if v % n_shards == cls and v >= int(start_vid)}
-        vids_c = sorted(kinds)
+        # vid list AND kinds in one snapshot: consulting the live map
+        # per-vid could see a concurrent L->H promotion and ship a
+        # half-of-each view of that vertex
+        vids_c, kinds = st.class_kinds(cls, n_shards, int(start_vid))
         pend_l: list[int] = []
-        for v in vids_c:
+        for v, kind in zip(vids_c.tolist(), kinds.tolist()):
             if used >= budget:
                 next_vid, done = v, False
                 break
-            kind = kinds[v]
             if kind == "L":
                 pend_l.append(v)
                 used += 1            # L vids are cheap; count conservatively
-            elif kind == "H":
-                with st._lock:
-                    chain = list(st.h_chain[v])
-                    pages = st.dev.read_pages(
-                        np.asarray(chain, dtype=np.int64), tag="graph")
+            else:
+                pages = st.export_chain(v)
                 h_vids.append(v)
-                h_lens.append(len(chain))
-                h_pages.append(np.array(pages))
-                used += len(chain)
+                h_lens.append(len(pages))
+                h_pages.append(pages)
+                used += len(pages)
         if pend_l:
             l_vids = pend_l
             l_nbrs = st.get_neighbors_batch(pend_l)

@@ -4,14 +4,15 @@ Implements the paper's dual mapping:
 
   * **H-type** (high-degree vertices): per-vertex chain of pages, each page
     ``[count, next_lpn, n0, n1, ...]``.  The mapping table entry is
-    VID -> head LPN (we additionally keep a tail pointer so appends are O(1),
+    VID -> head LPN (we keep the whole chain's LPNs, so appends find the
+    tail in O(1) and a batch fetches every chain page in one read; unit
     reads still walk the chain as in the paper).
   * **L-type** (low-degree vertices): many vertices packed in one page.
     Neighbor chunks grow from slot 0; meta grows from the page end:
     ``slot[-1]=n_nodes, slot[-2]=data_len,`` then per node *i*
     ``slot[-3-2i]=vid, slot[-4-2i]=chunk_offset``.  The L-type table key is
     the *largest* VID stored in the page (range search, paper Fig. 8).
-  * **gmap**: VID -> {H, L} selector bitmap.
+  * **selector**: VID -> {H, L} (``GraphStore._sel``).
 
 Embeddings are stored sequentially in the *embedding space* (top of the
 device, paper Fig. 7) with no page-level mapping: the location of VID *v*'s
@@ -43,8 +44,9 @@ H_CAP = SLOTS_PER_PAGE - _H_DATA          # neighbors per H page
 _L_NNODES = SLOTS_PER_PAGE - 1
 _L_DATALEN = SLOTS_PER_PAGE - 2
 
-# gmap's selector codes in its array twin (``GraphStore._sel``)
+# selector codes of ``GraphStore._sel`` (0: not mapped), and back
 _KIND = {"L": 1, "H": 2}
+_KIND_NAME = (None, "L", "H")
 
 
 def _l_meta_vid(i: int) -> int:
@@ -246,17 +248,14 @@ class GraphStore:
                  feature_dim: int = 0):
         self.dev = dev or BlockDevice()
         self.h_threshold = int(h_threshold)
-        self.gmap: dict[int, str] = {}                 # vid -> 'H' | 'L'
-        self.h_table: dict[int, tuple[int, int]] = {}  # vid -> (head_lpn, tail_lpn)
-        # full chain LPN list per H vid (device-DRAM mapping metadata, like
-        # the tail pointer): lets batched GetNeighbors fetch whole chains in
-        # one queued read instead of one pointer-chase round per page.
-        self.h_chain: dict[int, list[int]] = {}
-        # Array twins of the mapping tables, kept up to date by every site
-        # that changes them (``_map``, ``_map_chain``, the L-table inserts),
-        # so a frontier is planned with whole-array lookups:
-        #   _sel[vid]          gmap as a dense selector: 0 none, 1 L, 2 H
-        #   _chain_at/_n[vid]  vid's chain in _pool (length 0 unless H)
+        # The mapping tables (device-DRAM metadata), as arrays indexed by
+        # vid, so a frontier is planned with whole-array lookups:
+        #   _sel[vid]          selector: 0 not mapped, 1 L, 2 H
+        #   _chain_at/_n[vid]  vid's H chain in _pool (length 0 unless H):
+        #                      its head is _pool[_chain_at[vid]], its tail
+        #                      the run's last LPN.  The whole chain lets a
+        #                      batch fetch it in one queued read instead
+        #                      of one pointer-chase round per page.
         #   _pool              chain LPNs; a rewritten chain leaves its old
         #                      copy behind until a compaction
         self._sel = np.zeros(0, dtype=np.int8)
@@ -272,7 +271,6 @@ class GraphStore:
         self._emb_rows = 0
         self.num_vertices = 0
         self.stats = GraphStoreStats()
-        self._free_vids: list[int] = []                # deleted VIDs, reused (paper)
         self._lock = witness_lock("graphstore._lock", threading.RLock())
         self.cache = None                              # device-DRAM page cache
         self._cache_graph = True
@@ -327,32 +325,28 @@ class GraphStore:
 
     def _map(self, vids, kind: str | None) -> None:
         """Map ``vids`` as ``kind`` ('H' or 'L'), or unmap them (``None``),
-        in ``gmap`` and its selector twin ``_sel``.  An H vid's chain is
-        recorded apart (``_map_chain``)."""
+        in the selector ``_sel``.  An H vid's chain is recorded apart
+        (``_map_chain``)."""
         vids = np.asarray(vids, dtype=np.int64).reshape(-1)
         if not len(vids):
             return
-        if kind is None:
-            for v in vids.tolist():
-                self.gmap.pop(v, None)
-        else:
-            self.gmap.update(dict.fromkeys(vids.tolist(), kind))
         self._cover(int(vids.max()) + 1)
         self._sel[vids] = _KIND.get(kind, 0)
 
-    def _map_chain(self, vid: int, chain: list[int]) -> None:
-        """Record ``chain`` as ``vid``'s H chain in ``h_chain`` and its
-        twin: the LPNs go to the end of ``_pool`` and ``vid``'s table row
-        points at them.  Once superseded copies outweigh the live chains,
-        a full pool is compacted instead of grown."""
-        self.h_chain[vid] = chain
+    def _map_chain(self, vid: int, chain) -> None:
+        """Record the LPNs ``chain`` as ``vid``'s H chain: they go to the
+        end of ``_pool`` and ``vid``'s table row points at them.  Once
+        superseded copies outweigh the live chains, a full pool is
+        compacted before it is grown."""
+        chain = np.array(chain, dtype=np.int64)
         self._cover(vid + 1)
         self._pool_live += len(chain) - int(self._chain_n[vid])
+        self._chain_n[vid] = 0                 # its old run is superseded
+        if self._pool_top + len(chain) > len(self._pool) \
+                and self._pool_top > 2 * self._pool_live:
+            self._compact_pool()
         top = self._pool_top + len(chain)
         if top > len(self._pool):
-            if self._pool_top > 2 * self._pool_live:
-                self._compact_pool()
-                return
             grown = np.empty(max(top, 2 * len(self._pool), 1024), np.int64)
             grown[: self._pool_top] = self._pool[: self._pool_top]
             self._pool = grown
@@ -362,21 +356,57 @@ class GraphStore:
         self._pool_top = top
 
     def _unmap_chain(self, vid: int) -> None:
-        """Drop ``vid``'s H chain from ``h_chain`` and its twin."""
-        if self.h_chain.pop(vid, None) is not None:
+        """Drop ``vid``'s H chain from the table."""
+        if vid < len(self._chain_n) and self._chain_n[vid]:
             self._pool_live -= int(self._chain_n[vid])
             self._chain_n[vid] = 0
 
     def _compact_pool(self) -> None:
-        """Rewrite ``_pool`` with the live chains only (``h_chain``)."""
+        """Rewrite ``_pool`` with the live chains only, in vid order,
+        sized for the live chains plus any being recorded."""
+        live = np.flatnonzero(self._chain_n)
+        n = self._chain_n[live]
+        top = int(n.sum())
         pool = np.empty(max(2 * self._pool_live, 1024), np.int64)
-        at = 0
-        for v, chain in self.h_chain.items():
-            pool[at: at + len(chain)] = chain
-            self._chain_at[v] = at
-            self._chain_n[v] = len(chain)
-            at += len(chain)
-        self._pool, self._pool_top = pool, at
+        pool[:top] = self._pool[np.repeat(self._chain_at[live], n)
+                                + _ramp(n)]
+        self._chain_at[live] = np.cumsum(n) - n
+        self._pool, self._pool_top = pool, top
+
+    def _head(self, vid: int) -> int:
+        """First LPN of H vid ``vid``'s chain."""
+        return int(self._pool[self._chain_at[vid]])
+
+    def kind_of(self, vid: int) -> str | None:
+        """'H' or 'L' for a vid this store maps, else ``None``."""
+        v = int(vid)
+        return _KIND_NAME[int(self._sel[v])] if 0 <= v < len(self._sel) \
+            else None
+
+    def chain_of(self, vid: int) -> np.ndarray:
+        """The LPNs of ``vid``'s H chain, head first (empty unless H)."""
+        v = int(vid)
+        if not 0 <= v < len(self._chain_n):
+            return np.empty(0, dtype=np.int64)
+        at = int(self._chain_at[v])
+        return self._pool[at: at + int(self._chain_n[v])].copy()
+
+    def class_kinds(self, cls: int = 0, modulus: int = 1,
+                    start_vid: int = 0) -> tuple[np.ndarray, np.ndarray]:
+        """One locked snapshot of the mapped vids ``v >= start_vid`` with
+        ``v % modulus == cls`` (every mapped vid by default), ascending,
+        and each one's kind ('H' or 'L')."""
+        with self._lock:
+            vids = np.flatnonzero(self._sel)
+            vids = vids[(vids % modulus == cls) & (vids >= start_vid)]
+            return vids, np.array(_KIND_NAME[1:])[self._sel[vids] - 1]
+
+    def export_chain(self, vid: int) -> np.ndarray:
+        """The raw pages of ``vid``'s H chain, head first, read straight
+        off the device in one locked step — the export half of
+        ``import_h_chain``."""
+        with self._lock:
+            return self.dev.read_pages(self.chain_of(vid), tag="graph")
 
     def _kinds(self, vids: np.ndarray) -> np.ndarray:
         """Selector code of each vid (0 for a vid this store never held)."""
@@ -558,12 +588,12 @@ class GraphStore:
     def get_neighbors(self, vid: int) -> np.ndarray:
         """Paper GetNeighbors(VID) unit RPC."""
         with self._lock:
-            kind = self.gmap.get(int(vid))
+            kind = self.kind_of(vid)
             if kind is None:
                 return np.empty(0, dtype=SLOT_DTYPE)
             if kind == "H":
                 out = []
-                lpn, _ = self.h_table[int(vid)]
+                lpn = self._head(int(vid))
                 while lpn >= 0:
                     page = self.dev.read_page(lpn)
                     cnt = int(page[_H_COUNT])
@@ -666,7 +696,7 @@ class GraphStore:
         range-search index (``searchsorted`` over the page keys; -1 when
         this store has no L pages).  Lets an array coordinator plan a
         replica-spread fetch with ONE call per vertex class instead of
-        reaching into ``h_chain``/``_l_keys`` directly."""
+        reaching into the mapping tables directly."""
         with self._lock:
             chain_len = self._chain_lens(vids)
             if len(self._l_keys):
@@ -685,21 +715,14 @@ class GraphStore:
         chain the vid already owns is freed first, so a migration redo
         after a mid-copy failure cannot leak or double-map pages."""
         with self._lock:
-            if self.gmap.get(vid) == "H":
-                old, _ = self.h_table.pop(vid)
-                self._unmap_chain(vid)
-                while old >= 0:
-                    pg = self.dev.read_page(old)
-                    nxt = int(pg[_H_NEXT])
-                    self.dev.free_page(old)
-                    old = nxt
+            if self.kind_of(vid) == "H":
+                self._free_chain(vid)
             new_lpns = [self.dev.alloc_front() for _ in range(len(pages))]
             for i, lpn in enumerate(new_lpns):
                 page = np.asarray(pages[i], dtype=SLOT_DTYPE).copy()
                 page[_H_NEXT] = new_lpns[i + 1] if i + 1 < len(new_lpns) \
                     else -1
                 self.dev.write_page(lpn, page)
-            self.h_table[vid] = (new_lpns[0], new_lpns[-1])
             self._map_chain(vid, new_lpns)
             self._map([vid], "H")
             self.stats.pages_h += len(new_lpns)
@@ -711,37 +734,9 @@ class GraphStore:
         after a mid-copy failure converges to the same state."""
         with self._lock:
             vid = int(vid)
-            kind = self.gmap.get(vid)
-            if kind == "H":
-                lpn, _ = self.h_table.pop(vid)
-                self._unmap_chain(vid)
-                while lpn >= 0:
-                    pg = self.dev.read_page(lpn)
-                    nxt = int(pg[_H_NEXT])
-                    self.dev.free_page(lpn)
-                    lpn = nxt
-                self._map([vid], None)
-            elif kind == "L":
-                hit = self._l_lookup_page(vid)
-                if hit is not None:
-                    lpn, page = hit
-                    self._l_remove_node(page, lpn, vid)
-                self._map([vid], None)
-            chunk = np.asarray(nbrs, dtype=SLOT_DTYPE).reshape(-1)
-            if not len(self._l_keys):
-                self._l_insert_new_page([vid], [chunk])
-            elif vid > self._l_keys[-1]:
-                lpn = int(self._l_lpns[-1])
-                page = self.dev.read_page(lpn).copy()
-                if self._l_free_slots(page) >= len(chunk) + 2:
-                    self._l_append_node(page, vid, chunk)
-                    self.dev.write_page(lpn, page)
-                    self._l_keys[-1] = vid
-                else:
-                    self._l_insert_new_page([vid], [chunk])
-            else:
-                k = int(np.searchsorted(self._l_keys, vid))
-                self._l_split_insert(k, vid, chunk)
+            self._drop_vertex_pages(vid)
+            self._l_insert(vid,
+                           np.asarray(nbrs, dtype=SLOT_DTYPE).reshape(-1))
             self._map([vid], "L")
             self.num_vertices = max(self.num_vertices, vid + 1)
 
@@ -752,7 +747,8 @@ class GraphStore:
         the next rebuild compacts them).  Returns the vertex count
         dropped."""
         with self._lock:
-            vids = [v for v in list(self.gmap) if v % modulus == cls]
+            vids = np.flatnonzero(self._sel)
+            vids = vids[vids % modulus == cls].tolist()
             for v in vids:
                 self._drop_vertex_pages(v)
             return len(vids)
@@ -972,48 +968,39 @@ class GraphStore:
         self._l_lpns = np.insert(self._l_lpns, k, new_lpn)
 
     def add_vertex(self, vid: int, embed: np.ndarray | None = None) -> None:
-        """AddVertex: self-loop only, starts as L-type (paper).  Ascending
-        VIDs append to the last page; out-of-order VIDs split-insert into
-        the page covering their range."""
+        """AddVertex: self-loop only, starts as L-type (paper), placed by
+        ``_l_insert``."""
         with self._lock:
             vid = int(vid)
-            if vid in self.gmap:
+            if self.kind_of(vid) is not None:
                 return
             self.stats.unit_updates += 1
-            loop = np.array([vid], dtype=SLOT_DTYPE)
-            if not len(self._l_keys):
-                self._l_insert_new_page([vid], [loop])
-            elif vid > self._l_keys[-1]:
-                lpn = int(self._l_lpns[-1])
-                page = self.dev.read_page(lpn).copy()
-                if self._l_free_slots(page) >= 3:
-                    self._l_append_node(page, vid, loop)
-                    self.dev.write_page(lpn, page)
-                    self._l_keys[-1] = vid
-                else:
-                    self._l_insert_new_page([vid], [loop])
-            else:
-                k = int(np.searchsorted(self._l_keys, vid))
-                self._l_split_insert(k, vid, loop)
+            self._l_insert(vid, np.array([vid], dtype=SLOT_DTYPE))
             self._map([vid], "L")
             self.num_vertices = max(self.num_vertices, vid + 1)
             if embed is not None:
                 self.update_embed(vid, embed)
 
-    def _l_insert_new_page(self, vids, chunks) -> None:
-        lpn, page = self._new_l_page()
-        dlen = 0
-        for i, (v, ch) in enumerate(zip(vids, chunks)):
-            page[_l_meta_vid(i)] = v
-            page[_l_meta_off(i)] = dlen
-            page[dlen: dlen + len(ch)] = ch
-            dlen += len(ch)
-        page[_L_NNODES] = len(vids)
-        page[_L_DATALEN] = dlen
-        self.dev.write_page(lpn, page)
-        key = int(max(vids))
-        k = int(np.searchsorted(self._l_keys, key))
-        self._l_keys = np.insert(self._l_keys, k, key)
+    def _l_insert(self, vid: int, chunk: np.ndarray) -> None:
+        """Place the L node ``(vid, chunk)``: ascending VIDs append to the
+        last page (a fresh page once it is full); out-of-order VIDs
+        split-insert into the page covering their range."""
+        if len(self._l_keys) and vid <= self._l_keys[-1]:
+            k = int(np.searchsorted(self._l_keys, vid))
+            self._l_split_insert(k, vid, chunk)
+            return
+        if len(self._l_keys):
+            lpn = int(self._l_lpns[-1])
+            page = self.dev.read_page(lpn).copy()
+            if self._l_free_slots(page) >= len(chunk) + 2:
+                self._l_append_node(page, vid, chunk)
+                self.dev.write_page(lpn, page)
+                self._l_keys[-1] = vid
+                return
+        lpn, _ = self._new_l_page()
+        self.dev.write_page(lpn, self._l_build_page([(vid, chunk)]))
+        k = int(np.searchsorted(self._l_keys, vid))
+        self._l_keys = np.insert(self._l_keys, k, vid)
         self._l_lpns = np.insert(self._l_lpns, k, lpn)
 
     @staticmethod
@@ -1030,15 +1017,15 @@ class GraphStore:
         with self._lock:
             self.stats.unit_updates += 1
             for v in (dst, src):
-                if v not in self.gmap:
-                    self.add_vertex(v)
+                self.add_vertex(v)              # no-op when v is mapped
             self._insert_neighbor(int(dst), int(src))
             if dst != src:
                 self._insert_neighbor(int(src), int(dst))
 
     def _insert_neighbor(self, vid: int, nbr: int) -> None:
-        if self.gmap[vid] == "H":
-            head, tail = self.h_table[vid]
+        if self.kind_of(vid) == "H":
+            chain = self.chain_of(vid)
+            tail = int(chain[-1])
             page = self.dev.read_page(tail).copy()
             cnt = int(page[_H_COUNT])
             if cnt < H_CAP:
@@ -1054,8 +1041,7 @@ class GraphStore:
                 self.dev.write_page(lpn, newp)
                 page[_H_NEXT] = lpn
                 self.dev.write_page(tail, page)
-                self.h_table[vid] = (head, lpn)
-                self._map_chain(vid, self.h_chain[vid] + [lpn])
+                self._map_chain(vid, np.append(chain, lpn))
                 self.stats.pages_h += 1
             return
         # ---- L-type
@@ -1102,7 +1088,6 @@ class GraphStore:
 
     def _write_h_chain(self, vid: int, nbrs: np.ndarray) -> None:
         """Write a fresh H chain for ``vid`` and record its mapping."""
-        head = tail = -1
         chain: list[int] = []
         for c0 in range(0, len(nbrs), H_CAP):
             chunk = nbrs[c0: c0 + H_CAP]
@@ -1113,15 +1098,11 @@ class GraphStore:
             page[_H_DATA: _H_DATA + len(chunk)] = chunk
             self.dev.write_page(lpn, page)
             self.stats.pages_h += 1
-            chain.append(lpn)
-            if head < 0:
-                head = lpn
-            else:
-                prev = self.dev.read_page(tail).copy()
+            if chain:
+                prev = self.dev.read_page(chain[-1]).copy()
                 prev[_H_NEXT] = lpn
-                self.dev.write_page(tail, prev)
-            tail = lpn
-        self.h_table[vid] = (head, tail)
+                self.dev.write_page(chain[-1], prev)
+            chain.append(lpn)
         self._map_chain(vid, chain)
 
     def _l_shift_left(self, page: np.ndarray, start: int, ln: int) -> None:
@@ -1153,11 +1134,11 @@ class GraphStore:
                 self._remove_neighbor(int(src), int(dst))
 
     def _remove_neighbor(self, vid: int, nbr: int) -> None:
-        kind = self.gmap.get(vid)
+        kind = self.kind_of(vid)
         if kind is None:
             return
         if kind == "H":
-            lpn, _ = self.h_table[vid]
+            lpn = self._head(vid)
             while lpn >= 0:
                 page = self.dev.read_page(lpn).copy()
                 cnt = int(page[_H_COUNT])
@@ -1213,22 +1194,25 @@ class GraphStore:
         """Release ``vid``'s own mapping + pages (not its neighbors' backlinks
         — the sharded coordinator removes those on each neighbor's owning
         shard before calling this on the owner)."""
-        kind = self.gmap.get(vid)
+        kind = self.kind_of(vid)
         self._map([vid], None)
         if kind == "H":
-            lpn, _ = self.h_table.pop(vid)
-            self._unmap_chain(vid)
-            while lpn >= 0:
-                page = self.dev.read_page(lpn)
-                nxt = int(page[_H_NEXT])
-                self.dev.free_page(lpn)
-                lpn = nxt
+            self._free_chain(vid)
         elif kind == "L":
             hit = self._l_lookup_page(vid)
             if hit is not None:
                 lpn, page = hit
                 self._l_remove_node(page, lpn, vid)
-        self._free_vids.append(vid)
+
+    def _free_chain(self, vid: int) -> None:
+        """Unmap ``vid``'s H chain and free its pages, walked from the
+        head through ``_H_NEXT``."""
+        lpn = self._head(vid)
+        self._unmap_chain(vid)
+        while lpn >= 0:
+            nxt = int(self.dev.read_page(lpn)[_H_NEXT])
+            self.dev.free_page(lpn)
+            lpn = nxt
 
     def update_embed(self, vid: int, embed: np.ndarray) -> None:
         """UpdateEmbed(VID, Embed): in-place page RMW of one feature row."""
@@ -1254,10 +1238,8 @@ class GraphStore:
     # ============================================================== export
     def to_adjacency(self) -> dict[int, set[int]]:
         """Full adjacency export (oracle/validation only — reads every page)."""
-        out: dict[int, set[int]] = {}
-        for vid in list(self.gmap):
-            out[vid] = set(int(x) for x in self.get_neighbors(vid))
-        return out
+        return {vid: set(int(x) for x in self.get_neighbors(vid))
+                for vid in np.flatnonzero(self._sel).tolist()}
 
 
 # ---------------------------------------------------------------- preprocessing

@@ -71,7 +71,7 @@ def distributed_update_graph(store, edge_array, embeddings=None, *,
     tl = BulkTimeline()
     t0 = time.perf_counter()
     N = store.n_shards
-    R = int(getattr(store, "replication", 1))
+    R = store.replication
     ce = max(1, int(chunk_edges))
     er = max(1, int(emb_chunk_rows))
 
@@ -306,8 +306,8 @@ class MutationFirehose:
             self.counters.submitted += 1
 
     def _check_embed(self, vid: int) -> None:
-        """Replicated arrays bounds-check embed rows at the unit RPC; the
-        firehose keeps that contract at submission time, so a bad row is
+        """Arrays bounds-check embed rows at the unit RPC; the firehose
+        keeps that contract at submission time, so a bad row is
         rejected to the submitter instead of poisoning a later window."""
         check = getattr(self.store, "_check_emb_vid", None)
         if check is not None:
@@ -363,12 +363,8 @@ class MutationFirehose:
     def _replicas(self, vid: int) -> list[tuple[int, int]]:
         """(shard, local embedding row) of every live replica of ``vid``
         — primary first, resolved through the store's current routing
-        (placement-map and reshard aware); plain sharded arrays have
-        exactly the owner."""
-        st = self.store
-        if hasattr(st, "_emb_locate"):
-            return st._emb_locate(vid)
-        return [(int(vid) % st.n_shards, int(vid) // st.n_shards)]
+        (placement-map and reshard aware); at R = 1 exactly the owner."""
+        return self.store._emb_locate(vid)
 
     def _apply_window(self, window: list[tuple]) -> int:
         st = self.store

@@ -51,12 +51,13 @@ both endpoint flavours run the same device-side code, a remote
 (``RopShardEndpoint``) array is bit-identical to a local one
 (``tests/test_sharded_store.py``, ``tests/test_endpoint.py``).
 
-``ReplicatedGraphStore`` (below) extends the array with R-way replica
-placement: page-granular replica-spread reads against hub skew (fed by a
-gossiped, staleness-bounded view of the shards' read counters), write
+With ``replication=R >= 2`` the same coordinator keeps R copies of every
+vertex class: page-granular replica-spread reads against hub skew (fed by
+a gossiped, staleness-bounded view of the shards' read counters), write
 fan-out, and a ``fail_shard``/``rebuild_shard`` fault path whose rebuild
 streams survivor pages shard-to-shard over the endpoints' peer links —
-same plan->fetch->build contract, same bit-identity (see its docstring).
+same plan->fetch->build contract, same bit-identity (see the class
+docstring).
 """
 from __future__ import annotations
 
@@ -315,7 +316,44 @@ class _ShardedCacheView:
 
 class ShardedGraphStore:
     """Drop-in for ``GraphStore`` across the query/mutation surface the
-    service layer uses, backed by ``n_shards`` shard endpoints.
+    service layer uses, backed by ``n_shards`` shard endpoints, with
+    every vertex class kept on ``replication`` of them.
+
+    Placement: replica ``r`` of vertex ``vid`` lives on shard
+    ``(vid + r) % N``, its adjacency pages and its embedding row alike,
+    so shard ``s`` holds the R classes ``{(s - r) % N}`` and one
+    round-robin embedding stripe per role — the shard-local page math
+    stays the single-device math per stripe.  At R = 1 this is the plain
+    hash partitioning above: one owner per vid.
+
+    Reads: at R = 1 each vid's owner plans and serves it.  At R >= 2 the
+    plan stage assigns every page fetch of the request — H chain pages,
+    L vids by their shared page cost, embedding rows by stripe page — to
+    a live replica with an exact min-max solver (``_select_replicas``)
+    over a GOSSIPED view of the shards' page-read counters, pulled at
+    most every ``stats_staleness_s`` seconds, so the feedback loop never
+    reads shard state synchronously (the multi-host requirement).
+    Replicas hold identical data and the recomposed plan is
+    position-identical to the single-device plan, so the spread changes
+    WHICH device pays each page, never the result; since the array's
+    deferred latency is the max over shards, flattening the per-shard
+    page distribution is a direct win on skewed mixes (fig24: balance
+    0.36 -> 1.00, batched-read IO ~1.4x at R=2).
+
+    Writes fan out to every live replica under the coordinator mutation
+    lock; a replica that fails mid-fan-out is skipped — its state died
+    with the device and ``rebuild_shard`` re-materialises it.
+
+    Fault path: ``fail_shard(s)`` drops a device only when each of its
+    classes keeps a live replica (so at R = 1 it refuses any shard that
+    owns a class); fetches already planned onto it re-plan against the
+    survivors (``_with_failover``) and stay bit-identical.
+    ``rebuild_shard(s)`` refills a fresh device by shard-to-shard chunked
+    streaming: the destination endpoint pulls bounded page chunks from
+    each class's survivor over the peer links (H chains cloned
+    page-exactly, which the page spread relies on), so survivor pages
+    never transit the coordinator and restoring redundancy costs it one
+    RPC.
 
     Construction (exactly one backing form):
 
@@ -327,23 +365,35 @@ class ShardedGraphStore:
             endpoints are built around them).
         endpoints: pre-built ``ShardEndpoint`` list (local, remote, or
             mixed); adopted as-is, including their ``h_threshold``.
+        replication: replica count R (1 <= R <= N); every vertex class
+            keeps R copies, one per role column.
         h_threshold: L/H degree threshold pushed to owned endpoints.
         feature_dim: embedding width for owned endpoints (0 until a
             table is loaded).
-        placement: optional :class:`repro.store.placement.PlacementMap`
-            replacing the default ``vid % N`` ownership (must have one
-            role column for the unreplicated store).
+        placement: optional R-role
+            :class:`repro.store.placement.PlacementMap` replacing the
+            default ``(c + r) % N`` replica ring.
+        stats_staleness_s: max age of the gossiped read-counter snapshot
+            replica selection plans against (0 = refresh every
+            selection).
+        rebuild_chunk_pages: page budget per shard-to-shard chunk pull
+            during rebuild and reshard streams.
         flow: :class:`FlowControl` policy (defaults applied when None).
 
     Raises:
-        ValueError: conflicting backing arguments, zero shards, or a
-            placement map that is not total over the array.
+        ValueError: conflicting backing arguments, zero shards,
+            replication out of range, or a placement map that is not
+            total over the array or whose role count differs from
+            ``replication``.
     """
 
     def __init__(self, n_shards: int | None = None,
                  devs: list | None = None, *, endpoints: list | None = None,
+                 replication: int = 1,
                  h_threshold: int = 128, feature_dim: int = 0,
                  placement: PlacementMap | None = None,
+                 stats_staleness_s: float = 0.0,
+                 rebuild_chunk_pages: int = 512,
                  flow: FlowControl | None = None):
         if endpoints is not None:
             if devs is not None:
@@ -430,11 +480,12 @@ class ShardedGraphStore:
             self._failed[s] = bool(snap["failed"])
             if not own_endpoints:
                 self.h_threshold = int(snap["store"]["h_threshold"])
-        # routing generation + reshard machinery.  ``replication`` and
-        # ``_emb_rows`` live on the base class so the routing/locate math
-        # is shared; the replicated subclass overwrites them before its
-        # own ``_init_routing`` call.
-        self.replication = 1
+        r = int(replication)
+        if not 1 <= r <= self.n_shards:
+            raise ValueError(f"replication={r} needs 1 <= R <= "
+                             f"n_shards={self.n_shards}")
+        self.replication = r
+        self.rebuild_chunk_pages = int(rebuild_chunk_pages)
         self._emb_rows = 0
         # reader barrier: batched reads register with ``_read_routing``
         # so a class flip can quiesce every in-flight read that may hold
@@ -449,28 +500,34 @@ class ShardedGraphStore:
         self._mig_cv = threading.Condition(self._mutate)
         self._resharding = False
         self._reshard_stats: dict = {}
-        if not hasattr(self, "_init_routing_deferred"):
-            self._init_routing(1, placement)
-
-    # ------------------------------------------------------------- routing
-    def _init_routing(self, replication: int, placement) -> None:
-        """Install the initial routing generation: the given placement
-        map (validated against the array) or the legacy modular map
-        ``owner[c, r] = (c + r) % N``, with canonical embedding extents.
-
-        Raises:
-            ValueError: placement map that is not total, out of range,
-                or has the wrong number of role columns.
-        """
+        # the initial routing generation: the given placement map or the
+        # modular ring ``owner[c, r] = (c + r) % N``, canonical extents
         pmap = placement if placement is not None else modular(
-            self.n_shards, replication)
-        if pmap.owner.shape[1] != replication:
+            self.n_shards, r)
+        if pmap.owner.shape[1] != r:
             raise ValueError(
                 f"placement map has {pmap.owner.shape[1]} role columns, "
-                f"store replication is {replication}")
+                f"store replication is {r}")
         pmap.validate(self.n_shards)
-        self._routing = self._canonical_routing(pmap, self._emb_rows, 0)
+        self._routing = self._canonical_routing(pmap, 0, 0)
+        # gossiped selection feedback (R >= 2): every selection starts
+        # from a staleness-bounded snapshot of the shards' ACTUAL
+        # page-read counters since the last topology change (periodic
+        # ``counters`` pulls — never a synchronous shard peek).  Cache
+        # hits never reach the device counter, so cached reads correctly
+        # stop counting as device load.
+        self.stats_staleness_s = float(stats_staleness_s)
+        self.gossip_pulls = 0                      # guarded-by: _gossip_lock
+        self._gossip_lock = witness_lock(
+            "sharded._gossip_lock", threading.Lock())
+        self._gossip_reads = np.zeros(self.n_shards)   # guarded-by: _gossip_lock
+        self._gossip_depth = np.zeros(self.n_shards)   # guarded-by: _gossip_lock
+        self._gossip_t = -np.inf                   # guarded-by: _gossip_lock
+        self._gossip_inflight = False              # guarded-by: _gossip_lock
+        self._read_base = np.zeros(0)              # guarded-by: _gossip_lock
+        self._reset_feedback()
 
+    # ------------------------------------------------------------- routing
     def _canonical_routing(self, pmap: PlacementMap, n_rows: int,
                            epoch: int, heat: np.ndarray | None = None
                            ) -> _Routing:
@@ -535,10 +592,15 @@ class ShardedGraphStore:
                 self._rd_barrier = False
                 cv.notify_all()
 
-    def _check_not_resharding(self, what: str) -> None:
+    def _check_bulk_ingest(self) -> None:
+        """Reject a bulk ingest during an online reshard or while a shard
+        is failed (callers hold the maintenance gate)."""
         if self._resharding:
             raise RuntimeError(
-                f"{what} rejected: online reshard in progress")
+                "bulk ingest rejected: online reshard in progress")
+        if any(self._failed):
+            raise DeviceFailedError(
+                "bulk ingest needs every shard live; rebuild_shard first")
 
     def _emb_locate(self, vid: int, rt: _Routing | None = None):
         """Live (shard, local embedding row) candidates for ``vid``,
@@ -582,15 +644,6 @@ class ShardedGraphStore:
     def devs(self) -> list:
         """The shards' ``BlockDevice``s (in-process arrays only)."""
         return [sh.dev for sh in self.shards]
-
-    def owner_of(self, vid: int) -> int:
-        """Primary owner shard of ``vid`` under the current routing
-        (equals ``vid % n_shards`` at the default modular placement)."""
-        rt = self._routing
-        return int(rt.pmap.owner[int(vid) % rt.pmap.n_classes, 0])
-
-    def _owner_ep(self, vid: int):
-        return self.endpoints[self.owner_of(vid)]
 
     def _map(self, fn, items):
         """Bulk-ingest fan-out: per-shard write bursts (ms-scale simulated
@@ -682,13 +735,6 @@ class ShardedGraphStore:
             [embeddings[c::C] for c, _r in pmap.pairs_of(s)]) \
             if pmap.pairs_of(s) else embeddings[:0]
 
-    def _adj_shard_csr(self, indptr: np.ndarray, indices: np.ndarray,
-                       s: int):
-        """The global-CSR mask shard ``s`` writes as adjacency (all owned
-        classes under the current placement map)."""
-        return partition_csr(indptr, indices, self.n_shards, s,
-                             placement=self._routing.pmap)
-
     def update_graph(self, edge_array: np.ndarray,
                      embeddings: np.ndarray | None = None,
                      *, already_undirected: bool = False) -> BulkTimeline:
@@ -697,12 +743,23 @@ class ShardedGraphStore:
         The global edge preprocessing runs once, overlapped with the
         (much larger) embedding write exactly as on one device — except the
         embedding table is striped ``embeddings[s::N]`` and every shard's
-        sequential write burst proceeds in parallel on its own device.
+        sequential write burst proceeds in parallel on its own device;
+        every shard writes all R of its owned stripes.
+
+        Runs behind the maintenance gate: a bulk ingest must not
+        interleave with a streaming rebuild (and a rebuild in progress
+        means a failed flag is still set, which the check rejects).
 
         Raises:
+            DeviceFailedError: a shard is failed (rebuild first).
             RuntimeError: an online reshard is migrating classes.
         """
-        self._check_not_resharding("bulk ingest")
+        with self._maintenance:
+            self._check_bulk_ingest()
+            return self._update_graph(edge_array, embeddings,
+                                      already_undirected)
+
+    def _update_graph(self, edge_array, embeddings, already_undirected):
         tl = BulkTimeline()
         t0 = time.perf_counter()
 
@@ -747,7 +804,8 @@ class ShardedGraphStore:
         self._num_vertices = max(self._num_vertices, len(indptr) - 1)
 
         def write_adj(s):
-            ip, ix = self._adj_shard_csr(indptr, indices, s)
+            ip, ix = partition_csr(indptr, indices, self.n_shards, s,
+                                   placement=self._routing.pmap)
             self.endpoints[s].call("write_adjacency", indptr=ip, indices=ix)
 
         self._map(write_adj, range(self.n_shards))
@@ -779,11 +837,7 @@ class ShardedGraphStore:
         if emb_chunk_rows is not None:
             kw["emb_chunk_rows"] = int(emb_chunk_rows)
         with self._maintenance:
-            self._check_not_resharding("bulk ingest")
-            if any(self._failed):
-                raise DeviceFailedError(
-                    "bulk ingest needs every shard live; rebuild_shard "
-                    "first")
+            self._check_bulk_ingest()
             return distributed_update_graph(
                 self, edge_array, embeddings,
                 already_undirected=already_undirected, **kw)
@@ -795,16 +849,18 @@ class ShardedGraphStore:
         return MutationFirehose(self, **kw)
 
     # ------------------------------------------------------ batched queries
-    def _partition(self, vids: np.ndarray,
-                   rt: _Routing | None = None
+    def _partition(self, vids: np.ndarray, rt: _Routing,
+                   owner: np.ndarray | None = None
                    ) -> list[tuple[int, np.ndarray]]:
-        """plan phase: query positions grouped by primary-owner shard
-        under routing snapshot ``rt`` (no I/O).  Also accumulates the
-        per-class read heat the heat-aware resharder partitions on."""
-        rt = rt or self._routing
-        cls = vids % rt.pmap.n_classes
-        np.add.at(rt.heat, cls, 1.0)
-        owner = rt.pmap.owner[cls, 0]
+        """plan phase: query positions grouped by serving shard (no I/O)
+        — each vid's primary owner under routing snapshot ``rt``, which
+        also accumulates the per-class read heat the heat-aware
+        resharder partitions on, unless ``owner`` already names a shard
+        per position (a replica selection, which counts its own heat)."""
+        if owner is None:
+            cls = vids % rt.pmap.n_classes
+            np.add.at(rt.heat, cls, 1.0)
+            owner = rt.pmap.owner[cls, 0]
         parts = [(s, np.nonzero(owner == s)[0])
                  for s in range(self.n_shards)]
         return [(s, pos) for s, pos in parts if len(pos)]
@@ -884,9 +940,10 @@ class ShardedGraphStore:
         for win in taken:
             win.release()
 
-    def _submit_round(self, items: list) -> list:
-        """One concurrent metadata round: submit ``(shard, method,
-        kwargs)`` to every listed endpoint, then await all completions.
+    def _round(self, items: list, submit, result, what: str) -> list:
+        """One concurrent round: ``submit(item)`` on the endpoint of each
+        ``item[0]`` shard, then ``result(shard, handle)`` for each, in
+        order.
 
         A ``QueueFullError`` part-way through the submits must not abort
         the round half-issued: the handles already written are reaped
@@ -894,48 +951,65 @@ class ShardedGraphStore:
         after exponential backoff — bounded by ``flow.submit_retries``,
         after which it sheds as typed ``BackpressureError``.  A shard
         that fails mid-round is reported to the supervisor and the
-        remaining completions are reaped before the error propagates."""
+        remaining completions are reaped before the error propagates (a
+        local endpoint computes at submit time, so a drained device may
+        surface at submit rather than at await)."""
         for attempt in range(self.flow.submit_retries + 1):
             handles: list = []
             try:
-                for s, method, kw in items:
-                    handles.append(
-                        (s, self.endpoints[s].call_submit(method, **kw)))
+                for it in items:
+                    handles.append((it[0], submit(it)))
             except QueueFullError as e:
-                self._reap_call_handles(handles)
+                self._reap(handles, result)
                 if attempt >= self.flow.submit_retries:
                     raise self._shed(
-                        f"submit round over {len(items)} shards still "
-                        f"queue-full after {attempt + 1} attempts: {e}",
-                        {"source": "queue_full", "shard": int(items[len(handles)][0]),
+                        f"{what} still queue-full after {attempt + 1} "
+                        f"attempts: {e}",
+                        {"source": "queue_full",
+                         "shard": int(items[len(handles)][0]),
                          "attempts": attempt + 1, "qid": e.qid}) from e
                 self._bp_backoff(attempt)
                 continue
             except Exception as e:
-                self._reap_call_handles(handles)
+                self._reap(handles, result)
                 if isinstance(e, DeviceFailedError):
                     self._notify_shard_error(items[len(handles)][0], e)
                 raise
             outs: list = []
             try:
                 for s, h in handles:
-                    outs.append(self.endpoints[s].call_result(h))
-            except Exception as e:
-                self._reap_call_handles(handles[len(outs) + 1:])
+                    outs.append(result(s, h))
+            except BaseException as e:
+                # reap every outstanding completion before re-raising, or
+                # their reply payloads sit in the CQs forever — each
+                # failover retry would leak the healthy shards' full page
+                # blocks.  The handle whose await raised is consumed.
+                self._reap(handles[len(outs) + 1:], result)
                 if isinstance(e, DeviceFailedError):
                     self._notify_shard_error(handles[len(outs)][0], e)
                 raise
             return outs
         raise AssertionError("unreachable")
 
-    def _reap_call_handles(self, handles) -> None:
-        """Consume outstanding ``call_submit`` completions (best-effort)
-        so abandoned replies never sit in the CQs forever."""
+    @staticmethod
+    def _reap(handles, result) -> None:
+        """Consume outstanding completions (best-effort) so abandoned
+        replies never sit in the CQs forever."""
         for s, h in handles:
             try:
-                self.endpoints[s].call_result(h)
+                result(s, h)
             except Exception:  # noqa: BLE001 — best-effort reap
                 pass
+
+    def _submit_round(self, items: list) -> list:
+        """One concurrent metadata round: ``(shard, method, kwargs)``
+        items, submitted to every listed endpoint, then awaited (see
+        ``_round``)."""
+        eps = self.endpoints
+        return self._round(
+            items, lambda it: eps[it[0]].call_submit(it[1], **it[2]),
+            lambda s, h: eps[s].call_result(h),
+            f"submit round over {len(items)} shards")
 
     def probe_shards(self) -> list[dict]:
         """Supervisor heartbeat: one concurrent ``counters`` round over
@@ -981,75 +1055,34 @@ class ShardedGraphStore:
         backoff before shedding as typed ``BackpressureError``.
         """
         slots = self._acquire_windows([s for s, _ in reqs])
+        eps = self.endpoints
         try:
-            handles = self._submit_fetches(reqs)
-            outs, worst = self._await_fetches(handles)
+            outs = self._round(
+                reqs, lambda it: eps[it[0]].fetch_submit(**it[1]),
+                lambda s, h: eps[s].fetch_result(h), "batched fetch")
         finally:
             self._release_windows(slots)
+        worst = max([0.0] + [float(pl["io_us"]) for pl in outs])
         if pay:
             with self._bp_lock:
                 self.io_wait_us += worst
             sleep_us(worst)
         return outs, worst
 
-    def _submit_fetches(self, reqs) -> list:
-        for attempt in range(self.flow.submit_retries + 1):
-            handles: list = []
-            try:
-                for s, kw in reqs:
-                    handles.append((s, self.endpoints[s].fetch_submit(**kw)))
-                return handles
-            except QueueFullError as e:
-                self._reap_fetch_handles(handles)
-                if attempt >= self.flow.submit_retries:
-                    raise self._shed(
-                        f"batched fetch still queue-full after "
-                        f"{attempt + 1} attempts: {e}",
-                        {"source": "queue_full",
-                         "shard": int(reqs[len(handles)][0]),
-                         "attempts": attempt + 1, "qid": e.qid}) from e
-                self._bp_backoff(attempt)
-            except Exception as e:
-                # a local endpoint computes at submit time, so a drained
-                # device surfaces HERE rather than at await
-                self._reap_fetch_handles(handles)
-                if isinstance(e, DeviceFailedError):
-                    self._notify_shard_error(reqs[len(handles)][0], e)
-                raise
-        raise AssertionError("unreachable")
-
-    def _await_fetches(self, handles):
-        outs, worst = [], 0.0
-        try:
-            for s, h in handles:
-                payload = self.endpoints[s].fetch_result(h)
-                worst = max(worst, float(payload["io_us"]))
-                outs.append(payload)
-        except BaseException as e:
-            # a shard failed mid-await (drain path): reap every
-            # outstanding completion before re-raising, or their reply
-            # payloads sit in the CQs forever — each failover retry would
-            # leak the healthy shards' full page blocks.  The handle
-            # whose await raised is already consumed.
-            self._reap_fetch_handles(handles[len(outs) + 1:])
-            if isinstance(e, DeviceFailedError):
-                self._notify_shard_error(handles[len(outs)][0], e)
-            raise
-        return outs, worst
-
-    def _reap_fetch_handles(self, handles) -> None:
-        for s, h in handles:
-            try:
-                self.endpoints[s].fetch_result(h)
-            except Exception:  # noqa: BLE001 — best-effort reap
-                pass
-
     def _fan_fetch(self, vids_arr: np.ndarray):
         """plan -> per-shard fetch -> build: the shared front half of the
         batched queries (see module docstring).  Returns a global
         (block, plan) position-identical to a single device's
         ``_fetch_plan`` over the same vids.
+
+        At R = 1 each vid's owner plans and reads its own pages: ONE
+        fetch per owning shard.  At R >= 2 the coordinator plans a
+        replica-spread fetch instead (``_fan_fetch_spread``), re-planned
+        if a shard fails under it.
         """
+        if self.replication > 1:
+            return self._with_failover(
+                lambda: self._fan_fetch_spread(vids_arr))
         with self._read_routing() as rt:
             parts = self._partition(vids_arr, rt)
 
@@ -1074,13 +1107,6 @@ class ShardedGraphStore:
         block = blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
         return block, merge_plans(plans)
 
-    def get_neighbors(self, vid: int) -> np.ndarray:
-        """Neighbor list of one vid from its owning shard."""
-        with self._read_routing() as rt:
-            c = int(vid) % rt.pmap.n_classes
-            ep = self.endpoints[int(rt.pmap.owner[c, 0])]
-            return ep.call("get_neighbors", vid=int(vid))
-
     def get_neighbors_batch(self, vids) -> list[np.ndarray]:
         """Batched neighbor read: one fetch command per shard, results
         recomposed in input order (bit-identical to the single-device
@@ -1100,113 +1126,6 @@ class ShardedGraphStore:
         with spans.span("store.select"):
             return select_from_plan(vids_arr, block, plan, fanout, rng,
                                     segments=segments, rngs=rngs)
-
-    # ----------------------------------------------------------- embeddings
-    def get_embed(self, vid: int) -> np.ndarray:
-        """One embedding row from the vid's owning shard."""
-        with self._read_routing() as rt:
-            s, row = self._emb_locate(vid, rt)[0]
-            return self.endpoints[s].call("get_embed_row", row=row)
-
-    def get_embeds(self, vids: np.ndarray) -> np.ndarray:
-        """Coalesced gather across the array: each shard serves its owned
-        rows (local row from the routing extents; ``vid // N`` at the
-        default map) with ONE batched command, concurrently; rows
-        scatter back to their query positions."""
-        d = self.feature_dim
-        if not d:
-            raise KeyError("no embedding table loaded")
-        vids = np.asarray(vids, dtype=np.int64).reshape(-1)
-        out = np.empty((len(vids), d), dtype=np.float32)
-        if not len(vids):
-            return out
-        with self._read_routing() as rt:
-            cls = vids % rt.pmap.n_classes
-            parts = self._partition(vids, rt)
-            reqs = []
-            for s, pos in parts:
-                c = cls[pos]
-                reqs.append((s, {"emb_rows": rt.ew_base[c, 0]
-                                 + vids[pos] // rt.ew_mod[c, 0]}))
-            payloads, _ = self._endpoint_fetch(reqs)
-        for (s, pos), pl in zip(parts, payloads):
-            out[pos] = pl["emb"]
-        return out
-
-    def update_embed(self, vid: int, embed: np.ndarray) -> None:
-        """Overwrite one embedding row on the vid's owner (all live
-        replicas when replicated)."""
-        with self._write_gate((vid,)):
-            for s, row in self._emb_locate(vid):
-                self.endpoints[s].call("update_embed_row", row=row,
-                                       embed=embed)
-
-    # ------------------------------------------------------------- unit ops
-    def add_vertex(self, vid: int, embed: np.ndarray | None = None) -> None:
-        """Insert an isolated vertex (idempotent), optionally with its
-        embedding row."""
-        with self._write_gate((vid,)):
-            vid = int(vid)
-            ep = self._owner_ep(vid)
-            ep.call("add_vertex", vid=vid)       # adjacency under global vid
-            self._num_vertices = max(self._num_vertices, vid + 1)
-            if embed is not None:
-                for s, row in self._emb_locate(vid):
-                    self.endpoints[s].call("update_embed_row", row=row,
-                                           embed=embed)
-
-    def add_edge(self, dst: int, src: int) -> None:
-        """Undirected insert: each endpoint's chunk updates on ITS owning
-        shard (two independent single-page RMWs, possibly on different
-        devices)."""
-        with self._write_gate((dst, src)):
-            dst, src = int(dst), int(src)
-            for v in (dst, src):
-                # device-side add_vertex no-ops when the vid exists
-                self._owner_ep(v).call("add_vertex", vid=v)
-                self._num_vertices = max(self._num_vertices, v + 1)
-            self._owner_ep(dst).call("insert_neighbor", vid=dst, nbr=src,
-                                     count=True)
-            if dst != src:
-                self._owner_ep(src).call("insert_neighbor", vid=src,
-                                         nbr=dst, count=False)
-
-    def delete_edge(self, dst: int, src: int) -> None:
-        """Undirected removal of edge (dst, src) from both owners."""
-        with self._write_gate((dst, src)):
-            dst, src = int(dst), int(src)
-            self._owner_ep(dst).call("remove_neighbor", vid=dst, nbr=src,
-                                     count=True)
-            if dst != src:
-                self._owner_ep(src).call("remove_neighbor", vid=src,
-                                         nbr=dst, count=False)
-
-    def delete_vertex(self, vid: int) -> None:
-        """Remove ``vid`` everywhere: backlinks on each neighbor's owning
-        shard first, then the owner drops the vertex's own pages.  The
-        neighbor set (and so the touched class set) is unknown up front,
-        so the gate waits out ANY in-flight class migration."""
-        with self._write_gate():
-            vid = int(vid)
-            nbrs = self._owner_ep(vid).call("get_neighbors", vid=vid)
-            for nbr in np.asarray(nbrs).tolist():
-                nbr = int(nbr)
-                if nbr == vid:
-                    continue
-                self._owner_ep(nbr).call("remove_neighbor", vid=nbr,
-                                         nbr=vid, count=False)
-            self._owner_ep(vid).call("drop_vertex_pages", vid=vid,
-                                     count=True)
-
-    # --------------------------------------------------------------- export
-    def to_adjacency(self) -> dict[int, set[int]]:
-        """Full adjacency as ``{vid: neighbor set}`` (test/verification
-        helper — walks every shard)."""
-        out: dict[int, set[int]] = {}
-        for ep in self.endpoints:
-            for v, nb in ep.call("export_adjacency"):
-                out[int(v)] = set(np.asarray(nb).tolist())
-        return out
 
     # ------------------------------------------------------ online reshard
     def placement_stats(self) -> dict:
@@ -1233,13 +1152,15 @@ class ShardedGraphStore:
             "last_reshard": last,
         }
 
-    def _live_sources(self, c: int, dst: int) -> list[int]:
+    def _live_sources(self, c: int, exclude: int) -> list[int]:
         """Live shards holding class ``c`` under the CURRENT routing,
-        excluding ``dst`` — the candidate copy sources, primary first."""
+        primary first, ``exclude`` left out (-1 leaves none out): copy
+        and rebuild sources, and the shard whose mapping tables plan a
+        spread fetch (every replica agrees on them: same op history)."""
         row = self._routing.pmap.owner[c]
         out = []
         for s in (int(x) for x in row):
-            if s != dst and not self._failed[s] and s not in out:
+            if s != exclude and not self._failed[s] and s not in out:
                 out.append(s)
         if not out:
             raise DeviceFailedError(
@@ -1353,7 +1274,7 @@ class ShardedGraphStore:
             refine: class-split factor for ``rebalance`` (finer classes
                 let one hot class spread over several shards).
             chunk_pages: page budget per shard-to-shard chunk pull
-                (defaults to ``rebuild_chunk_pages``, or 512).
+                (defaults to ``rebuild_chunk_pages``).
             pace_s: sleep between chunk pulls so migration traffic
                 trickles under serving reads (supervisor-style pacing).
             on_progress: optional callback receiving ``{"event":
@@ -1413,8 +1334,7 @@ class ShardedGraphStore:
         if any(self._failed):
             raise DeviceFailedError(
                 "reshard needs every shard live at start; rebuild first")
-        chunk_pages = int(chunk_pages
-                          or getattr(self, "rebuild_chunk_pages", 512))
+        chunk_pages = int(chunk_pages or self.rebuild_chunk_pages)
         n_old = self.n_shards
         epoch0 = self._routing.epoch
 
@@ -1583,130 +1503,16 @@ class ShardedGraphStore:
 
     def _topology_changed(self) -> None:
         """Post-attach/detach hook: resize the supervisor's per-shard
-        state and reset the replicated gossip feedback (both no-ops on
-        the base store without them)."""
+        state and reset the gossip feedback."""
         sup = self.health
         if sup is not None and hasattr(sup, "resize"):
             try:
                 sup.resize(self.n_shards)
             except Exception:  # noqa: BLE001 — telemetry best-effort
                 pass
-        if hasattr(self, "_reset_feedback"):
-            self._reset_feedback()
+        self._reset_feedback()
 
-
-class ReplicatedGraphStore(ShardedGraphStore):
-    """R-way replicated CSSD array: the sharded store with redundancy,
-    skewed-read load-spreading, and a failed-shard drain/rebuild path.
-
-    Placement: replica ``r`` of vertex ``vid`` lives on shard
-    ``(vid + r) % N`` — for both its adjacency pages (keyed by global vid,
-    as in the base store) and its embedding row.  Shard ``s`` therefore
-    holds the R residue classes ``{(s - r) % N}``; its embedding table is
-    the concatenation of R round-robin stripes (role ``r`` stripe = class
-    ``(s - r) % N``, local row ``stripe_off[s, r] + vid // N``), so the
-    shard-local page math stays the single-device math per stripe.
-
-    Reads: the plan stage runs a vectorized *replica-selection* pass over
-    every page fetch of the request — H chains at PAGE granularity
-    (replicas keep layout-identical chains, so page i can come from any
-    live owner), L vids weighted by their shared page cost, embedding
-    rows grouped by stripe page — assigned by an exact min-max solver
-    (level binary-search + max-flow over the classes->candidates graph,
-    ``_minmax_quotas``) on top of a GOSSIPED view of the shards' read
-    counters: the coordinator pulls each endpoint's page-read counter at
-    most every ``stats_staleness_s`` seconds (0 = every selection) and
-    plans against that snapshot, so the feedback loop never reads shard
-    state synchronously — the multi-host requirement.  The loop stays
-    closed (estimation bias cannot accumulate, just bounded-staleness
-    delayed), and since every replica holds identical data and the
-    recomposed plan is position-identical to the single-device plan, the
-    spread changes WHICH device pays each page, never the result: an
-    R-replicated sample stays **bit-identical** to the 1-device store
-    under the same seed.  The deferred-latency array cost is ``max`` over
-    shards, so flattening the per-shard page distribution is a direct
-    latency win on skewed mixes (fig24: balance 0.36 -> 1.00,
-    batched-read IO ~1.4x at R=2).
-
-    Writes fan out to every live replica under the coordinator mutation
-    lock (each device's ``on_write`` hook invalidates its own page cache);
-    a replica that fails mid-fan-out is skipped — its state died with the
-    device and ``rebuild_shard`` re-materialises it from a survivor.
-
-    Fault path: ``fail_shard(s)`` drops the device (every later command
-    raises ``DeviceFailedError``) after checking each of its classes keeps
-    a live replica; in-flight fetches that already planned onto the dying
-    shard re-plan against survivors (``_with_failover``).  Degraded reads
-    are served — bit-identically — by the surviving replicas.
-    ``rebuild_shard(s)`` re-materialises the lost partition onto a fresh
-    device by **shard-to-shard chunked streaming**: the coordinator sends
-    the destination endpoint a pure-metadata plan, and the destination
-    pulls bounded page chunks from each class's surviving endpoint over
-    the peer links (batched L export re-laid through the bulk packing, H
-    chains cloned page-exactly — preserving the cross-replica chain
-    layout the page spread relies on — embedding stripes gathered from
-    each class's survivor).  Survivor pages never transit the
-    coordinator; restoring R-way redundancy costs the coordinator one
-    RPC.
-    """
-
-    # base __init__ must not install a 1-role routing the replicated
-    # store immediately replaces — it defers to our own _init_routing
-    _init_routing_deferred = True
-
-    def __init__(self, n_shards: int | None = None, devs: list | None = None,
-                 *, endpoints: list | None = None, replication: int = 2,
-                 h_threshold: int = 128, feature_dim: int = 0,
-                 placement: PlacementMap | None = None,
-                 stats_staleness_s: float = 0.0,
-                 rebuild_chunk_pages: int = 512,
-                 flow: FlowControl | None = None):
-        """Same backing forms as :class:`ShardedGraphStore`, plus:
-
-        Args:
-            replication: replica count R (1 <= R <= N); every vertex
-                class keeps R copies, one per role column.
-            placement: optional R-role :class:`PlacementMap` replacing
-                the default ``(c + r) % N`` replica ring.
-            stats_staleness_s: max age of the gossiped read-counter
-                snapshot replica selection plans against (0 = refresh
-                every selection).
-            rebuild_chunk_pages: page budget per shard-to-shard chunk
-                pull during rebuild and reshard streams.
-
-        Raises:
-            ValueError: replication out of range, or a placement map
-                whose role count differs from ``replication``.
-        """
-        super().__init__(n_shards, devs, endpoints=endpoints,
-                         h_threshold=h_threshold, feature_dim=feature_dim,
-                         flow=flow)
-        r = int(replication)
-        if not 1 <= r <= self.n_shards:
-            raise ValueError(f"replication={r} needs 1 <= R <= "
-                             f"n_shards={self.n_shards}")
-        self.replication = r
-        self._emb_rows = 0
-        self._init_routing(r, placement)
-        # gossiped selection feedback: every selection starts from a
-        # staleness-bounded snapshot of the shards' ACTUAL page-read
-        # counters since the last topology change (periodic ``counters``
-        # pulls — never a synchronous shard peek).  Cache hits never
-        # reach the device counter, so cached reads correctly stop
-        # counting as device load.
-        self.stats_staleness_s = float(stats_staleness_s)
-        self.rebuild_chunk_pages = int(rebuild_chunk_pages)
-        self.gossip_pulls = 0                      # guarded-by: _gossip_lock
-        self._gossip_lock = witness_lock(
-            "sharded._gossip_lock", threading.Lock())
-        self._gossip_reads = np.zeros(self.n_shards)   # guarded-by: _gossip_lock
-        self._gossip_depth = np.zeros(self.n_shards)   # guarded-by: _gossip_lock
-        self._gossip_t = -np.inf                   # guarded-by: _gossip_lock
-        self._gossip_inflight = False              # guarded-by: _gossip_lock
-        self._read_base = np.zeros(0)              # guarded-by: _gossip_lock
-        self._read_base = self._refresh_gossip(force=True).copy()
-
-    # ------------------------------------------------------------- topology
+    # ------------------------------------------------------------- replicas
     def replica_shards(self, vid: int) -> list[int]:
         """The shards holding ``vid``'s replicas, role order (primary
         first) — ``[(vid + r) % N]`` at the default modular map."""
@@ -1728,29 +1534,6 @@ class ReplicatedGraphStore(ShardedGraphStore):
             raise DeviceFailedError(f"no live replica for vertex {vid}")
         return out
 
-    def _survivor_of_class(self, c: int, exclude: int) -> int:
-        rt = self._routing
-        for r in range(self.replication):
-            s = int(rt.pmap.owner[c, r])
-            if s != exclude and not self._failed[s]:
-                return s
-        raise DeviceFailedError(f"no live replica holds vertex class {c}")
-
-    def _meta_shard(self, c: int, rt: _Routing | None = None) -> int:
-        """A live replica holding class ``c``'s mapping tables — the
-        planning metadata every replica agrees on (same op history)."""
-        rt = rt or self._routing
-        for r in range(self.replication):
-            s = int(rt.pmap.owner[c, r])
-            if not self._failed[s]:
-                return s
-        raise DeviceFailedError(f"no live replica for vertex class {c}")
-
-    # ----------------------------------------------------- embedding layout
-    def _rows_of_class(self, c: int) -> int:
-        return rows_of_class(self._emb_rows, int(c),
-                             self._routing.pmap.n_classes)
-
     def _check_emb_vid(self, vid: int) -> None:
         """Reject rows beyond the ingested table: in the striped replica
         layout the next local row belongs to ANOTHER role's stripe, so an
@@ -1759,26 +1542,6 @@ class ReplicatedGraphStore(ShardedGraphStore):
         if not 0 <= int(vid) < self._emb_rows:
             raise KeyError(f"vid {vid} outside the embedding table "
                            f"({self._emb_rows} rows)")
-
-    def update_graph(self, edge_array, embeddings=None, *,
-                     already_undirected: bool = False):
-        """Bulk UpdateGraph across the replicated array (see the base
-        class); every shard writes all R of its owned stripes.
-
-        Raises:
-            DeviceFailedError: a shard is failed (rebuild first).
-            RuntimeError: an online reshard is migrating classes.
-        """
-        # behind the maintenance gate: a bulk ingest must not interleave
-        # with a streaming rebuild (and a rebuild in progress means a
-        # failed flag is still set, which the check below rejects)
-        with self._maintenance:
-            self._check_not_resharding("bulk ingest")
-            if any(self._failed):
-                raise DeviceFailedError(
-                    "bulk ingest needs every shard live; rebuild_shard first")
-            return super().update_graph(edge_array, embeddings,
-                                        already_undirected=already_undirected)
 
     # ----------------------------------------------------- replica selection
     def _refresh_gossip(self, force: bool = False) -> np.ndarray:
@@ -1848,6 +1611,10 @@ class ReplicatedGraphStore(ShardedGraphStore):
         return h
 
     def _reset_feedback(self) -> None:
+        """Re-base the selection's load history at a fresh gossip pull
+        (an array with R = 1 never selects, so it pulls nothing)."""
+        if self.replication < 2:
+            return
         reads = self._refresh_gossip(force=True)
         with self._gossip_lock:
             self._read_base = reads.copy()
@@ -1939,22 +1706,16 @@ class ReplicatedGraphStore(ShardedGraphStore):
         raises ``DeviceFailedError`` from that device (surfaced through
         the endpoint, whatever the transport); the retry re-runs the
         selection, which now excludes it — the drain path of a degraded
-        array.  Reads are idempotent, so the retry is safe.
+        array.  Reads are idempotent, so the retry is safe.  At R = 1 no
+        other replica can take over, so ``fn`` runs once.
         """
         last = None
-        for _ in range(self.n_shards + 1):
+        for _ in range(self.n_shards + 1 if self.replication > 1 else 1):
             try:
                 return fn()
             except DeviceFailedError as e:
                 last = e
         raise last
-
-    def _fan_fetch(self, vids_arr: np.ndarray):
-        if self.replication == 1:
-            return self._with_failover(
-                lambda: ShardedGraphStore._fan_fetch(self, vids_arr))
-        return self._with_failover(
-            lambda: self._fan_fetch_spread(vids_arr))
 
     def _fan_fetch_spread(self, vids_arr: np.ndarray):
         """plan -> page-granular replica-spread fetch -> build.
@@ -1973,8 +1734,8 @@ class ReplicatedGraphStore(ShardedGraphStore):
         """
         # plan + fetch under the coordinator mutation lock: one vid's chain
         # pages are read under SEVERAL shards' locks, so a delete landing
-        # between them could drop h_chain entries mid-plan (the base store
-        # reads each vid inside ONE shard critical section and never had
+        # between them could drop chain entries mid-plan (the owner fetch
+        # reads each vid inside ONE shard critical section and never has
         # this gap).  The simulated array wait is paid after release, so
         # mutations only ever wait out the (fast) planning math.
         with self._mutate:
@@ -1996,7 +1757,7 @@ class ReplicatedGraphStore(ShardedGraphStore):
         for c in np.unique(cls_arr).tolist():
             idx = np.nonzero(cls_arr == c)[0]
             idxs.append(idx)
-            items.append((self._meta_shard(int(c)), "plan_info",
+            items.append((self._live_sources(int(c), -1)[0], "plan_info",
                           {"vids": vids_arr[idx]}))
         # one concurrent round-trip (queue-full safe: a QueueFullError
         # part-way reaps the submitted handles and retries the full set)
@@ -2099,11 +1860,12 @@ class ReplicatedGraphStore(ShardedGraphStore):
         return block, merge_plans(plans), worst
 
     # ------------------------------------------------------------ unit reads
-    def _unit_call(self, s: int, ep, method: str, **kw):
-        """Unit read against one replica, with the shard-attributed error
+    @contextmanager
+    def _shard_errors(self, s: int):
+        """Unit read against shard ``s``: its ``DeviceFailedError`` is
         reported to the supervisor before failover re-plans it."""
         try:
-            return ep.call(method, **kw)
+            yield
         except DeviceFailedError as e:
             self._notify_shard_error(s, e)
             raise
@@ -2113,7 +1875,8 @@ class ReplicatedGraphStore(ShardedGraphStore):
         def read():
             with self._read_routing() as rt:
                 s, _r, ep = self._live_eps(vid, rt)[0]
-                return self._unit_call(s, ep, "get_neighbors", vid=int(vid))
+                with self._shard_errors(s):
+                    return ep.call("get_neighbors", vid=int(vid))
         return self._with_failover(read)
 
     def get_embed(self, vid: int) -> np.ndarray:
@@ -2127,13 +1890,18 @@ class ReplicatedGraphStore(ShardedGraphStore):
         def read():
             with self._read_routing() as rt:
                 s, row = self._emb_locate(vid, rt)[0]
-                return self._unit_call(s, self.endpoints[s],
-                                       "get_embed_row", row=row)
+                with self._shard_errors(s):
+                    return self.endpoints[s].call("get_embed_row", row=row)
         return self._with_failover(read)
 
     def get_embeds(self, vids: np.ndarray) -> np.ndarray:
-        """Replica-spread coalesced embedding gather (see class
-        docstring); bit-identical rows, load-balanced page fetches.
+        """Coalesced gather across the array: each serving shard reads
+        its rows (local row from the routing extents; ``vid // N`` at the
+        default map) with ONE batched command, concurrently; rows scatter
+        back to their query positions.  At R = 1 the owner serves every
+        row; at R >= 2 a replica-spread selection picks the serving
+        replica per stripe page (see class docstring) — bit-identical
+        rows, load-balanced page fetches.
 
         Raises:
             KeyError: no table loaded, or a vid outside it.
@@ -2145,37 +1913,36 @@ class ReplicatedGraphStore(ShardedGraphStore):
         out = np.empty((len(vids), d), dtype=np.float32)
         if not len(vids):
             return out
-        if int(vids.min()) < 0 or int(vids.max()) >= self._emb_rows:
-            self._check_emb_vid(int(vids.max()
-                                    if vids.max() >= self._emb_rows
-                                    else vids.min()))
+        lo, hi = int(vids.min()), int(vids.max())
+        if lo < 0 or hi >= self._emb_rows:
+            self._check_emb_vid(lo if lo < 0 else hi)
 
         def gather():
             with self._read_routing() as rt:
                 C = rt.pmap.n_classes
                 cls = vids % C
-                local = vids // C
-                # group by stripe page so rows sharing a 4 KB page are
-                # fetched together from ONE replica (no duplicate page
-                # fetches); weigh rows in PAGES — page-mates split their
-                # page's single fetch — so embedding quotas stay
-                # commensurate with adjacency quotas
-                page_key = (local * d) // SLOTS_PER_PAGE
-                if d >= SLOTS_PER_PAGE:
-                    w = np.full(len(vids), d / SLOTS_PER_PAGE)
-                else:
-                    # page-mates are same-CLASS rows on one stripe page;
-                    # rows of different classes sharing a raw page index
-                    # live on different shards' stripes and must not pool
-                    # their weight
-                    ck = cls * (int(page_key.max()) + 1) + page_key
-                    _, inv, cnt = np.unique(ck, return_inverse=True,
-                                            return_counts=True)
-                    w = 1.0 / cnt[inv]
-                owner = self._select_replicas(vids, weights=w, key=page_key)
-                parts = [(s, np.nonzero(owner == s)[0])
-                         for s in range(self.n_shards)]
-                parts = [(s, pos) for s, pos in parts if len(pos)]
+                owner = None
+                if self.replication > 1:
+                    # group by stripe page so rows sharing a 4 KB page are
+                    # fetched together from ONE replica (no duplicate page
+                    # fetches); weigh rows in PAGES — page-mates split
+                    # their page's single fetch — so embedding quotas stay
+                    # commensurate with adjacency quotas
+                    page_key = ((vids // C) * d) // SLOTS_PER_PAGE
+                    if d >= SLOTS_PER_PAGE:
+                        w = np.full(len(vids), d / SLOTS_PER_PAGE)
+                    else:
+                        # page-mates are same-CLASS rows on one stripe
+                        # page; rows of different classes sharing a raw
+                        # page index live on different shards' stripes
+                        # and must not pool their weight
+                        ck = cls * (int(page_key.max()) + 1) + page_key
+                        _, inv, cnt = np.unique(ck, return_inverse=True,
+                                                return_counts=True)
+                        w = 1.0 / cnt[inv]
+                    owner = self._select_replicas(vids, weights=w,
+                                                  key=page_key)
+                parts = self._partition(vids, rt, owner)
                 reqs = []
                 for s, pos in parts:
                     # which role serves each position on shard s
@@ -2387,7 +2154,7 @@ class ReplicatedGraphStore(ShardedGraphStore):
                 pairs = rt.pmap.pairs_of(s)
                 classes = []
                 for c, _r in pairs:
-                    src = self._survivor_of_class(c, exclude=s)
+                    src = self._live_sources(c, s)[0]
                     entry = {"cls": int(c), "src": int(src)}
                     if self._emb_rows and self._feature_dim:
                         r2 = [int(rr) for rr in range(self.replication)

@@ -13,7 +13,7 @@ from repro.data.pipeline import Pipeline
 from repro.serve.scheduler import QoSTelemetry
 from repro.serve.supervisor import ShardSupervisor
 from repro.store.blockdev import BlockDevice
-from repro.store.sharded import ReplicatedGraphStore
+from repro.store.sharded import ShardedGraphStore
 from repro.train.checkpoint import Checkpointer
 
 
@@ -116,7 +116,7 @@ def _rep_store():
     n = 64
     edges = rng.integers(0, n, size=(256, 2), dtype=np.int64)
     emb = rng.standard_normal((n, 8)).astype(np.float32)
-    st = ReplicatedGraphStore(n_shards=2, replication=2, h_threshold=16)
+    st = ShardedGraphStore(n_shards=2, replication=2, h_threshold=16)
     st.update_graph(edges, emb)
     return st
 

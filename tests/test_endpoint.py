@@ -9,8 +9,8 @@ import pytest
 from repro.core import gnn
 from repro.core.service import HolisticGNNService, make_service_dfg
 from repro.store import (BlockDevice, DeviceFailedError, GraphStore,
-                         ReplicatedGraphStore, ShardedGraphStore,
-                         make_rop_endpoints, sample_batch)
+                         ShardedGraphStore, make_rop_endpoints,
+                         sample_batch)
 from repro.store.graphstore import PLAN_FIELDS, select_from_plan
 
 
@@ -32,12 +32,8 @@ def _single(h_threshold=16, **kw):
 def _remote(n_shards, replication=1, *, h_threshold=16, edges=None,
             emb=None, **store_kw):
     eps = make_rop_endpoints(n_shards, h_threshold=h_threshold)
-    if replication > 1:
-        store = ReplicatedGraphStore(endpoints=eps, replication=replication,
-                                     h_threshold=h_threshold, **store_kw)
-    else:
-        store = ShardedGraphStore(endpoints=eps, h_threshold=h_threshold,
-                                  **store_kw)
+    store = ShardedGraphStore(endpoints=eps, replication=replication,
+                              h_threshold=h_threshold, **store_kw)
     if edges is not None:
         store.update_graph(edges, emb)
     return store
@@ -248,16 +244,16 @@ def test_failed_fetch_reaps_outstanding_handles():
 def test_gossip_staleness_bounds_counter_pulls():
     _, edges, emb = _single()
     # staleness 0: every selection refreshes the counter snapshot
-    eager = ReplicatedGraphStore(n_shards=2, replication=2, h_threshold=16,
-                                 stats_staleness_s=0.0)
+    eager = ShardedGraphStore(n_shards=2, replication=2, h_threshold=16,
+                              stats_staleness_s=0.0)
     eager.update_graph(edges, emb)
     p0 = eager.gossip_pulls
     for _ in range(5):
         eager.get_embeds(np.arange(40))
     assert eager.gossip_pulls - p0 >= 5
     # large staleness bound: the cached snapshot serves every selection
-    lazy = ReplicatedGraphStore(n_shards=2, replication=2, h_threshold=16,
-                                stats_staleness_s=60.0)
+    lazy = ShardedGraphStore(n_shards=2, replication=2, h_threshold=16,
+                             stats_staleness_s=60.0)
     lazy.update_graph(edges, emb)
     p0 = lazy.gossip_pulls
     for _ in range(5):

@@ -6,7 +6,8 @@ import pytest
 import jax.numpy as jnp
 
 from repro.store.blockdev import BlockDevice
-from repro.store.graphstore import H_CAP, GraphStore
+from repro.store.graphstore import (H_CAP, _H_NEXT, _L_NNODES, GraphStore,
+                                    _l_meta_vid)
 from repro.store.sampler import _subsample, sample_batch, sample_batch_ref
 
 
@@ -33,7 +34,7 @@ def test_get_neighbors_batch_matches_pointwise(seed, h_threshold):
     vids = list(range(n)) + [n + 3, n + 17]    # incl. isolated + unknown vids
     batch = gs.get_neighbors_batch(vids)
     assert len(batch) == len(vids)
-    kinds = set(gs.gmap.values())
+    kinds = set(gs.class_kinds()[1].tolist())
     assert kinds == {"H", "L"}                 # both mapping types exercised
     for v, got in zip(vids, batch):
         np.testing.assert_array_equal(got, gs.get_neighbors(v), err_msg=str(v))
@@ -46,7 +47,7 @@ def test_get_neighbors_batch_after_mutations():
     for u in range(4, 10):
         gs.add_edge(0, u)                      # promotes 0 to H-type
     gs.delete_edge(1, 2)
-    assert gs.gmap[0] == "H"
+    assert gs.kind_of(0) == "H"
     vids = list(range(12))
     for v, got in zip(vids, gs.get_neighbors_batch(vids)):
         np.testing.assert_array_equal(got, gs.get_neighbors(v), err_msg=str(v))
@@ -60,7 +61,7 @@ def test_get_neighbors_batch_multipage_h_chain():
                       np.arange(1, n_nbrs + 1)], axis=1)
     gs = GraphStore(BlockDevice(), h_threshold=16)
     gs.update_graph(edges)
-    assert gs.gmap[0] == "H" and len(gs.h_chain[0]) >= 3
+    assert gs.kind_of(0) == "H" and len(gs.chain_of(0)) >= 3
     for u in range(n_nbrs + 1, n_nbrs + 40):       # grow the tail page
         gs.add_edge(0, u)
     got = gs.get_neighbors_batch([0, 1, 2])
@@ -81,15 +82,15 @@ def _hub_store(mutate=False):
     gs.update_graph(np.concatenate(
         [_store_edges(np.random.default_rng(5), 400, 3000), hubs]))
     n = int(star[-1]) + 1
-    assert len(gs.h_chain[3]) >= 3 and len(gs.h_chain[9]) >= 2
+    assert len(gs.chain_of(3)) >= 3 and len(gs.chain_of(9)) >= 2
     if mutate:
-        low = [v for v in range(40, 400) if gs.gmap.get(v) == "L"]
+        low = [v for v in range(40, 400) if gs.kind_of(v) == "L"]
         for v in low[:12]:
             gs.add_edge(v, low[-1])          # relocates or appends chunks
         p = low[20]
         for u in range(100, 100 + 12):
             gs.add_edge(p, u)                # promotes p to H
-        assert gs.gmap[p] == "H"
+        assert gs.kind_of(p) == "H"
         for u in range(n, n + H_CAP):        # grows hub 3 by a page
             gs.add_edge(3, u)
         gs.delete_vertex(low[30])            # tombstone in an L page
@@ -99,16 +100,34 @@ def _hub_store(mutate=False):
     return gs, n
 
 
-def _assert_tables_mirror(gs):
-    """The plan's array tables hold exactly what gmap/h_chain/_l_keys do."""
-    for v, kind in gs.gmap.items():
-        assert gs._kinds(np.array([v]))[0] == {"L": 1, "H": 2}[kind], v
-    assert int((gs._sel > 0).sum()) == len(gs.gmap)
-    for v, chain in gs.h_chain.items():
-        at, k = int(gs._chain_at[v]), int(gs._chain_n[v])
-        assert gs._pool[at: at + k].tolist() == chain, v
-    assert int(gs._chain_n.sum()) == sum(map(len, gs.h_chain.values()))
+def _assert_tables_match_device(gs):
+    """The mapping tables agree with the pages on the device: each hub's
+    pool run is the chain reached by following ``_H_NEXT`` from its head
+    (and holds the hub's own self-loop, so it is the hub's chain), and
+    ``_sel`` classifies as L exactly the live vids of the L pages (each
+    found by the range search) and as H exactly the vids with a chain."""
+    hubs = np.flatnonzero(gs._chain_n)
+    for v in hubs.tolist():
+        walk, lpn, held = [], int(gs._pool[gs._chain_at[v]]), set()
+        while lpn >= 0:
+            walk.append(lpn)
+            page = gs.dev.read_page(lpn)
+            held.update(page[2: 2 + int(page[0])].tolist())
+            lpn = int(page[_H_NEXT])
+        assert gs.chain_of(v).tolist() == walk and v in held, v
+    assert int(gs._chain_n.sum()) == gs._pool_live
     assert np.all(np.diff(gs._l_keys) > 0)
+    l_vids = set()
+    for k, lpn in enumerate(gs._l_lpns.tolist()):
+        page = gs.dev.read_page(lpn)
+        on_page = [int(page[_l_meta_vid(i)])
+                   for i in range(int(page[_L_NNODES]))]
+        on_page = [v for v in on_page if v >= 0]          # no tombstones
+        assert all(int(np.searchsorted(gs._l_keys, v)) == k
+                   for v in on_page), lpn
+        l_vids.update(on_page)
+    assert set(np.flatnonzero(gs._sel == 1).tolist()) == l_vids
+    assert np.array_equal(np.flatnonzero(gs._sel == 2), hubs)
 
 
 def _select_ref(gs, vids, fanout, rng):
@@ -130,7 +149,7 @@ def test_array_plan_matches_reference(case):
     unknown and duplicate vids, and a multi-request segments/rngs
     frontier."""
     gs, n = _hub_store(mutate=case != "bulk_hub_chains")
-    _assert_tables_mirror(gs)
+    _assert_tables_match_device(gs)
     rng = np.random.default_rng(4)
     vids = np.concatenate([[3, 9], rng.integers(0, 400, 60)])
     if case == "unknown_and_dups":
@@ -175,11 +194,11 @@ def test_sample_reads_distinct_l_pages_then_every_chain_page(mutate):
                            np.random.default_rng(2).integers(0, 400, 80)])
     l_pages, h_pages = set(), set()
     for v in vids.tolist():
-        if gs.gmap.get(v) == "L":
+        if gs.kind_of(v) == "L":
             k = int(np.searchsorted(gs._l_keys, v))
             l_pages.add(int(gs._l_lpns[k]))
-        elif gs.gmap.get(v) == "H":
-            h_pages.update(gs.h_chain[v])
+        elif gs.kind_of(v) == "H":
+            h_pages.update(gs.chain_of(v).tolist())
     want = sorted(l_pages) + sorted(h_pages)
     seen = []
     read = gs.dev.read_pages

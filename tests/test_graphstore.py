@@ -7,7 +7,7 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.store.blockdev import BlockDevice, SLOTS_PER_PAGE
-from repro.store.graphstore import GraphStore, preprocess_edges
+from repro.store.graphstore import _H_NEXT, GraphStore, preprocess_edges
 
 
 def _mk_graph(n=200, e=1200, seed=0):
@@ -48,7 +48,7 @@ def test_bulk_load_matches_oracle():
     for v in range(n):
         assert set(int(x) for x in gs.get_neighbors(v)) == adj[v], v
     # power-law: some vertices must be H-type, most L-type
-    kinds = set(gs.gmap.values())
+    kinds = set(gs.class_kinds()[1].tolist())
     assert kinds == {"H", "L"}
 
 
@@ -88,7 +88,7 @@ def test_unit_ops_and_promotion():
     # adding many edges promotes 0 from L to H
     for u in range(4, 10):
         gs.add_edge(0, u)
-    assert gs.gmap[0] == "H"
+    assert gs.kind_of(0) == "H"
     assert set(gs.get_neighbors(0)) == {0, 1} | set(range(4, 10))
     # delete edge both directions
     gs.delete_edge(0, 4)
@@ -141,21 +141,29 @@ def test_chain_pool_compacts_and_keeps_every_chain():
     every chain (and batched read) still matches the mapping tables."""
     gs = GraphStore(BlockDevice(), h_threshold=8)
     gs.update_graph(_mk_graph(seed=3))
-    hubs = [v for v, k in gs.gmap.items() if k == "H"]
-    pages = {v: np.stack([gs.dev.read_page(lpn).copy()
-                          for lpn in gs.h_chain[v]]) for v in hubs}
+    vids, kinds = gs.class_kinds()
+    hubs = vids[kinds == "H"].tolist()
+    pages = {v: gs.export_chain(v) for v in hubs}
+    vids = list(range(210))
+    before = [gs.get_neighbors(v) for v in vids]
     writes = 0
     while writes < 2 * len(gs._pool):
         for v in hubs[:3]:
             gs.import_h_chain(v, pages[v])
             writes += len(pages[v])
     assert gs._pool_top < writes
-    for v, chain in gs.h_chain.items():
-        at, n = int(gs._chain_at[v]), int(gs._chain_n[v])
-        assert gs._pool[at: at + n].tolist() == chain
-    vids = list(range(210))
-    for v, got in zip(vids, gs.get_neighbors_batch(vids)):
+    for v in hubs:
+        chain, walk, lpn = gs.chain_of(v).tolist(), [], gs.chain_of(v)[0]
+        while lpn >= 0:
+            walk.append(int(lpn))
+            lpn = gs.dev.read_page(lpn)[_H_NEXT]
+        assert chain == walk, v
+        np.testing.assert_array_equal(
+            np.delete(gs.export_chain(v), _H_NEXT, axis=1),
+            np.delete(pages[v], _H_NEXT, axis=1))
+    for v, got, want in zip(vids, gs.get_neighbors_batch(vids), before):
         np.testing.assert_array_equal(got, gs.get_neighbors(v))
+        np.testing.assert_array_equal(got, want)
 
 
 def test_write_amplification_unit_ops():

@@ -13,8 +13,7 @@ import pytest
 from repro.core.service import HolisticGNNService
 from repro.rpc.queues import BackpressureError
 from repro.store import (BlockDevice, GraphStore, MutationFirehose,
-                         ReplicatedGraphStore, ShardedGraphStore,
-                         make_rop_endpoints)
+                         ShardedGraphStore, make_rop_endpoints)
 from repro.store.blockdev import DeviceFailedError
 
 
@@ -28,10 +27,8 @@ def _graph(n=400, e=3000, feat=24, seed=0):
 
 def _mk(n_shards, replication, **kw):
     kw.setdefault("h_threshold", 16)
-    if replication == 1:
-        return ShardedGraphStore(n_shards=n_shards, **kw)
-    return ReplicatedGraphStore(n_shards=n_shards,
-                                replication=replication, **kw)
+    return ShardedGraphStore(n_shards=n_shards, replication=replication,
+                             **kw)
 
 
 def _shard_devs(store):

@@ -6,8 +6,8 @@ array INTO that same map."""
 import numpy as np
 import pytest
 
-from repro.store import (BlockDevice, GraphStore, ReplicatedGraphStore,
-                         ShardedGraphStore, sample_batch)
+from repro.store import (BlockDevice, GraphStore, ShardedGraphStore,
+                         sample_batch)
 from repro.store.placement import (PlacementMap, common_refine, grow_plan,
                                    heat_plan, modular, plan_moves,
                                    rows_of_class, shrink_plan)
@@ -153,13 +153,8 @@ def test_any_total_map_reads_bit_identical(n_shards, replication,
     ref = GraphStore(BlockDevice(), h_threshold=16)
     ref.update_graph(edges, emb)
     pmap = _random_map(n_shards, n_classes, replication, seed)
-    if replication == 1:
-        store = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
-                                  placement=pmap)
-    else:
-        store = ReplicatedGraphStore(n_shards=n_shards, h_threshold=16,
-                                     replication=replication,
-                                     placement=pmap)
+    store = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
+                              replication=replication, placement=pmap)
     store.update_graph(edges, emb)
     _assert_reads_equal(ref, store, n)
     ps = store.placement_stats()
@@ -177,16 +172,10 @@ def test_ingest_under_map_agrees_with_reshard_into_map(n_shards,
     n = emb.shape[0]
     pmap = _random_map(n_shards, 2 * n_shards, replication, seed=21)
 
-    if replication == 1:
-        direct = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
-                                   placement=pmap)
-        moved = ShardedGraphStore(n_shards=n_shards, h_threshold=16)
-    else:
-        direct = ReplicatedGraphStore(n_shards=n_shards, h_threshold=16,
-                                      replication=replication,
-                                      placement=pmap)
-        moved = ReplicatedGraphStore(n_shards=n_shards, h_threshold=16,
-                                     replication=replication)
+    direct = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
+                               replication=replication, placement=pmap)
+    moved = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
+                              replication=replication)
     direct.update_graph(edges, emb)
     moved.update_graph(edges, emb)
     report = moved.reshard(placement=pmap, chunk_pages=16)

@@ -1,5 +1,5 @@
-"""ReplicatedGraphStore: R-way replica parity with the single-device
-store, replica-spread selection balance, degraded-mode bit-identity under
+"""ShardedGraphStore with R replicas: R-way replica parity with the
+single-device store, the per-R read plan, replica-spread selection balance, degraded-mode bit-identity under
 every single-shard failure, write-fan-out coherence, and the
 fail/rebuild/restore cycle — through the raw store and the service RPCs."""
 import numpy as np
@@ -8,7 +8,7 @@ import pytest
 from repro.core import gnn
 from repro.core.service import HolisticGNNService, make_service_dfg
 from repro.store import (BlockDevice, DeviceFailedError, GraphStore,
-                         ReplicatedGraphStore, sample_batch)
+                         ShardedGraphStore, sample_batch)
 
 
 def _graph(n=420, e=3200, feat=24, seed=0):
@@ -23,8 +23,8 @@ def _pair(n_shards, replication, *, h_threshold=16, n=420, e=3200, feat=24):
     edges, emb = _graph(n, e, feat)
     single = GraphStore(BlockDevice(), h_threshold=h_threshold)
     single.update_graph(edges, emb)
-    rep = ReplicatedGraphStore(n_shards=n_shards, replication=replication,
-                               h_threshold=h_threshold)
+    rep = ShardedGraphStore(n_shards=n_shards, replication=replication,
+                            h_threshold=h_threshold)
     rep.update_graph(edges, emb)
     return single, rep, n
 
@@ -62,9 +62,40 @@ def test_replicated_bit_identical_healthy(n_shards, replication):
 
 def test_bad_replication_factor_rejected():
     with pytest.raises(ValueError):
-        ReplicatedGraphStore(n_shards=2, replication=3)
+        ShardedGraphStore(n_shards=2, replication=3)
     with pytest.raises(ValueError):
-        ReplicatedGraphStore(n_shards=2, replication=0)
+        ShardedGraphStore(n_shards=2, replication=0)
+
+
+@pytest.mark.parametrize("replication", [1, 2])
+def test_read_commands_follow_replication(replication):
+    """One coordinator, two read plans chosen by R alone.  At R = 1 each
+    owner plans its own vids: one ``fetch`` per owning shard per read,
+    and neither ``plan_info`` nor a gossip ``counters`` pull — not even
+    at construction.  At R >= 2 the coordinator plans from one
+    ``plan_info`` per occupied class and pulls the gossip counters for
+    each selection (staleness 0)."""
+    single, rep, n = _pair(3, replication)
+
+    def calls(method):
+        return [ep.method_stats[method].calls
+                if method in ep.method_stats else 0
+                for ep in rep.endpoints]
+
+    methods = ("fetch", "plan_info", "counters")
+    before = {m: calls(m) for m in methods}
+    vids = np.arange(0, n, 5)                    # every class occupied
+    rep.get_neighbors_batch(vids)
+    rep.get_embeds(vids)
+    got = {m: [b - a for a, b in zip(before[m], calls(m))] for m in methods}
+    if replication == 1:
+        assert got == {"fetch": [2, 2, 2], "plan_info": [0, 0, 0],
+                       "counters": [0, 0, 0]}, got
+        assert calls("counters") == [0, 0, 0]
+    else:
+        assert got["plan_info"] == [1, 1, 1], got
+        assert got["counters"] == [2, 2, 2], got
+    _assert_reads_match(single, rep, n)
 
 
 # ---------------------------------------------------------- degraded reads

@@ -8,8 +8,8 @@ import numpy as np
 import pytest
 
 from repro.store import (BlockDevice, DeviceFailedError, GraphStore,
-                         LocalShardEndpoint, ReplicatedGraphStore,
-                         ShardedGraphStore, sample_batch)
+                         LocalShardEndpoint, ShardedGraphStore,
+                         sample_batch)
 from repro.store.placement import modular
 
 
@@ -21,15 +21,12 @@ def _graph(n=360, e=2600, feat=16, seed=0):
     return edges, emb
 
 
-def _pair(n_shards, *, replication=None, n=360, e=2600, feat=16):
+def _pair(n_shards, *, replication=1, n=360, e=2600, feat=16):
     edges, emb = _graph(n, e, feat)
     ref = GraphStore(BlockDevice(), h_threshold=16)
     ref.update_graph(edges, emb)
-    if replication is None:
-        store = ShardedGraphStore(n_shards=n_shards, h_threshold=16)
-    else:
-        store = ReplicatedGraphStore(n_shards=n_shards, h_threshold=16,
-                                     replication=replication)
+    store = ShardedGraphStore(n_shards=n_shards, h_threshold=16,
+                              replication=replication)
     store.update_graph(edges, emb)
     return ref, store, n
 

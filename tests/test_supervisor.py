@@ -17,9 +17,8 @@ from repro.rpc.queues import BackpressureError, QueueFullError
 from repro.serve import (AdmissionError, BatchScheduler, HealthPolicy,
                          ServingRuntime, ShardSupervisor)
 from repro.store import (BlockDevice, DeviceFailedError, GraphStore,
-                         ReplicatedGraphStore, ShardedGraphStore,
-                         make_local_endpoints, make_rop_endpoints,
-                         sample_batch)
+                         ShardedGraphStore, make_local_endpoints,
+                         make_rop_endpoints, sample_batch)
 from repro.store.sharded import FlowControl
 
 
@@ -38,9 +37,9 @@ def _pair(n_shards=3, replication=2, *, remote=False, flow=None,
     single.update_graph(edges, emb)
     eps = (make_rop_endpoints(n_shards, h_threshold=h_threshold) if remote
            else None)
-    rep = ReplicatedGraphStore(n_shards=None if eps else n_shards,
-                               endpoints=eps, replication=replication,
-                               h_threshold=h_threshold, flow=flow)
+    rep = ShardedGraphStore(n_shards=None if eps else n_shards,
+                            endpoints=eps, replication=replication,
+                            h_threshold=h_threshold, flow=flow)
     rep.update_graph(edges, emb)
     return single, rep, n
 

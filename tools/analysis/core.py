@@ -85,10 +85,8 @@ class AnalysisConfig:
     # calls into the package class index
     attr_types: dict = field(default_factory=lambda: {
         ("endpoint", "store"): ("GraphStore",),
-        ("ingest", "store"): ("ReplicatedGraphStore", "ShardedGraphStore",
-                              "GraphStore"),
-        ("supervisor", "store"): ("ReplicatedGraphStore",
-                                  "ShardedGraphStore"),
+        ("ingest", "store"): ("ShardedGraphStore", "GraphStore"),
+        ("supervisor", "store"): ("ShardedGraphStore",),
         ("runtime", "scheduler"): ("BatchScheduler",),
         ("scheduler", "qos"): ("QoSTelemetry",),
     })
