@@ -38,6 +38,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import spans
 from ..concurrency import witness_lock
 
 PAGE_BYTES = 4096
@@ -352,17 +353,22 @@ class BlockDevice:
             time.perf_counter() - self._t0, "read",
             int(lpns[0]) if len(lpns) else 0, len(lpns) * PAGE_BYTES, tag))
 
-    def read_rows(self, lpns, offsets, d: int, *,
+    def read_rows(self, lpns, offsets, d: int, *, out=None, at=None,
                   tag: str = "embed") -> np.ndarray:
-        """Batched row read -> (len(offsets), d): one queued command for
-        the page set ``lpns``, charged exactly as ``read_pages(lpns)``,
-        that returns only the ``d``-slot rows starting at the flat slots
-        ``offsets`` of the page array.
+        """Batched row read: one queued command for the page set ``lpns``,
+        charged exactly as ``read_pages(lpns)``, that copies only the
+        ``d``-slot rows starting at the flat slots ``offsets`` of the page
+        array.  Returns the rows, ``(len(offsets), d)``; given ``out``,
+        row ``i`` is written to ``out[at[i]]`` (``out[i]`` without ``at``)
+        and ``out`` is returned.
 
-        Each row is gathered once through a strided window over the page
-        array, so no page is copied: a row that straddles pages is
-        contiguous there.  An empty page set issues no command; its rows
-        lie in pages the caller already holds (the page cache's hits).
+        A row that straddles pages is contiguous in the page array, so no
+        page is copied.  A row of a page or more is one slice copy straight
+        into its place in the output (counter ``store.embed_rows_direct``);
+        narrower rows are gathered together through a strided window over
+        the page array, where a loop step per row would cost more than the
+        row it moves.  An empty page set issues no command; its rows lie in
+        pages the caller already holds (the page cache's hits).
         """
         lpns = np.asarray(lpns, dtype=np.int64)
         if len(lpns):
@@ -370,10 +376,22 @@ class BlockDevice:
         offsets = np.asarray(offsets, dtype=np.int64)
         flat = self._pages.reshape(-1)
         if not len(offsets):
-            return np.empty((0, d), SLOT_DTYPE)
+            return np.empty((0, d), SLOT_DTYPE) if out is None else out
         if offsets.min() < 0 or offsets.max() > flat.size - d:
             raise IndexError("row outside the device")
-        return np.lib.stride_tricks.sliding_window_view(flat, d)[offsets]
+        if d < SLOTS_PER_PAGE:
+            rows = np.lib.stride_tricks.sliding_window_view(flat, d)[offsets]
+            if out is None:
+                return rows
+            out[slice(len(rows)) if at is None else at] = rows
+            return out
+        if out is None:
+            out = np.empty((len(offsets), d), SLOT_DTYPE)
+        dst = range(len(offsets)) if at is None else np.asarray(at).tolist()
+        for i, o in zip(dst, offsets.tolist()):
+            out[i] = flat[o:o + d]
+        spans.count("store.embed_rows_direct", len(offsets))
+        return out
 
     def pages_of(self, lpns) -> np.ndarray:
         """Copies of pages ``lpns`` with no command: for a caller whose

@@ -27,7 +27,8 @@ Mechanics:
     merged page set, but no page block is built.  A row wholly inside one
     hit page is copied from the slab; every other row (in a missed page,
     or straddling pages, which are contiguous on the device) is gathered
-    by ONE ``dev.read_rows`` charged for exactly the missed pages.  The
+    by ONE ``dev.read_rows`` charged for exactly the missed pages, which
+    writes it straight into its place in the one output array.  The
     install of the kept misses is the only whole-page copy;
   * invalidation is hooked at the device write layer (``BlockDevice.on_write``
     fires for every ``write_page``/``write_span``/``free_page`` and for the
@@ -136,7 +137,8 @@ class EmbeddingPageCache:
         ``read_pages(dev, lpns)``; only the rows are copied out.  A row
         wholly inside one hit page comes from the slab; every other row
         (in a missed page, or straddling pages) comes from ONE
-        ``dev.read_rows`` charged for exactly the missed pages.
+        ``dev.read_rows`` charged for exactly the missed pages, written
+        into the same output: each row is copied once.
         """
         lpns = np.asarray(lpns, dtype=np.int64).reshape(-1)
         offsets = np.asarray(offsets, dtype=np.int64).reshape(-1)
@@ -156,16 +158,19 @@ class EmbeddingPageCache:
             within = offsets - first * SLOTS_PER_PAGE
             row_slot = table[first]
             from_slab = (row_slot >= 0) & (within + d <= SLOTS_PER_PAGE)
-            rows = np.empty((len(offsets), d), SLOT_DTYPE)
+            miss_lpns = lpns[~hit]
             if from_slab.any():                # before installs evict
+                rows = np.empty((len(offsets), d), SLOT_DTYPE)
                 win = np.lib.stride_tricks.sliding_window_view(
                     self._slab.reshape(-1), d)
                 rows[from_slab] = win[row_slot[from_slab] * SLOTS_PER_PAGE
                                       + within[from_slab]]
-            miss_lpns = lpns[~hit]
-            if not from_slab.all():
-                rest = ~from_slab
-                rows[rest] = dev.read_rows(miss_lpns, offsets[rest], d)
+                rest = np.flatnonzero(~from_slab)
+                if len(rest):
+                    dev.read_rows(miss_lpns, offsets[rest], d,
+                                  out=rows, at=rest)
+            else:                              # every row from the device
+                rows = dev.read_rows(miss_lpns, offsets, d)
             n_miss = len(miss_lpns)
             if not n_miss:
                 return rows, 0

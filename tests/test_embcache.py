@@ -174,6 +174,10 @@ def _embed_bytes():
     return spans.snapshot().get("store.embed_bytes", {"n": 0})["n"]
 
 
+def _rows_direct():
+    return spans.snapshot().get("store.embed_rows_direct", {"n": 0})["n"]
+
+
 @pytest.mark.parametrize("d", [24, 100, 1000, 1500, 8415])
 def test_row_path_matches_page_block_path(d):
     """Rows gathered straight from the slab and the page array are the
@@ -182,7 +186,9 @@ def test_row_path_matches_page_block_path(d):
     them — cold, warm, partly warm, a scan larger than the cache, after
     RMW invalidation and after device growth (at d=1500 a row spans two
     or three pages; at d=8415, Coauthor-Physics' width, nine or ten, more
-    than the whole cache holds)."""
+    than the whole cache holds).  Rows of a page or more are each copied
+    straight into the output (``store.embed_rows_direct``); narrower rows
+    never take that path."""
     cap = 8
     n = 100 * SLOTS_PER_PAGE // d + 3              # ~100 pages, last partial
     rng = np.random.default_rng(d)
@@ -201,8 +207,10 @@ def test_row_path_matches_page_block_path(d):
     def check(vids):
         vids = np.asarray(vids, dtype=np.int64)
         miss0 = old.cache.stats.misses
-        b0 = _embed_bytes()
+        b0, r0 = _embed_bytes(), _rows_direct()
         got = new.get_embeds(vids)
+        assert _rows_direct() - r0 == \
+            (len(vids) if d * 4 >= PAGE_BYTES else 0)
         want = _page_block_gather(old, vids)
         np.testing.assert_array_equal(got.view(np.int32),
                                       want.view(np.int32))
@@ -297,3 +305,77 @@ def test_row_path_builds_no_page_block():
     bound = 4 * k * d * 4 + cap * PAGE_BYTES
     assert n_pages * PAGE_BYTES > 2 * bound
     assert peak < bound, (peak, bound)
+
+
+def test_wide_row_path_copies_each_row_once():
+    """At Coauthor-Physics' width every row spans 9-10 pages and comes
+    from the device: the gather's peak host allocation is the rows it
+    returns plus the pages the cache installs, with no second row-sized
+    temporary."""
+    n, d, cap, k = 700, 8415, 8, 300
+    table = np.random.default_rng(8).standard_normal((n, d)) \
+        .astype(np.float32)
+    gs = GraphStore(BlockDevice(64), feature_dim=d)
+    gs.update_graph(np.array([[0, 1]], dtype=np.int64), table)
+    gs.attach_cache(EmbeddingPageCache(cap))
+    rng = np.random.default_rng(9)
+    gs.get_embeds(rng.integers(0, n, k))           # warm every lazy path
+    vids = rng.integers(0, n, k)
+    r0 = _rows_direct()
+    tracemalloc.start()
+    try:
+        got = gs.get_embeds(vids)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    np.testing.assert_array_equal(got, table[vids])
+    assert _rows_direct() - r0 == k
+    bound = got.nbytes + cap * PAGE_BYTES + (1 << 20)
+    assert 2 * got.nbytes > bound
+    assert peak < bound, (peak, bound)
+
+
+@pytest.mark.parametrize("d", [100, 1000, 1024, 1500, 8415])
+@pytest.mark.parametrize("positions", ["given", "in_order"])
+def test_device_read_rows_writes_into_out(d, positions):
+    """``BlockDevice.read_rows(..., out=, at=)`` puts row ``i`` at
+    ``out[at[i]]`` (``out[i]`` without ``at``), leaves every other row
+    of ``out`` as it was, returns ``out``, and charges the command exactly
+    as the read without ``out`` does."""
+    rng = np.random.default_rng(d)
+    n = 40 * SLOTS_PER_PAGE // d + 2
+    table = rng.integers(-2**31, 2**31, (n, d), dtype=np.int64) \
+        .astype(np.int32)
+    devs = []
+    for _ in "ab":
+        dev = BlockDevice(64, simulate_latency=True, page_read_us=30.0,
+                          command_latency_us=80.0)
+        dev.write_span(0, table.reshape(-1))
+        devs.append(dev)
+    new, old = devs
+    vids = rng.integers(0, n, 25)
+    offsets = vids * d
+    lpns = np.unique(np.concatenate(
+        [np.arange(o // SLOTS_PER_PAGE, (o + d - 1) // SLOTS_PER_PAGE + 1)
+         for o in offsets]))
+    out = np.full((len(vids) + 7, d), -5, np.int32)
+    at = (rng.permutation(len(out))[:len(vids)] if positions == "given"
+          else None)
+    r0 = _rows_direct()
+    with new.defer_latency() as got_us:
+        ret = new.read_rows(lpns, offsets, d, out=out, at=at)
+    direct = _rows_direct() - r0
+    with old.defer_latency() as want_us:
+        want = old.read_rows(lpns, offsets, d)
+    assert ret is out
+    placed = np.arange(len(vids)) if at is None else at
+    np.testing.assert_array_equal(out[placed], table[vids])
+    np.testing.assert_array_equal(want, table[vids])
+    kept = np.setdiff1d(np.arange(len(out)), placed)
+    assert len(kept) == 7 and (out[kept] == -5).all()
+    assert direct == (len(vids) if d >= SLOTS_PER_PAGE else 0)
+    assert got_us.us == want_us.us == 80.0 + 30.0 * len(lpns) / 8
+    assert (new.stats.read_pages, new.stats.read_bytes) == \
+        (old.stats.read_pages, old.stats.read_bytes)
+    ev = [(e.kind, e.lpn, e.nbytes, e.tag) for e in new.stats.events]
+    assert ev == [(e.kind, e.lpn, e.nbytes, e.tag) for e in old.stats.events]
